@@ -112,6 +112,8 @@ def main() -> None:
                     help="table1|table3|fig2|roofline")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import fig2_loo, roofline_report, table1_kfold, table3_vary_k
     sections = {
         "table1": lambda: table1_kfold.run(quick=args.quick),

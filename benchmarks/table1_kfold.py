@@ -69,6 +69,7 @@ from repro.data.svm_suite import kfold_chunks, make_dataset
 from repro.launch.roofline import roofline_terms
 from repro.svm import (bias_from_solution, init_f, kernel_matrix, predict,
                        smo_solve_batched)
+from repro.svm.precision import kernel_input
 
 SIZES = {"adult": 1000, "heart": 270, "madelon": 1200, "mnist": 1000,
          "webdata": 1000}
@@ -245,7 +246,7 @@ def _ato_bucketed_row(name: str, k: int, reps: int) -> dict:
     with per-lane buckets vs the widest-lane pad. The solve chain advances
     on the bucketed seeds; ramp timings are warm min-of-reps."""
     ds = make_dataset(name, n_override=SIZES[name])
-    X = jnp.asarray(ds.X)
+    X = kernel_input(ds.X)            # run_cv's precision policy
     y = jnp.asarray(ds.y, jnp.float64)
     chunks = kfold_chunks(ds.n, k, seed=0)
     n = chunks.size

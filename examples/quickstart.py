@@ -2,8 +2,11 @@
 
     PYTHONPATH=src python examples/quickstart.py
 """
+from repro.compile_cache import enable_compile_cache
 from repro.core.cv import run_cv
 from repro.data.svm_suite import make_dataset
+
+enable_compile_cache()
 
 ds = make_dataset("madelon", n_override=600)
 print(f"dataset={ds.name} n={ds.n} d={ds.X.shape[1]} C={ds.C} gamma={ds.gamma}")

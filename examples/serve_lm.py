@@ -9,12 +9,14 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.launch.inputs import concrete_batch
 from repro.models import init_params, model_params_def
 from repro.models import transformer as T
 from repro.serving import build_serve_step
 
+enable_compile_cache()
 arch = sys.argv[1] if len(sys.argv) > 1 else "gemma3-4b"
 new_tokens = int(sys.argv[2]) if len(sys.argv) > 2 else 32
 B, PROMPT = 4, 16

@@ -19,9 +19,12 @@ import sys
 import tempfile
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.core.cv import run_cv
 from repro.data.svm_suite import make_dataset
 from repro.svm import SVC
+
+enable_compile_cache()
 
 
 def _serve(sock_path: str) -> None:

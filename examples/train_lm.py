@@ -6,7 +6,10 @@ checkpoint/restart — the end-to-end training driver.
 import sys
 import tempfile
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import train
+
+enable_compile_cache()
 
 arch = sys.argv[1] if len(sys.argv) > 1 else "granite-8b"
 steps = int(sys.argv[2]) if len(sys.argv) > 2 else 200

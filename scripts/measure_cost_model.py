@@ -50,9 +50,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.svm import cost_model
 from repro.svm.engine import DenseKernel, PallasRBF
 from repro.svm.kernels import kernel_matrix
+from repro.svm.precision import kernel_input
 from repro.svm.scheduler import LanePool
 
 #: width-1 keeps the cap unless a batched width beats it by this factor
@@ -77,7 +79,7 @@ C_SPREAD = (0.25, 0.5, 1.0, 2.0, 4.0, 1.0, 0.5, 2.0)
 
 def _problem(n: int, d: int, gamma: float, n_lanes: int):
     rng = np.random.default_rng(0)
-    X = jnp.asarray(rng.normal(size=(n, d)))
+    X = kernel_input(rng.normal(size=(n, d)))   # the entry points' f32 policy
     y = jnp.asarray(np.where(rng.random(n) < 0.5, -1.0, 1.0))
     masks = [jnp.asarray(np.random.default_rng(10 + h).random(n) < 0.85)
              for h in range(n_lanes)]
@@ -159,6 +161,7 @@ def measure_shrink(kind: str, *, ns, d, gamma, chunk_iters, reps,
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--n", type=int, default=1000,
                     help="instances per synthetic lane problem")

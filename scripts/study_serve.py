@@ -17,6 +17,8 @@ import sys
 
 
 def main(argv=None) -> int:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--socket", required=True,
                     help="AF_UNIX socket path to listen on")

@@ -47,6 +47,7 @@ from repro.data.svm_suite import SVMDataset, kfold_chunks
 from repro.svm import (DenseKernel, PallasRBF, bias_from_solution,
                        dual_objective, kernel_matrix, predict,
                        smo_solve_batched)
+from repro.svm.precision import STATE_DTYPE, kernel_input
 
 # step numbering inside a checkpoint directory: fold h's mid-fold chunk
 # snapshots live at h*_FOLD_STRIDE + 1 + chunk, its completion record at
@@ -209,8 +210,8 @@ def run_cv(ds: SVMDataset, k: int = 10, method: str = "sir",
             "run_cv mid-fold checkpoints do not record the shrink ledger; "
             "use shrink_every=0 here, drop chunk_iters, or switch to a "
             "study-keyed driver (run_cv_batched / run_grid)")
-    X = jnp.asarray(ds.X)
-    y = jnp.asarray(ds.y, jnp.float64)
+    X = kernel_input(ds.X)
+    y = jnp.asarray(ds.y, STATE_DTYPE)
 
     chunks = kfold_chunks(ds.n, k, seed=seed)
     n = chunks.size  # padded n (multiple of k)
@@ -314,7 +315,7 @@ def run_cv(ds: SVMDataset, k: int = 10, method: str = "sir",
     base_counts: dict[int, int] = {}
     done_folds = sorted(results)
     prev_lane = None
-    zeros = jnp.zeros(n, K.dtype)
+    zeros = jnp.zeros(n, STATE_DTYPE)
     for h in range(start_fold, k):
         avail = [g for g in done_folds if g not in unavailable_folds]
         if resume is not None and h == start_fold:
@@ -467,8 +468,8 @@ def run_cv_batched(ds: SVMDataset, k: int = 10, tol: float = 1e-3,
         raise ValueError("shrink_every requires the repacked schedule: "
                          "shrinking is a lane-pool transformation, not an "
                          "engine.solve_batched feature")
-    X = jnp.asarray(ds.X)
-    y = jnp.asarray(ds.y, jnp.float64)
+    X = kernel_input(ds.X)
+    y = jnp.asarray(ds.y, STATE_DTYPE)
 
     chunks = kfold_chunks(ds.n, k, seed=seed)
     n = chunks.size
@@ -490,7 +491,8 @@ def run_cv_batched(ds: SVMDataset, k: int = 10, tol: float = 1e-3,
 
     if schedule == "batched":
         t0 = time.perf_counter()
-        res = smo_solve_batched(K, y, masks, ds.C, jnp.zeros((k, n), K.dtype),
+        res = smo_solve_batched(K, y, masks, ds.C,
+                                jnp.zeros((k, n), STATE_DTYPE),
                                 jnp.tile(-y, (k, 1)), tol=tol,
                                 max_iter=max_iter, chunk_iters=chunk_iters)
         jax.block_until_ready(res)
@@ -517,7 +519,7 @@ def run_cv_batched(ds: SVMDataset, k: int = 10, tol: float = 1e-3,
                 max_width=max_width,
                 shrink_every=shrink_every, shrink_quantum=shrink_quantum,
                 shrink_caps=shrink_caps, shrink_on_seed=shrink_on_seed)
-    zeros = jnp.zeros(n, source.dtype)
+    zeros = jnp.zeros(n, STATE_DTYPE)
     for h in range(k):
         plan.lane(h, train_mask=masks[h], C=ds.C, alpha0=zeros, f0=-y,
                   max_iter=max_iter)
@@ -582,8 +584,8 @@ def run_loo(ds: SVMDataset, method: str = "sir", rounds: int | None = None,
     records) for free."""
     if method not in ("cold", "avg", "top", "ato", "mir", "sir"):
         raise ValueError(f"unknown LOO method {method!r}")
-    X = jnp.asarray(ds.X)
-    y = jnp.asarray(ds.y, jnp.float64)
+    X = kernel_input(ds.X)
+    y = jnp.asarray(ds.y, STATE_DTYPE)
     n = ds.n
     rounds = n if rounds is None else min(rounds, n)
 
@@ -592,7 +594,7 @@ def run_loo(ds: SVMDataset, method: str = "sir", rounds: int | None = None,
 
     plan = Plan(sources={"loo": DenseKernel(K)}, y=y, tol=tol,
                 chunk_iters=chunk_iters, max_width=max_width)
-    zeros = jnp.zeros(n, K.dtype)
+    zeros = jnp.zeros(n, STATE_DTYPE)
     # full-data SVM (shared by AVG/TOP; also round -1 for the chain methods)
     plan.lane("full", train_mask=jnp.ones(n, bool), C=ds.C, alpha0=zeros,
               f0=-y, max_iter=max_iter)

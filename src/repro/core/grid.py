@@ -48,6 +48,7 @@ from repro.core.cv import _fold_masks, _transition_idx
 from repro.core.study import Plan, StudyCheckpoint, run_plan
 from repro.data.svm_suite import SVMDataset, kfold_chunks
 from repro.svm import KernelSpec
+from repro.svm.precision import STATE_DTYPE, kernel_input
 
 
 @dataclasses.dataclass
@@ -197,8 +198,8 @@ def grid_plans(ds: SVMDataset, Cs, gammas, k: int = 10,
     _check_grid_args(pool, source_backend, method)
     Cs = sorted(float(c) for c in Cs)
     gammas = [float(g) for g in gammas]
-    y_all = jnp.asarray(ds.y, jnp.float64)
-    X = jnp.asarray(ds.X)
+    y_all = jnp.asarray(ds.y, STATE_DTYPE)
+    X = kernel_input(ds.X)
     chunks = kfold_chunks(ds.n, k, seed=seed)
     n = chunks.size
     y = y_all[:n]
@@ -212,9 +213,8 @@ def grid_plans(ds: SVMDataset, Cs, gammas, k: int = 10,
     sources = {gi: KernelSpec(X=X, gamma=gamma, kind="rbf",
                               backend=kernel_backend, n=n)
                for gi, gamma in enumerate(gammas)}
-    # cold-start alphas in the KERNEL dtype (KernelSpec answers it without
-    # materializing), matching run_cv's jnp.zeros(n, K.dtype)
-    zeros = jnp.zeros(n, sources[0].dtype if sources else jnp.float64)
+    # cold-start alphas in the state dtype, matching run_cv's
+    zeros = jnp.zeros(n, STATE_DTYPE)
 
     def make_plan(keys) -> Plan:
         plan = Plan(sources={gi: sources[gi] for gi in keys}, y=y, tol=tol,

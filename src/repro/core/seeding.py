@@ -28,8 +28,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import jax.scipy.linalg
 import numpy as np
 
+from repro.svm.precision import STATE_DTYPE, kdot
 from repro.svm.smo import SMOResult
 
 _INF = jnp.inf
@@ -136,12 +138,39 @@ def scale_seed_C(alpha: jnp.ndarray, y: jnp.ndarray, C_old, C_new,
 # --------------------------------------------------------------------------
 
 def cold_seed(K, y, C, prev, S_idx, R_idx, T_idx, **_):
-    return jnp.zeros_like(y, dtype=K.dtype)
+    return jnp.zeros_like(y, dtype=STATE_DTYPE)
 
 
 # --------------------------------------------------------------------------
 # MIR — Multiple Instance Replacement (paper Eq. 13-18, Algorithm 2)
 # --------------------------------------------------------------------------
+
+#: MIR's f32 ridge, relative to the mean diagonal of A^T A: large enough
+#: to keep the Gram matrix positive definite in f32 (whose resolution is
+#: ~1e-7 of that diagonal), small enough to leave the fit's well-determined
+#: directions alone (DESIGN.md §Precision policy)
+MIR_RIDGE = 1e-4
+
+
+def _least_squares(A, b):
+    """``argmin ||A x - b||`` in ``A``'s dtype, returned as f64 state.
+
+    An f64 ``A`` (the reference path) takes LAPACK-style ``lstsq``. An
+    f32 ``A`` takes the ridge-regularized normal equations through a
+    Cholesky factor: the TPU compiler aborts on f32 SVD (which ``lstsq``
+    needs), f64 ``lstsq`` compiles for minutes even at 3,001 x 300, and
+    QR takes 90 s to compile at adult's 32,561 x 3,256 — while ``A^T A``
+    is one MXU matmul and its Cholesky compiles in about 20 s. A
+    non-finite solve falls back to zeros, which the repair then fills."""
+    if A.dtype == STATE_DTYPE:
+        return jnp.linalg.lstsq(A, b)[0]
+    G = jnp.dot(A.T, A, precision=jax.lax.Precision.HIGHEST)
+    lam = MIR_RIDGE * jnp.trace(G) / G.shape[0]
+    G = G + lam * jnp.eye(G.shape[0], dtype=G.dtype)
+    x = jax.scipy.linalg.cho_solve(jax.scipy.linalg.cho_factor(G),
+                                   kdot(A.T, b).astype(A.dtype))
+    return jnp.where(jnp.isfinite(x), x, 0.0).astype(STATE_DTYPE)
+
 
 @jax.jit
 def mir_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx):
@@ -153,6 +182,10 @@ def mir_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx):
     with df_i = b - f_i on I_u + I_l and 0 on I_m (rows i over the previous
     training set X = S + R). Solved by lstsq; the box/equality constraints
     are then repaired per the paper's AdjustAlpha.
+
+    The least-squares solve runs in the kernel dtype (``_least_squares``).
+    Its result is only a start point: the f64 repair below and the solver
+    that follows fix the equality constraint and the optimum.
     """
     X_idx = jnp.concatenate([S_idx, R_idx])
     alpha, f = prev.alpha, prev.f
@@ -162,12 +195,14 @@ def mir_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx):
     df = jnp.where(free, 0.0, b - f)[X_idx]
 
     beta_R = (y * alpha)[R_idx]
-    rhs = df + K[X_idx][:, R_idx] @ beta_R
-    A = K[X_idx][:, T_idx]
+    # one 2-D gather per slab: K[X_idx] first would materialize a
+    # (|S+R|, n) copy — 3.8 GB at adult's size
+    rhs = df + kdot(K[jnp.ix_(X_idx, R_idx)], beta_R)
+    A = K[jnp.ix_(X_idx, T_idx)]
     # append the equality constraint as one more row of the LS system
     A_full = jnp.concatenate([A, jnp.ones((1, T_idx.shape[0]), K.dtype)], 0)
     rhs_full = jnp.concatenate([rhs, jnp.sum(beta_R)[None]], 0)
-    beta_T, *_ = jnp.linalg.lstsq(A_full, rhs_full)
+    beta_T = _least_squares(A_full, rhs_full)
 
     lo, hi = _box(y[T_idx], C)
     beta_T = water_fill(jnp.clip(beta_T, lo, hi), lo, hi, jnp.sum(beta_R))
@@ -203,7 +238,7 @@ def sir_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx,
     K_RT = K[R_idx][:, T_idx]
     same = (y[R_idx][:, None] == y[T_idx][None, :])
     alpha_R = prev.alpha[R_idx]
-    priority = jax.random.uniform(rng_key, (T_idx.shape[0],), K.dtype)
+    priority = jax.random.uniform(rng_key, (T_idx.shape[0],), STATE_DTYPE)
 
     def body(r, carry):
         beta_T, used = carry
@@ -223,7 +258,7 @@ def sir_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx,
         return beta_T, used
 
     beta_T, _ = jax.lax.fori_loop(
-        0, m, body, (jnp.zeros(T_idx.shape[0], K.dtype),
+        0, m, body, (jnp.zeros(T_idx.shape[0], STATE_DTYPE),
                      jnp.zeros(T_idx.shape[0], bool)))
 
     lo, hi = _box(y[T_idx], C)
@@ -260,7 +295,7 @@ def ato_seed_ref(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx,
     after ``max_steps`` (then clamps alpha_R to 0, as the remaining mass is
     small) and always ends with the exact constraint repair.
     """
-    y = jnp.asarray(y, K.dtype)
+    y = jnp.asarray(y, STATE_DTYPE)
     alpha = prev.alpha.copy()
     f = prev.f.copy()
     n = y.shape[0]
@@ -293,12 +328,12 @@ def ato_seed_ref(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx,
                                    Q_MT @ vT + Q_MR @ vR], 0)
             Phi = jnp.linalg.pinv(A1) @ rhs
         else:
-            Phi = jnp.zeros((0,), K.dtype)
+            Phi = jnp.zeros((0,), STATE_DTYPE)
         # per-unit df (Eq. 11 divided by y_i): g_i = -sum_M y_m Phi_m K_im
         #   + sum_T y_t (C-a_t) K_it - sum_R y_r a_r K_ir
-        g = (K[:, Tc] @ (y[Tc] * vT) + K[:, Rc] @ (y[Rc] * vR))
+        g = kdot(K[:, Tc], y[Tc] * vT) + kdot(K[:, Rc], y[Rc] * vR)
         if M.size > 0:
-            g = g - K[:, M] @ (y[M] * Phi)
+            g = g - kdot(K[:, M], y[M] * Phi)
         # step size: smallest eta>0 putting some bound instance's f at b (Eq.5)
         bound = train_now & ~free
         safe_g = jnp.where(jnp.abs(g) > 1e-12, g, 1.0)
@@ -329,16 +364,53 @@ def ato_seed_ref(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx,
 
 
 def _bucket_cap(m: int, n: int) -> int:
-    """Smallest multiple of 128 >= m, clamped to [1, n]. Buckets the
-    working-set pad so jit retraces are O(n / 128) per problem size instead
-    of one per transition, while keeping the padded LU within ~2x of the
-    exact-|M| cost (a pow2 bucket can pad 605 -> 1024 and quadruple it)."""
-    cap = max(128, -(-m // 128) * 128)
-    return max(1, min(cap, n))
+    """Working-set pad for the ATO ramp: the smallest rung >= m of a
+    ladder of multiples of 128 growing by ~sqrt(2) per rung (128, 256,
+    384, 512, 768, 1024, 1536, 2048, 2944, 4096, 5888, 8192, ...),
+    clamped to [1, n].
+
+    Every distinct pad is one compiled ramp program, and on a TPU at
+    adult's size that compile takes 40-75 s (mostly the bordered LU), so
+    the rungs must be few: O(log n) per problem size, where plain
+    multiples of 128 gave O(n / 128) — about one per fold transition. A
+    rung pads the LU by at most sqrt(2) in width (~2.8x in flops)."""
+    j = 0
+    while True:
+        cap = -(-int(128 * 2 ** (j / 2)) // 128) * 128
+        if cap >= m:
+            return max(1, min(cap, n))
+        j += 1
+
+
+#: widest ATO working set: a (4,097)-square bordered system is 134 MB in
+#: f64 and compiles in ~40 s on a TPU; wider free sets take the coupled
+#: selection (``_ato_ramp``)
+ATO_MAX_M = 4096
+
+
+def _ramp_cap(m: int, n: int) -> tuple[int, bool]:
+    """(m_cap, coupled) for an exact working-set bound ``m``."""
+    cap = _bucket_cap(m, n)
+    return (cap, False) if cap <= ATO_MAX_M else (ATO_MAX_M, True)
+
+
+def _bordered_solve(B, rhs, lu_dtype):
+    """Solve the f64 bordered KKT system ``B x = rhs`` through an LU in
+    ``lu_dtype``. Below f64 the solve takes one step of f64 iterative
+    refinement against the f64 ``B`` (same factors, residual in f64), which
+    recovers most of the digits the f32 factorization drops; an f64 LU (the
+    reference path) is solved directly."""
+    if lu_dtype == B.dtype:
+        return jnp.linalg.solve(B, rhs)
+    lu = jax.scipy.linalg.lu_factor(B.astype(lu_dtype))
+    x = jax.scipy.linalg.lu_solve(lu, rhs.astype(lu_dtype)).astype(B.dtype)
+    resid = rhs - B @ x
+    return x + jax.scipy.linalg.lu_solve(
+        lu, resid.astype(lu_dtype)).astype(B.dtype)
 
 
 def _ato_ramp(K, y, C, alpha, f, b_fallback, in_S, in_T, in_R, tol,
-              m_cap: int, max_steps: int):
+              m_cap: int, max_steps: int, coupled: bool = False):
     """Fixed-shape ATO ramp: ``ato_seed_ref``'s loop with masks for the
     M/T/R sets and a bordered KKT solve for Phi. Pure traced function —
     jit- and vmap-safe (the grid batches it across a C row).
@@ -348,11 +420,25 @@ def _ato_ramp(K, y, C, alpha, f, b_fallback, in_S, in_T, in_R, tol,
     it can never become free, while graduated T rows can. Callers therefore
     pad the working set to ``m_cap >= |free S at entry| + |T|``, which is
     exact — overflow is impossible, not just unlikely.
+
+    ``coupled=True`` is for a bound above ``ATO_MAX_M`` (adult at its
+    published size keeps ~90% of its rows free, so the exact pad is all of
+    n: a 32,561-square bordered LU per step, 8.5 GB in f64, more than a
+    16 GB chip holds beside K). M is then the ``m_cap`` free rows that the
+    pure T/R ramp moves most (largest ``|K @ w|``); the other free rows
+    keep their alphas for the ramp, and the solver that follows settles
+    them. Seeding never moves the fixed point, only the iteration count.
     """
     n = y.shape[0]
-    C = jnp.asarray(C, K.dtype)
+    C = jnp.asarray(C, STATE_DTYPE)
     thresh = 1e-12 * jnp.maximum(C, 1.0)
     valid = jnp.arange(m_cap)
+    # the bordered system is factored in the kernel dtype: LU has no f64
+    # lowering on the TPU, and an f32 K carries no more than f32 anyway.
+    # A ridge only regularizes if it sits above the factorization's
+    # resolution: 1e-10 relative for f64, 100 ulps for f32.
+    lu_dtype = K.dtype
+    ridge = max(1e-10, 100 * float(jnp.finfo(lu_dtype).eps))
 
     def cond(carry):
         _alpha, _f, T_act, R_act, step, stop = carry
@@ -371,31 +457,37 @@ def _ato_ramp(K, y, C, alpha, f, b_fallback, in_S, in_T, in_R, tol,
         w = y * v
         # fixed-shape working set: indices of M padded to m_cap (padding
         # lanes gather row 0 but are masked out of every product below)
-        idx = jnp.nonzero(free, size=m_cap, fill_value=0)[0]
-        lane = valid < nf
+        if coupled:
+            score = jnp.where(free, jnp.abs(kdot(K, w)), -1.0)
+            idx = jax.lax.top_k(score.astype(jnp.float32), m_cap)[1]
+            lane = valid < jnp.minimum(nf, m_cap)
+        else:
+            idx = jnp.nonzero(free, size=m_cap, fill_value=0)[0]
+            lane = valid < nf
         yM = jnp.where(lane, y[idx], 0.0)
-        Q = (yM[:, None] * yM[None, :]) * K[idx][:, idx]
+        Q = (yM[:, None] * yM[None, :]) * K[jnp.ix_(idx, idx)]
         # Bordered KKT system replacing the reference's pinv least squares
         # (Eq. 10): unknown (db, Phi) with the equality row enforced exactly
         #     [0    yM^T] [db ]   [sum(w)        ]
         #     [yM   Q_MM] [Phi] = [yM * (K_M: @ w)]
         # Padding lanes carry an identity diagonal and zero rhs (Phi = 0
-        # there); a tiny relative ridge keeps the LU finite on duplicate
+        # there); a small relative ridge keeps the LU finite on duplicate
         # instances, and a non-finite solve falls back to Phi = 0 (pure
         # T/R ramp — the M-empty behaviour).
-        lam = 1e-10 * (1.0 + jnp.max(jnp.abs(jnp.diagonal(Q))))
-        B = jnp.zeros((m_cap + 1, m_cap + 1), K.dtype)
+        lam = ridge * (1.0 + jnp.max(jnp.abs(jnp.diagonal(Q))))
+        B = jnp.zeros((m_cap + 1, m_cap + 1), STATE_DTYPE)
         B = B.at[0, 0].set(jnp.where(nf > 0, 0.0, 1.0))
         B = B.at[0, 1:].set(yM)
         B = B.at[1:, 0].set(yM)
         B = B.at[1:, 1:].set(Q + jnp.diag(jnp.where(lane, lam, 1.0)))
         r0 = jnp.where(nf > 0, jnp.sum(w), 0.0)
-        r = yM * (K[idx] @ w)
-        sol = jnp.linalg.solve(B, jnp.concatenate([r0[None], r]))
+        r = yM * kdot(K[idx], w)
+        sol = _bordered_solve(B, jnp.concatenate([r0[None], r]), lu_dtype)
         Phi = jnp.where(lane & jnp.isfinite(sol[1:]), sol[1:], 0.0)
-        Phi_full = jnp.zeros(n, K.dtype).at[idx].add(jnp.where(lane, Phi, 0.0))
+        Phi_full = jnp.zeros(n, STATE_DTYPE).at[idx].add(
+            jnp.where(lane, Phi, 0.0))
         # per-unit df (Eq. 11 divided by y_i), one kernel matvec
-        g = K @ (w - y * Phi_full)
+        g = kdot(K, w - y * Phi_full)
         # step size: smallest eta>0 putting some bound instance's f at b
         bound = train_now & ~free
         live = jnp.abs(g) > 1e-12
@@ -403,7 +495,7 @@ def _ato_ramp(K, y, C, alpha, f, b_fallback, in_S, in_T, in_R, tol,
         etas = jnp.where(bound & live, (b - f) / safe_g, _INF)
         etas = jnp.where(etas > 1e-12, etas, _INF)
         eta = jnp.minimum(jnp.min(etas), 1.0)
-        eta = jnp.where(jnp.isfinite(eta), eta, jnp.ones((), K.dtype))
+        eta = jnp.where(jnp.isfinite(eta), eta, jnp.ones((), STATE_DTYPE))
         # apply (M, T-active, R-active are disjoint: one fused update)
         alpha_new = jnp.clip(alpha + eta * (v - Phi_full), 0.0, C)
         f_new = f + eta * g
@@ -423,21 +515,25 @@ def _ato_ramp(K, y, C, alpha, f, b_fallback, in_S, in_T, in_R, tol,
     return jnp.where(in_R, 0.0, alpha)   # R must leave the training set
 
 
-@functools.partial(jax.jit, static_argnames=("m_cap", "max_steps"))
+@functools.partial(jax.jit,
+                   static_argnames=("m_cap", "max_steps", "coupled"))
 def _ato_seed_jit(K, y, C, alpha, f, b_fallback, in_S, in_T, in_R,
-                  S_idx, T_idx, tol, *, m_cap, max_steps):
+                  S_idx, T_idx, tol, *, m_cap, max_steps, coupled=False):
     out = _ato_ramp(K, y, C, alpha, f, b_fallback, in_S, in_T, in_R, tol,
-                    m_cap, max_steps)
-    return repair_equality(out, y, jnp.asarray(C, K.dtype), S_idx, T_idx)
+                    m_cap, max_steps, coupled)
+    return repair_equality(out, y, jnp.asarray(C, STATE_DTYPE), S_idx, T_idx)
 
 
-@functools.partial(jax.jit, static_argnames=("m_cap", "max_steps"))
+@functools.partial(jax.jit,
+                   static_argnames=("m_cap", "max_steps", "coupled"))
 def _ato_seed_batch_jit(K, y, Cs, alphas, fs, b_fallbacks, in_S, in_T, in_R,
-                        S_idx, T_idx, tol, *, m_cap, max_steps):
+                        S_idx, T_idx, tol, *, m_cap, max_steps,
+                        coupled=False):
     def one(C, alpha, f, b_fb):
         out = _ato_ramp(K, y, C, alpha, f, b_fb, in_S, in_T, in_R, tol,
-                        m_cap, max_steps)
-        return repair_equality(out, y, jnp.asarray(C, K.dtype), S_idx, T_idx)
+                        m_cap, max_steps, coupled)
+        return repair_equality(out, y, jnp.asarray(C, STATE_DTYPE), S_idx,
+                               T_idx)
 
     return jax.vmap(one)(Cs, alphas, fs, b_fallbacks)
 
@@ -456,15 +552,15 @@ def ato_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx,
     working set BEFORE the loop; everything else — including the constraint
     repair — runs on device.
     """
-    y = jnp.asarray(y, K.dtype)
+    y = jnp.asarray(y, STATE_DTYPE)
     n = y.shape[0]
     in_S, in_T, in_R = _transition_masks(n, S_idx, R_idx, T_idx)
     nf0 = int(jnp.sum(in_S & (prev.alpha > 0) & (prev.alpha < C)))
-    m_cap = _bucket_cap(nf0 + int(T_idx.shape[0]), n)
+    m_cap, coupled = _ramp_cap(nf0 + int(T_idx.shape[0]), n)
     b_fb = 0.5 * (prev.b_up + prev.b_low)
     return _ato_seed_jit(K, y, C, prev.alpha, prev.f, b_fb, in_S, in_T, in_R,
                          S_idx, T_idx, tol, m_cap=m_cap,
-                         max_steps=int(max_steps))
+                         max_steps=int(max_steps), coupled=coupled)
 
 
 def ato_seed_batch(K, y, Cs, prev: SMOResult, S_idx, R_idx, T_idx,
@@ -482,37 +578,37 @@ def ato_seed_batch(K, y, Cs, prev: SMOResult, S_idx, R_idx, T_idx,
     bound the solo ``ato_seed`` uses), lanes are grouped by cap, and one
     program is dispatched per bucket. Lanes with a small free set no
     longer pay the widest lane's O(m_cap^3) bordered solve; since caps are
-    already bucketed to multiples of 128, the group count (and the jit
-    retrace count) stays O(n / 128). ``bucket_by_lane=False`` keeps the
+    already bucketed on a sqrt(2) ladder, the group count (and the jit
+    retrace count) stays O(log n). ``bucket_by_lane=False`` keeps the
     historical behaviour — every lane padded to the widest cap in one
     program (the baseline the ``ato_bucketed`` benchmark row compares
     against).
     """
-    y = jnp.asarray(y, K.dtype)
+    y = jnp.asarray(y, STATE_DTYPE)
     n = y.shape[0]
-    Cs = jnp.asarray(Cs, K.dtype)
+    Cs = jnp.asarray(Cs, STATE_DTYPE)
     in_S, in_T, in_R = _transition_masks(n, S_idx, R_idx, T_idx)
     free0 = in_S[None] & (prev.alpha > 0) & (prev.alpha < Cs[:, None])
     nf0s = np.asarray(jnp.sum(free0, axis=1))   # one (lanes,) transfer
     t_sz = int(T_idx.shape[0])
     b_fbs = 0.5 * (prev.b_up + prev.b_low)
     if bucket_by_lane:
-        caps = np.asarray([_bucket_cap(int(nf) + t_sz, n) for nf in nf0s])
+        caps = [_ramp_cap(int(nf) + t_sz, n) for nf in nf0s]
     else:
-        caps = np.full(nf0s.shape[0],
-                       _bucket_cap(int(nf0s.max()) + t_sz, n))
-    out = jnp.zeros(prev.alpha.shape, K.dtype)
+        caps = [_ramp_cap(int(nf0s.max()) + t_sz, n)] * nf0s.shape[0]
+    out = jnp.zeros(prev.alpha.shape, STATE_DTYPE)
     # the trace key is (m_cap, group size): caps are monotone in C, so
     # bucket membership is a contiguous C-range and the distinct
     # (cap, size) combinations stay small for realistic rows. Padding
     # group sizes would bound the key space further but costs a full
     # O(m_cap^3)-per-step ramp lane per pad — not worth it at C-row scale.
-    for cap in sorted(set(caps.tolist())):
-        idx = jnp.asarray(np.nonzero(caps == cap)[0])
+    for cap, coupled in sorted(set(caps)):
+        idx = jnp.asarray([i for i, c in enumerate(caps)
+                           if c == (cap, coupled)])
         sub = _ato_seed_batch_jit(K, y, Cs[idx], prev.alpha[idx],
                                   prev.f[idx], b_fbs[idx], in_S, in_T, in_R,
                                   S_idx, T_idx, tol, m_cap=int(cap),
-                                  max_steps=int(max_steps))
+                                  max_steps=int(max_steps), coupled=coupled)
         out = out.at[idx].set(sub)
     return out
 

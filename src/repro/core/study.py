@@ -63,6 +63,7 @@ from repro.core import seeding
 from repro.svm import shrink as shrink_mod
 from repro.svm.engine import (DenseKernel, EngineState, SMOResult,
                               finalize)
+from repro.svm.precision import kdot
 from repro.svm.scheduler import LanePool
 from repro.svm.sources import KernelSpec, is_factory
 from repro.svm.smo import init_f
@@ -246,7 +247,7 @@ def _eval_lanes_sv_jit(K, y, test_idx, train_masks, Cs, res, cap):
         svi = jnp.nonzero(sv, size=cap, fill_value=y.shape[0])[0]
         coef = jnp.where(jnp.arange(cap) < jnp.sum(sv),
                          r.alpha[svi] * y[svi], 0.0)
-        dec = K[ti][:, svi] @ coef + b
+        dec = kdot(K[ti][:, svi], coef) + b
         pred = jnp.where(dec >= 0, 1, -1)
         return jnp.sum(pred == y[ti])
 
@@ -897,10 +898,18 @@ def run_plan(plan: Plan, *, checkpoint: StudyCheckpoint | None = None,
             seed_s=seed_s, solve_s=solve_s, restored=spec.id in pre_done)
 
     evals = run_plan_evals(pool, plan, specs, results)
+    occupancy, source_stats = pool.occupancy, pool.cache.stats
+    # the pool and its cache reference each other (eviction and trace
+    # callbacks), so they outlive this call until Python's cycle collector
+    # runs; dropping the sources now frees a dense K (4.2 GB at adult's
+    # size) when the caller lets go of it, before the next entry-point call
+    # builds its own
+    for key in list(pool.sources):
+        pool.remove_source(key)
 
     return StudyResult(results=results, stats=stats, evals=evals,
-                       occupancy=pool.occupancy, seed_time=pool.seed_time,
+                       occupancy=occupancy, seed_time=pool.seed_time,
                        solve_time=wall - pool.seed_time,
                        restored=frozenset(pre_done),
-                       source_stats=pool.cache.stats,
+                       source_stats=source_stats,
                        analysis=plan_analysis, tenant=tenant)

@@ -17,8 +17,27 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+#: block index for an axis a BlockSpec does not tile. Index maps must
+#: return int32: under x64 a Python ``0`` traces as int64, and Mosaic then
+#: refuses the index map ("failed to legalize operation 'func.return'")
+_I0 = np.int32(0)
+
+
+def check_compiled_operands(name: str, *operands) -> None:
+    """Refuse f64 operands for a compiled launch: Mosaic lowers no f64,
+    and the precision policy (``svm/precision.py``) keeps kernel values
+    in f32. Interpret mode keeps f64 as the tiling-validation oracle."""
+    for a in operands:
+        if jnp.dtype(a.dtype) == jnp.float64:
+            raise TypeError(
+                f"{name}: compiled Pallas launches take float32 kernel "
+                f"operands, got {a.dtype}; build sources with "
+                "repro.svm.precision.kernel_input")
 
 
 def auto_interpret(interpret: bool | None) -> bool:
@@ -40,7 +59,8 @@ def _rbf_kernel(xn_ref, zn_ref, x_ref, z_ref, o_ref, acc_ref, *, gamma,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(x_ref[...], z_ref[...].T,
-                            preferred_element_type=acc_ref.dtype)
+                            preferred_element_type=acc_ref.dtype,
+                            precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(k_step == n_k_steps - 1)
     def _finalize():
@@ -59,6 +79,8 @@ def rbf_kernel_matrix(X, Z, gamma: float, *, bm: int = 128, bn: int = 128,
     CPU (validation mode for this container) and compiles elsewhere.
     """
     interpret = auto_interpret(interpret)
+    if not interpret:
+        check_compiled_operands("rbf_kernel_matrix", X, Z)
     n, d = X.shape
     m = Z.shape[0]
     pad_n = (-n) % bm
@@ -78,8 +100,8 @@ def rbf_kernel_matrix(X, Z, gamma: float, *, bm: int = 128, bn: int = 128,
         functools.partial(_rbf_kernel, gamma=gamma, n_k_steps=n_k_steps),
         grid=(N // bm, M // bn, n_k_steps),
         in_specs=[
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+            pl.BlockSpec((bm, 1), lambda i, j, k: (i, _I0)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (_I0, j)),
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)),
         ],

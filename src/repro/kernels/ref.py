@@ -35,9 +35,10 @@ def smo_f_update_ref(f, K_i, K_j, delta):
 
 
 def fused_smo_step_ref(f, X, xij, sq_norms, delta, gamma):
-    """Fused pair-rows + rank-2 update: the FusedRBF.rows2 expression."""
+    """Fused pair-rows + rank-2 update: the FusedRBF.rows2 expression,
+    rows upcast to f's dtype before the update (the engine's order)."""
     cross = X @ xij.T
     d2 = jnp.maximum(sq_norms[:, None] + jnp.sum(xij * xij, 1)[None]
                      - 2.0 * cross, 0.0)
-    K2 = jnp.exp(-gamma * d2)
+    K2 = jnp.exp(-gamma * d2).astype(f.dtype)
     return f + delta * (K2[:, 0] - K2[:, 1])

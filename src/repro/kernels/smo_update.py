@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.rbf import auto_interpret
+from repro.kernels.rbf import _I0, auto_interpret, check_compiled_operands
 
 
 def _fupdate_kernel(f_ref, ki_ref, kj_ref, delta_ref, o_ref):
@@ -29,6 +29,8 @@ def smo_f_update(f, K_i, K_j, delta, *, block: int = 8192,
     elsewhere) — see :func:`repro.kernels.rbf.auto_interpret`.
     """
     interpret = auto_interpret(interpret)
+    if not interpret:
+        check_compiled_operands("smo_f_update", f, K_i, K_j)
     n = f.shape[0]
     pad = (-n) % block
     fp = jnp.pad(f, (0, pad))[None, :]
@@ -39,12 +41,12 @@ def smo_f_update(f, K_i, K_j, delta, *, block: int = 8192,
         _fupdate_kernel,
         grid=((n + pad) // block,),
         in_specs=[
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, block), lambda i: (_I0, i)),
+            pl.BlockSpec((1, block), lambda i: (_I0, i)),
+            pl.BlockSpec((1, block), lambda i: (_I0, i)),
+            pl.BlockSpec((1, 1), lambda i: (_I0, _I0)),
         ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((1, block), lambda i: (_I0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n + pad), f.dtype),
         interpret=interpret,
     )(fp, kip, kjp, d)

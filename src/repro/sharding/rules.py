@@ -95,15 +95,9 @@ def named_sharding(mesh: Mesh, axes: tuple, rules: LogicalAxisRules):
 
 
 def current_abstract_mesh():
-    """The ambient abstract mesh, or ``None``.
-
-    ``jax.sharding.get_abstract_mesh`` / ``set_mesh`` only exist on newer jax;
-    on older versions there is no ambient-mesh scope, so constraints degrade
-    to no-ops (the caller's code still runs, unsharded)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return None
-    mesh = get()
+    """The ambient abstract mesh (``jax.sharding.set_mesh``), or ``None``
+    outside a mesh scope."""
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return None
     return mesh
@@ -111,9 +105,8 @@ def current_abstract_mesh():
 
 def constrain(x, axes: tuple, rules: LogicalAxisRules | None = None):
     """with_sharding_constraint by logical axes. No-op outside a mesh scope
-    (``jax.sharding.set_mesh``) and on jax versions without ambient-mesh
-    support, so the same model code runs in single-device smoke tests and in
-    the 512-device dry-run unchanged."""
+    (``jax.sharding.set_mesh``), so the same model code runs in
+    single-device smoke tests and in the 512-device dry-run unchanged."""
     if rules is None:
         return x
     mesh = current_abstract_mesh()

@@ -54,6 +54,13 @@ Convergence is detected *inside* the chunk body (a converged state passes
 through untouched), which makes the same body ``vmap``-safe for batched
 execution: converged folds freeze while the rest keep iterating.
 
+Precision
+---------
+Sources hand out kernel values in their own dtype (float32 under the
+precision policy, ``svm/precision.py``); the engine upcasts every row and
+diagonal it reads to the float64 state before any arithmetic, so alpha,
+f, C and the gap are float64 whatever the source holds.
+
 Bit-parity contract
 -------------------
 For a given source the engine replays the seed solvers' floating-point ops
@@ -72,6 +79,7 @@ import jax.numpy as jnp
 from repro.kernels.rbf import auto_interpret
 from repro.kernels.smo_step import fused_smo_step
 from repro.sharding import constrain
+from repro.svm.precision import STATE_DTYPE, kdot
 
 _INF = jnp.inf
 _TAU = 1e-12
@@ -196,20 +204,14 @@ class DenseKernel:
     ~1.8x slower per batched iteration on CPU — the extra (b, n) masked
     passes cost more than the batched gathers they replace.
 
-    ``fupdate`` selects the rank-2 indicator-update implementation:
-    ``"jnp"`` is the plain expression, ``"pallas"`` routes through the
-    fused ``kernels/smo_update.py`` tile kernel (elementwise, so the two
-    are bit-identical), ``"auto"`` picks pallas off-CPU — the same
-    backend auto-detect the kernels themselves use.
+    The rank-2 indicator update is the plain jnp expression: f is float64
+    state, which no Pallas launch may take (``svm/precision.py``).
     """
 
     fused = False
 
-    def __init__(self, K, fupdate: str = "auto"):
+    def __init__(self, K):
         self.K = K
-        if fupdate == "auto":
-            fupdate = "jnp" if jax.default_backend() == "cpu" else "pallas"
-        self.fupdate = fupdate
 
     @property
     def dtype(self):
@@ -238,9 +240,6 @@ class DenseKernel:
         return alpha.at[j].add(-y_j * delta)
 
     def update_f(self, f, K_i, K_j, delta):
-        if self.fupdate == "pallas":
-            from repro.kernels.smo_update import smo_f_update
-            return smo_f_update(f, K_i, K_j, delta)
         return f + delta * (K_i - K_j)
 
     def rows_at(self, idx):
@@ -250,24 +249,24 @@ class DenseKernel:
 
     def matvec(self, v):
         """``K @ v`` — the unshrink reconstruction path (`shrink.py`)."""
-        return self.K @ v
+        return kdot(self.K, v)
 
     def compact(self, idx):
         """Active-set gather for the shrinking scheduler: the kernel
         restricted to rows/columns ``idx`` (pads — index n — clamp to the
         last row, inert under the compact validity mask)."""
         idx = jnp.asarray(idx)
-        return DenseKernel(self.K[idx][:, idx], fupdate=self.fupdate)
+        return DenseKernel(self.K[idx][:, idx])
 
     def constrain(self, v):
         return v
 
     def tree_flatten(self):
-        return (self.K,), (self.fupdate,)
+        return (self.K,), ()
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(children[0], fupdate=aux[0])
+        return cls(children[0])
 
 
 @jax.tree_util.register_pytree_node_class
@@ -361,7 +360,7 @@ class OnDemandRBF:
             xb, sb = args
             d2 = jnp.maximum(sb[:, None] + self.sq_norms[None]
                              - 2.0 * (xb @ self.X.T), 0.0)
-            return jnp.exp(-self.gamma * d2) @ v
+            return kdot(jnp.exp(-self.gamma * d2), v)
 
         return jax.lax.map(one, (Xb, sqb)).reshape(-1)[:n]
 
@@ -414,7 +413,9 @@ class PallasRBF(OnDemandRBF):
     runs with full-array blocks — no padding, one contraction step — so
     every op matches ``FusedRBF``'s jnp expression and alpha/f are
     bit-identical to ``FusedRBF``, solo and vmapped under the lane pool
-    (tests/test_engine.py). Compiled launches use MXU-aligned blocks and
+    (tests/test_engine.py). Compiled launches take blocks derived from
+    (n, d) and VMEM (``kernels/smo_step.compiled_blocks``) unless
+    ``bm``/``bk`` are given, need a float32 X (the precision policy), and
     carry the usual allclose guarantee only.
     """
 
@@ -520,19 +521,19 @@ def _step(source, y, train_mask, C, diag, tol, it_cap, wss, state):
     if wss == "2":
         # LibSVM WSS-2: among j in I_low with f_j > f_i, maximise
         # (f_j - f_i)^2 / eta_j.
-        K_i = source.row(i)
+        K_i = source.row(i).astype(f.dtype)
         diff = f - f_i
         eta = jnp.maximum(source.read(diag, i) + diag - 2.0 * K_i, _TAU)
         gain = jnp.where(i_low & (diff > 0), diff * diff / eta, -_INF)
         j = _argmax(gain)
-        K_j = source.row(j)
+        K_j = source.row(j).astype(f.dtype)
     else:
         # WSS-1 (maximal violating pair): j from f alone, so fused sources
         # can evaluate both kernel rows in a single pass — and streaming
         # sources can defer them to the fused update launch entirely.
         j = _argmax(jnp.where(i_low, f, -_INF))
         if not streams:
-            K_i, K_j = source.rows2(i, j)
+            K_i, K_j = (r.astype(f.dtype) for r in source.rows2(i, j))
 
     # --- analytic 2-variable update, delta >= 0 along (+y_i, -y_j) ---
     f_j = source.read(f, j)
@@ -541,7 +542,8 @@ def _step(source, y, train_mask, C, diag, tol, it_cap, wss, state):
     # K[i,j] for the eta denominator: a scalar hook for streaming sources
     # (no row in scope), the hoisted row read otherwise (pure dataflow —
     # bit-identical to reading it inline below)
-    K_ij = source.kij(i, j) if streams else source.read(K_i, j)
+    K_ij = (source.kij(i, j).astype(f.dtype) if streams
+            else source.read(K_i, j))
     eta_ij = jnp.maximum(source.read(diag, i) + source.read(diag, j)
                          - 2.0 * K_ij, _TAU)
     delta = (f_j - f_i) / eta_ij
@@ -576,11 +578,11 @@ def smo_chunk(source, y, train_mask, C, state: EngineState, *,
     if source.fused and wss == "2":
         raise ValueError("fused kernel sources evaluate both rows in one "
                          "pass and require WSS-1 (wss='1')")
-    C = jnp.asarray(C, source.dtype)
+    C = jnp.asarray(C, STATE_DTYPE)
     if it_cap is None:
         it_cap = jnp.iinfo(jnp.int32).max
     it_cap = jnp.asarray(it_cap, state.n_iter.dtype)
-    diag = source.diag()
+    diag = source.diag().astype(STATE_DTYPE)
     step = functools.partial(_step, source, y, train_mask, C, diag, tol,
                              it_cap, wss)
 
@@ -617,10 +619,10 @@ def chunk_batched_jit(source, y, train_masks, Cs, tol, it_caps, states,
     lanes carry their own iteration budgets — a scalar broadcasts."""
     it_caps = jnp.broadcast_to(jnp.asarray(it_caps, states.n_iter.dtype),
                                states.done.shape)
-    diag = source.diag()
+    diag = source.diag().astype(STATE_DTYPE)
 
     def one(mask, C, cap, state):
-        return _step(source, y, mask, jnp.asarray(C, source.dtype), diag,
+        return _step(source, y, mask, jnp.asarray(C, STATE_DTYPE), diag,
                      tol, cap, wss, state)
 
     def cond(carry):
@@ -658,8 +660,8 @@ def chunk_batched_sources_jit(sources, ys, train_masks, Cs, tol, it_caps,
                                states.done.shape)
 
     def one(src, y, mask, C, cap, state):
-        return _step(src, y, mask, jnp.asarray(C, src.dtype), src.diag(),
-                     tol, cap, wss, state)
+        return _step(src, y, mask, jnp.asarray(C, STATE_DTYPE),
+                     src.diag().astype(STATE_DTYPE), tol, cap, wss, state)
 
     def cond(carry):
         s, t = carry
@@ -678,12 +680,11 @@ def chunk_batched_sources_jit(sources, ys, train_masks, Cs, tol, it_caps,
 # drivers: single solve / batched solve
 # --------------------------------------------------------------------------
 
-def init_state(source, y, train_mask, alpha0, f0,
-               n_iter0=0) -> EngineState:
+def init_state(train_mask, alpha0, f0, n_iter0=0) -> EngineState:
     """Entry transform shared by every wrapper: zero alphas outside the
-    training mask, cast to the source dtype, reset the done flag."""
+    training mask, cast to the float64 state dtype, reset the done flag."""
     alpha0 = jnp.where(train_mask, alpha0, 0.0)
-    return EngineState(alpha0.astype(source.dtype), f0.astype(source.dtype),
+    return EngineState(alpha0.astype(STATE_DTYPE), f0.astype(STATE_DTYPE),
                        jnp.asarray(n_iter0, jnp.int64), jnp.zeros((), bool))
 
 
@@ -717,7 +718,7 @@ def solve(source, y, train_mask, C, alpha0, f0, *, tol: float = 1e-3,
     ``n_iter0`` pre-loads the iteration counter when resuming a checkpointed
     partial solve, so ``n_iter`` accounting survives a restart.
     """
-    state = init_state(source, y, train_mask, alpha0, f0, n_iter0=n_iter0)
+    state = init_state(train_mask, alpha0, f0, n_iter0=n_iter0)
     n = chunk_iters if chunk_iters is not None else max_iter
     # cap counts TOTAL updates incl. the pre-loaded n_iter0, so a resumed
     # solve stops exactly where the uninterrupted one would have
@@ -754,11 +755,11 @@ def solve_batched(source, y, train_masks, Cs, alpha0s, f0s, *,
     if source.fused and wss == "2":
         raise ValueError("fused kernel sources require WSS-1 (wss='1')")
     b, n = train_masks.shape
-    Cs = jnp.broadcast_to(jnp.asarray(Cs, source.dtype), (b,))
-    alpha0s = jnp.where(train_masks, alpha0s, 0.0).astype(source.dtype)
+    Cs = jnp.broadcast_to(jnp.asarray(Cs, STATE_DTYPE), (b,))
+    alpha0s = jnp.where(train_masks, alpha0s, 0.0).astype(STATE_DTYPE)
     n_iter0s = jnp.broadcast_to(
         jnp.asarray(0 if n_iter0s is None else n_iter0s, jnp.int64), (b,))
-    states = EngineState(alpha0s, f0s.astype(source.dtype),
+    states = EngineState(alpha0s, f0s.astype(STATE_DTYPE),
                          n_iter0s, jnp.zeros(b, bool))
     it_cap = jnp.asarray(max_iter, jnp.int64)
     while True:

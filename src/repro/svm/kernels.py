@@ -7,11 +7,17 @@ MXU; this module is the pure-jnp reference path (and the CPU path).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
+@jax.jit
 def rbf_kernel(X: jnp.ndarray, Z: jnp.ndarray, gamma: float) -> jnp.ndarray:
-    """K[i,j] = exp(-gamma * ||x_i - z_j||^2), shapes (n,d),(m,d) -> (n,m)."""
+    """K[i,j] = exp(-gamma * ||x_i - z_j||^2), shapes (n,d),(m,d) -> (n,m).
+
+    One fused program: run op by op, the cross-term, its scaled copy and
+    the exp argument would each be a live (n, m) temporary (three times
+    K's 4.2 GB at adult's published size)."""
     xn = jnp.sum(X * X, axis=-1)[:, None]
     zn = jnp.sum(Z * Z, axis=-1)[None, :]
     d2 = jnp.maximum(xn + zn - 2.0 * (X @ Z.T), 0.0)

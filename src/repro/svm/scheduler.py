@@ -91,6 +91,7 @@ from repro.svm import shrink as shrink_mod
 from repro.svm.engine import (EngineState, SMOResult, chunk_batched_jit,
                               chunk_batched_sources_jit, chunk_jit, finalize,
                               init_state, stack_sources)
+from repro.svm.precision import STATE_DTYPE
 from repro.svm.sources import SourceCache, is_factory
 
 
@@ -477,10 +478,9 @@ class LanePool:
                      after=after, shrink0=shrink0, tenant=tenant)
         if alpha0 is not None:
             if after is None:
-                # cache.meta answers dtype without materializing a factory
-                # source — intake must not force kernels into residency
-                lane.state = init_state(self.cache.meta(key), self._ys[key],
-                                        train_mask, alpha0, f0,
+                # intake must not force kernels into residency: the state
+                # needs no source at all
+                lane.state = init_state(train_mask, alpha0, f0,
                                         n_iter0=n_iter0)
                 self._attach_shrink(lane)
             else:   # held: built at admission, when ``after`` retires
@@ -548,9 +548,8 @@ class LanePool:
                 continue
             if lane.after is not None and lane.after not in self.results:
                 continue
-            meta, y = self.cache.meta(lane.source), self._ys[lane.source]
             if lane.dep is None:          # explicit start held by ``after``
-                lane.state = init_state(meta, y, lane.train_mask, lane.alpha0,
+                lane.state = init_state(lane.train_mask, lane.alpha0,
                                         lane.f0, n_iter0=lane.n_iter0)
                 lane.alpha0 = lane.f0 = None
                 self._attach_shrink(lane)
@@ -569,7 +568,7 @@ class LanePool:
             dt = (time.perf_counter() - t0) - (self.cache.kernel_time - k0)
             lane.seed_s += dt
             self.seed_time += dt
-            lane.state = init_state(meta, y, lane.train_mask, alpha0, f0)
+            lane.state = init_state(lane.train_mask, alpha0, f0)
             self._attach_shrink(lane)
             self._trace("admit", lane_id, lane.source)
 
@@ -604,7 +603,7 @@ class LanePool:
             Cs.append(live[0].C)
             caps.append(0)
         payload = (jnp.stack(masks),
-                   jnp.asarray(Cs, self.cache.meta(key).dtype),
+                   jnp.asarray(Cs, STATE_DTYPE),
                    jnp.asarray(caps, jnp.int64),
                    EngineState.stack(states))
         self._packed[key] = (tuple(ln.id for ln in live), payload)
@@ -863,7 +862,7 @@ class LanePool:
                     Cs.append(lanes[0].C)
                     it_caps.append(0)
                 out = chunk_batched_jit(
-                    src, y, jnp.stack(masks), jnp.asarray(Cs, src.dtype),
+                    src, y, jnp.stack(masks), jnp.asarray(Cs, STATE_DTYPE),
                     self.tol, jnp.asarray(it_caps, jnp.int64),
                     EngineState.stack(states), n_iters=self.chunk_iters,
                     wss=self.wss)
@@ -897,7 +896,7 @@ class LanePool:
                     it_caps.append(0)
                 out = chunk_batched_sources_jit(
                     stack_sources(srcs), jnp.stack(cys), jnp.stack(cmasks),
-                    jnp.asarray(Cs, src.dtype), stol,
+                    jnp.asarray(Cs, STATE_DTYPE), stol,
                     jnp.asarray(it_caps, jnp.int64),
                     EngineState.stack(cstates), n_iters=self.chunk_iters,
                     wss=self.wss)

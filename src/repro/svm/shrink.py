@@ -66,6 +66,7 @@ import jax.numpy as jnp
 
 from repro.svm.engine import (EngineState, SMOResult, _INF, _sets, chunk_jit,
                               finalize, init_state, optimality)
+from repro.svm.precision import kdot
 
 #: heuristic cadence when shrinking is enabled without an explicit period
 #: (``shrink_every="auto"`` resolves here when the cost model approves)
@@ -151,7 +152,7 @@ def _gap_of(alpha, f, y, mask, C):
 
 @jax.jit
 def _dense_f(K, y, alpha):
-    return K @ (alpha * y) - y
+    return kdot(K, alpha * y) - y
 
 
 def reconstruct_f(source, y, alpha):
@@ -382,7 +383,7 @@ def solve_shrunk(source, y, train_mask, C, alpha0, f0, *, tol: float = 1e-3,
         return engine.solve(source, y, train_mask, C, alpha0, f0, tol=tol,
                             max_iter=max_iter, wss=wss,
                             chunk_iters=chunk_iters, n_iter0=n_iter0)
-    state = init_state(source, y, train_mask, alpha0, f0, n_iter0=n_iter0)
+    state = init_state(train_mask, alpha0, f0, n_iter0=n_iter0)
     ls = LaneShrink(int(state.alpha.shape[0]), every=shrink_every,
                     quantum=shrink_quantum, caps=shrink_caps)
     if shrink_on_seed:

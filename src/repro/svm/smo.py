@@ -31,17 +31,18 @@ import jax.numpy as jnp
 
 from repro.svm.engine import (DenseKernel, SMOResult, _sets,  # noqa: F401
                               solve, solve_batched)
+from repro.svm.precision import kdot
 
 
 def init_f(K: jnp.ndarray, y: jnp.ndarray, alpha: jnp.ndarray) -> jnp.ndarray:
     """f_i = sum_j alpha_j y_j K_ij - y_i, for all i (masked or not)."""
-    return K @ (alpha * y) - y
+    return kdot(K, alpha * y) - y
 
 
 def dual_objective(K: jnp.ndarray, y: jnp.ndarray, alpha: jnp.ndarray) -> jnp.ndarray:
     """Paper Problem (1): sum(alpha) - 0.5 aT Q a with Q_ij = y_i y_j K_ij."""
     v = alpha * y
-    return jnp.sum(alpha) - 0.5 * (v @ (K @ v))
+    return jnp.sum(alpha) - 0.5 * (v @ kdot(K, v))
 
 
 def smo_solve(K: jnp.ndarray, y: jnp.ndarray, train_mask: jnp.ndarray,

@@ -12,6 +12,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.svm.precision import STATE_DTYPE, kdot, kernel_input
 from repro.svm.smo import SMOResult
 
 
@@ -32,7 +33,7 @@ def bias_from_solution(res: SMOResult, y: jnp.ndarray, train_mask: jnp.ndarray,
 @jax.jit
 def decision_function(K_test_train: jnp.ndarray, y_train: jnp.ndarray,
                       alpha: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    return K_test_train @ (alpha * y_train) + b
+    return kdot(K_test_train, alpha * y_train) + b
 
 
 def predict(K_test_train, y_train, alpha, b):
@@ -88,10 +89,12 @@ class SVC:
         from repro.core.study import Plan, run_plan
         from repro.svm.kernels import kernel_matrix
 
-        X = jnp.asarray(X, jnp.float64)
         y_pm = self._encode(y)
+        # gamma="scale" reads the data as given; the kernel reads it at
+        # the policy's kernel dtype
+        self.gamma_ = self._resolve_gamma(jnp.asarray(X, jnp.float64))
+        X = kernel_input(X)
         n = X.shape[0]
-        self.gamma_ = self._resolve_gamma(X)
         K = kernel_matrix(X, X, kind=self.kind, gamma=self.gamma_,
                           backend=self.kernel_backend)
         from repro.svm.engine import DenseKernel
@@ -99,7 +102,7 @@ class SVC:
                     shrink_every=self.shrink_every,
                     shrink_quantum=self.shrink_quantum)
         plan.lane("fit", train_mask=jnp.ones(n, bool), C=self.C,
-                  alpha0=jnp.zeros(n, K.dtype), f0=-y_pm,
+                  alpha0=jnp.zeros(n, STATE_DTYPE), f0=-y_pm,
                   max_iter=self.max_iter)
         sres = run_plan(plan)
         res = sres.results["fit"]
@@ -113,7 +116,7 @@ class SVC:
 
     def decision_function(self, X) -> jnp.ndarray:
         from repro.svm.kernels import kernel_matrix
-        Kt = kernel_matrix(jnp.asarray(X, jnp.float64), self.X_,
+        Kt = kernel_matrix(kernel_input(X), self.X_,
                            kind=self.kind, gamma=self.gamma_,
                            backend=self.kernel_backend)
         return decision_function(Kt, self.y_, self.result_.alpha, self.b_)

@@ -256,9 +256,6 @@ ELASTIC_SCRIPT = textwrap.dedent("""
 """)
 
 
-@pytest.mark.skipif(not hasattr(jax.sharding, "AxisType"),
-                    reason="subprocess harness uses jax.sharding.AxisType / "
-                           "make_mesh(axis_types=...); needs jax >= 0.5")
 @pytest.mark.parametrize("save_mesh,restore_mesh", [((4, 2), (2, 4)),
                                                     ((8, 1), (2, 4))])
 def test_elastic_restore_across_meshes(tmp_path, save_mesh, restore_mesh):

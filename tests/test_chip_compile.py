@@ -1,0 +1,109 @@
+"""Compile the main path's device programs for a described TPU v5e chip at
+adult's published shape (32,560 x 123 after the 10-fold truncation), with
+Pallas compiled (``interpret=False``). Nothing runs: the TPU compiler
+refuses here what the chip would refuse (f64 Pallas operands, int64 index
+maps, blocks over VMEM, programs over HBM), at no chip time.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library, and pytest-xdist workers all
+import this file."""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.rbf import rbf_kernel_matrix
+from repro.kernels.smo_step import compiled_blocks, fused_smo_step
+from repro.svm.engine import DenseKernel, EngineState, PallasRBF, chunk_jit
+
+N, D = 32560, 123
+HBM_BYTES = 16 * 10**9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    # the TPU library reads this once, when it loads: without it the
+    # compiler writes its logs outside the checkout
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no TPU here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    """ShapeDtypeStruct factory on one described chip, with the persistent
+    compilation cache off (a cache entry compiled for a described chip
+    cannot be read back without one)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one = SingleDeviceSharding(topo.devices[0])
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, total
+    return compiled
+
+
+def _state(spec):
+    return EngineState(spec((N,), jnp.float64), spec((N,), jnp.float64),
+                       spec((), jnp.int64), spec((), bool))
+
+
+def test_fused_smo_step_compiles_for_v5e(spec):
+    assert compiled_blocks(N, D) == (1480, D)   # divides n: no padded X copy
+    compiled = _compile(
+        lambda f, X, xij, sq: fused_smo_step(f, X, xij, sq, 0.37, gamma=0.5,
+                                             interpret=False),
+        spec((N,), jnp.float64), spec((N, D), jnp.float32),
+        spec((2, D), jnp.float32), spec((N,), jnp.float32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_rbf_kernel_matrix_compiles_for_v5e(spec):
+    compiled = _compile(
+        lambda a, b: rbf_kernel_matrix(a, b, 0.5, interpret=False),
+        spec((N, D), jnp.float32), spec((N, D), jnp.float32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_dense_chunk_compiles_for_v5e(spec):
+    """K float32 (4.2 GB), y/alpha/f float64: the precision policy's dense
+    program fits a 16 GB chip."""
+    _compile(lambda K, y, mask, st: chunk_jit(
+        DenseKernel(K), y, mask, 100.0, 1e-3, jnp.asarray(10**6, jnp.int64),
+        st, n_iters=4096, wss="2"),
+        spec((N, N), jnp.float32), spec((N,), jnp.float64),
+        spec((N,), bool), _state(spec))
+
+
+def test_pallas_chunk_compiles_for_v5e(spec):
+    compiled = _compile(lambda X, sq, y, mask, st: chunk_jit(
+        PallasRBF(X, 0.5, sq, interpret=False), y, mask, 100.0, 1e-3,
+        jnp.asarray(10**6, jnp.int64), st, n_iters=4096, wss="1"),
+        spec((N, D), jnp.float32), spec((N,), jnp.float32),
+        spec((N,), jnp.float64), spec((N,), bool), _state(spec))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_compiled_launch_refuses_f64_operands(spec):
+    with pytest.raises(TypeError, match="float32"):
+        jax.jit(lambda f, X, xij, sq: fused_smo_step(
+            f, X, xij, sq, 0.37, gamma=0.5, interpret=False)).lower(
+                spec((N,), jnp.float64), spec((N, D), jnp.float64),
+                spec((2, D), jnp.float64), spec((N,), jnp.float64))

@@ -344,18 +344,22 @@ def test_pallas_nbytes_is_data_not_matrix():
     assert isinstance(mat, PallasRBF) and mat.nbytes == spec.nbytes
 
 
-def test_dense_fupdate_pallas_bitwise():
-    """DenseKernel's opt-in pallas f-update replays the plain-jnp ops."""
+def test_dense_f32_kernel_solves_in_f64():
+    """The precision policy at the engine: an f32 K is read upcast, so the
+    solve is bit-identical to one over the same values held in f64 — the
+    state (alpha, f) is float64 either way, like LIBSVM's Qfloat K with
+    double gradients."""
     ds, X, K, y = _setup(n=120)
     n = y.shape[0]
     mask = jnp.ones(n, bool).at[:20].set(False)
-    base = solve(DenseKernel(K), y, mask, ds.C, jnp.zeros(n), -y)
-    pal = solve(DenseKernel(K, fupdate="pallas"), y, mask, ds.C,
-                jnp.zeros(n), -y)
-    np.testing.assert_array_equal(np.asarray(base.alpha),
-                                  np.asarray(pal.alpha))
-    np.testing.assert_array_equal(np.asarray(base.f), np.asarray(pal.f))
-    assert int(base.n_iter) == int(pal.n_iter)
+    K32 = K.astype(jnp.float32)
+    lo = solve(DenseKernel(K32), y, mask, ds.C, jnp.zeros(n), -y)
+    hi = solve(DenseKernel(K32.astype(jnp.float64)), y, mask, ds.C,
+               jnp.zeros(n), -y)
+    assert lo.alpha.dtype == lo.f.dtype == jnp.float64
+    np.testing.assert_array_equal(np.asarray(lo.alpha), np.asarray(hi.alpha))
+    np.testing.assert_array_equal(np.asarray(lo.f), np.asarray(hi.f))
+    assert int(lo.n_iter) == int(hi.n_iter) and bool(lo.converged)
 
 
 def test_run_cv_batched_pallas_backend():
@@ -386,10 +390,11 @@ def test_grid_pallas_resident_is_n2_independent():
     pal = run_grid(ds, source_backend="pallas_rbf", **kw)
     dense = run_grid(ds, **kw)
     n = pal.n
-    x_bytes = n * ds.X.shape[1] * 8
+    itemsize = 4   # kernel operands are float32 (svm/precision.py)
+    x_bytes = n * ds.X.shape[1] * itemsize
     assert pal.resident["peak_resident_bytes"] <= x_bytes
-    assert pal.resident["peak_resident_bytes"] < n * n * 8
-    assert dense.resident["peak_resident_bytes"] >= n * n * 8
+    assert pal.resident["peak_resident_bytes"] < n * n * itemsize
+    assert dense.resident["peak_resident_bytes"] >= n * n * itemsize
     for cp, cd in zip(pal.cells, dense.cells):
         assert (cp.C, cp.gamma) == (cd.C, cd.gamma)
         assert cp.accuracy == pytest.approx(cd.accuracy, abs=1e-12)

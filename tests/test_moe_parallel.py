@@ -4,9 +4,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
 SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -39,10 +36,6 @@ SCRIPT = textwrap.dedent("""
 """)
 
 
-@pytest.mark.skipif(
-    not (hasattr(jax.sharding, "AxisType") and hasattr(jax.sharding, "set_mesh")),
-    reason="subprocess harness uses jax.sharding.AxisType / set_mesh; "
-           "needs jax >= 0.5")
 def test_shard_map_moe_matches_scatter():
     env = dict(os.environ, PYTHONPATH="src")
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,

@@ -10,14 +10,17 @@ from repro.core.cv import _fold_masks, _transition_idx
 from repro.data.svm_suite import kfold_chunks, make_dataset
 from repro.svm import (DenseKernel, LaneScheduler, init_f, kernel_matrix,
                        smo_solve)
+from repro.svm.precision import kernel_input
 from repro.svm.scheduler import bucket_width
 
 SUITE = ("adult", "heart", "madelon", "mnist", "webdata")
 
 
 def _setup(name, n=140, k=4):
+    """Kernel and folds as the CV entry points build them: f32 kernel values
+    (``kernel_input``), f64 labels and state."""
     ds = make_dataset(name, n_override=n)
-    X = jnp.asarray(ds.X)
+    X = kernel_input(ds.X)
     y = jnp.asarray(ds.y, jnp.float64)
     K = kernel_matrix(X, X, gamma=ds.gamma)
     chunks = kfold_chunks(n, k, seed=0)

@@ -321,6 +321,45 @@ def test_ato_jit_drained_R_exits_early():
     assert float(jnp.max(jnp.abs(a_jit - a_ref))) < 0.2 * ds.C
 
 
+def test_ato_coupled_working_set(monkeypatch):
+    """A free set wider than ATO_MAX_M (adult at its published size keeps
+    ~90% of its rows free) takes the coupled selection, here over f32
+    kernel values as on the chip: the seed meets the box and equality
+    constraints, and the fold it starts converges to the cold solve's
+    held-out predictions."""
+    from repro.svm import bias_from_solution, decision_function
+    ds, K, y, chunks, res0, (S, R, T) = _fold_setup("adult", n=600, k=6)
+    monkeypatch.setattr(seeding, "ATO_MAX_M", 128)
+    coupled = []
+    ato_jit = seeding._ato_seed_jit
+
+    def spy(*args, **kw):
+        coupled.append(kw["coupled"])
+        return ato_jit(*args, **kw)
+
+    monkeypatch.setattr(seeding, "_ato_seed_jit", spy)
+    K32 = K.astype(jnp.float32)
+    alpha0 = seeding.ato_seed(K32, y, ds.C, res0, S, R, T)
+    assert coupled == [True]
+    eps = 1e-8 * max(ds.C, 1.0)
+    assert bool(jnp.all((alpha0 >= -eps) & (alpha0 <= ds.C + eps)))
+    assert float(jnp.abs(jnp.sum(alpha0 * y))) < 1e-6 * max(ds.C, 1.0)
+    assert float(jnp.abs(alpha0[R]).max()) == 0.0
+    nn = chunks.size
+    mask1 = jnp.ones(nn, bool).at[jnp.asarray(chunks[1])].set(False)
+    cold = smo_solve(K32, y, mask1, ds.C, jnp.zeros(nn), -y)
+    warm = smo_solve(K32, y, mask1, ds.C, alpha0, init_f(K32, y, alpha0))
+    assert bool(warm.converged)
+    t_idx = jnp.asarray(chunks[1])
+    dc = decision_function(K32[t_idx], y, cold.alpha,
+                           bias_from_solution(cold, y, mask1, ds.C))
+    dw = decision_function(K32[t_idx], y, warm.alpha,
+                           bias_from_solution(warm, y, mask1, ds.C))
+    differs = (dc >= 0) != (dw >= 0)
+    near_zero = (jnp.abs(dc) < 2e-3) | (jnp.abs(dw) < 2e-3)
+    assert bool(jnp.all(~differs | near_zero))
+
+
 def test_ato_seed_batch_matches_solo():
     """The vmapped batch entry (the grid's C-row path) reproduces the solo
     seeder lane for lane."""

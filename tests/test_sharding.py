@@ -2,13 +2,7 @@
 AbstractMesh lets us test the production 16x16 / 2x16x16 resolution logic
 without 512 real devices."""
 import pytest
-
-try:
-    from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
-except ImportError:  # pre-AxisType jax (< 0.5): no abstract-mesh axis types
-    pytest.skip("jax.sharding.AxisType/AbstractMesh unavailable on this jax "
-                "version; mesh-resolution tests need jax >= 0.5",
-                allow_module_level=True)
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
 from repro.sharding import DEFAULT_RULES, logical_to_pspec
 

@@ -15,6 +15,7 @@ from repro.core.study import Plan, StudyCheckpoint, run_plan
 from repro.data.svm_suite import kfold_chunks, make_dataset
 from repro.svm import (DenseKernel, LanePool, init_f, kernel_matrix,
                        smo_solve)
+from repro.svm.precision import kernel_input
 
 SUITE = ("adult", "heart", "madelon", "mnist", "webdata")
 GAMMA_SCALES = (0.5, 2.0)   # two sources per dataset: gamma/2 and 2*gamma
@@ -344,8 +345,9 @@ def test_bad_source_backend_fails_at_entry():
 
 def _loo_reference(ds, method, rounds, tol=1e-3, max_iter=2_000_000):
     """The pre-Study sequential LOO loop, kept inline as the parity oracle
-    for the plan-built ``run_loo``."""
-    X = jnp.asarray(ds.X)
+    for the plan-built ``run_loo``, under the entry points' precision policy
+    (f32 kernel values, f64 state)."""
+    X = kernel_input(ds.X)
     y = jnp.asarray(ds.y, jnp.float64)
     n = ds.n
     K = kernel_matrix(X, X, kind="rbf", gamma=ds.gamma)
