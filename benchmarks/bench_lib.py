@@ -13,7 +13,7 @@ def emit(name: str, rows: list[dict]) -> None:
         json.dump(rows, fh, indent=1)
     if rows:
         # column union in first-appearance order: rows are heterogeneous
-        # (occupancy / roofline / residency blocks appear per schedule)
+        # (occupancy / residency blocks appear per schedule)
         cols = list(dict.fromkeys(c for r in rows for c in r))
         print(",".join(cols))
         for r in rows:

@@ -1,6 +1,6 @@
-"""Benchmark driver — one section per paper table/figure plus the roofline
-report. ``python -m benchmarks.run [--quick]`` prints CSV per section and
-writes JSON under results/bench/.
+"""Benchmark driver — one section per paper table/figure.
+``python -m benchmarks.run [--quick]`` prints CSV per section and writes
+JSON under results/bench/.
 
 The table1 section additionally writes ``BENCH_table1.json`` at the repo
 root (cold vs cold_batched vs seeded methods) so the perf trajectory is
@@ -109,17 +109,16 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="small datasets / fewer k values")
     ap.add_argument("--only", default=None,
-                    help="table1|table3|fig2|roofline")
+                    help="table1|table3|fig2")
     args = ap.parse_args()
 
     from repro.compile_cache import enable_compile_cache
     enable_compile_cache()
-    from benchmarks import fig2_loo, roofline_report, table1_kfold, table3_vary_k
+    from benchmarks import fig2_loo, table1_kfold, table3_vary_k
     sections = {
         "table1": lambda: table1_kfold.run(quick=args.quick),
         "table3": lambda: table3_vary_k.run(quick=args.quick),
         "fig2": lambda: fig2_loo.run(quick=args.quick),
-        "roofline": lambda: roofline_report.run(quick=args.quick),
     }
     failed = []
     for name, fn in sections.items():

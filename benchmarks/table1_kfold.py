@@ -24,9 +24,8 @@ Beyond the paper's columns:
 * ``ato_ref`` — the eager host-side ATO loop that the jitted ramp
   replaced, kept as the jit baseline;
 * ``ato_shrink`` — ATO-seeded CV with active-set shrinking on (DESIGN.md
-  §Shrinking), carrying the unshrunk baseline, the seeding handoff
-  ablation, and an active-fraction-scaled ``hbm_per_iter`` block (see
-  ``_shrink_row``);
+  §Shrinking), carrying the unshrunk baseline and the seeding handoff
+  ablation (see ``_shrink_row``);
 * ``ato_bucketed`` — the batched ATO ramp across a 3-lane C row for every
   fold transition, with per-lane m_cap buckets (``init_s``) vs the
   historical widest-lane pad (``init_s_padded``); the bucketed ramp must
@@ -46,14 +45,11 @@ Beyond the paper's columns:
 * ``cold_pallas`` / ``grid_pooled_pallas`` — the matrix-free rows
   (DESIGN.md §Pallas sources): cold folds / a cold budgeted grid over
   row-streaming ``PallasRBF`` sources, never materializing an n² kernel.
-  Each row carries an ``hbm_per_iter`` block — the analytic per-iteration
-  HBM traffic of the dense vs fused-streaming source and the roofline
-  service time of the pallas stream (``launch/roofline.py`` bandwidth
-  model) — the accelerator-side signal these rows exist to track; on this
-  CPU container the interpret-mode kernels make their wall-clock an
+  On the CPU the interpret-mode kernels make their wall-clock an
   emulation artifact, so they time one rep on a reduced grid
   (``PALLAS_GRID``) and their ``peak_resident.bytes`` (X bytes, not n²)
-  is the load-bearing CPU-side number.
+  is the load-bearing CPU-side number; their device time is the chip
+  benchmark's (``bench/``).
 """
 from __future__ import annotations
 
@@ -66,7 +62,6 @@ from benchmarks.bench_lib import emit
 from repro.core import seeding
 from repro.core.cv import _fold_masks, _transition_idx, run_cv, run_cv_batched
 from repro.data.svm_suite import kfold_chunks, make_dataset
-from repro.launch.roofline import roofline_terms
 from repro.svm import (bias_from_solution, init_f, kernel_matrix, predict,
                        smo_solve_batched)
 from repro.svm.precision import kernel_input
@@ -100,35 +95,6 @@ SHRINK_EVERY_BENCH = 512
 #: row runs a 2x2 grid corner — enough cells to exercise multi-source
 #: residency accounting without dominating the bench wall-clock
 PALLAS_GRID = 2
-
-
-def _hbm_iter_estimate(n: int, d: int, active_frac: float = 1.0) -> dict:
-    """Analytic per-SMO-iteration HBM traffic (f64): the dense source
-    streams two (n,) kernel rows plus the solver state (f read+write,
-    alpha update); the fused pallas step streams X once (n*d) plus the
-    same state — one HBM pass per iteration regardless of n². memory_s is
-    the roofline service time of the pallas stream at the accelerator
-    bandwidth model's HBM_BW; with the MXU cross-term FLOPs alongside it
-    shows which side of the ridge a fused iteration sits on.
-
-    ``active_frac`` scales every per-iteration term to the compact working
-    set a shrunk lane dispatches over (DESIGN.md §Shrinking): kernel rows,
-    the X stream and the f/alpha state are all cap-length buffers, so the
-    whole block shrinks with the run's measured mean active fraction. The
-    full-set bytes are kept alongside for the artifact diff."""
-    m = max(1, int(round(active_frac * n)))
-    state = 3 * m * 8
-    dense = 2 * m * 8 + state
-    pallas = m * d * 8 + state
-    flops = 2.0 * m * d + 8.0 * m
-    rf = roofline_terms(flops, pallas, 0.0)
-    out = {"dense_bytes": dense, "pallas_bytes": pallas,
-           "memory_s": rf["memory_s"], "dominant": rf["dominant"]}
-    if active_frac != 1.0:
-        out["active_frac"] = round(float(active_frac), 4)
-        out["dense_bytes_full"] = 2 * n * 8 + 3 * n * 8
-        out["pallas_bytes_full"] = n * d * 8 + 3 * n * 8
-    return out
 
 
 def _grid_rows(name: str, reps: int) -> list[dict]:
@@ -183,8 +149,6 @@ def _grid_rows(name: str, reps: int) -> list[dict]:
                 "bytes": rep.resident["peak_resident_bytes"],
                 "materializations": rep.resident["materializations"],
                 "kernel_s": round(rep.kernel_time, 4)}
-        if method_name == "grid_pooled_pallas":
-            row["hbm_per_iter"] = _hbm_iter_estimate(rep.n, ds.X.shape[1])
         rows.append(row)
     return rows
 
@@ -199,11 +163,7 @@ def _shrink_row(name: str, k: int, reps: int) -> dict:
     ``shrink_on_seed=False``, so seeded lanes wait ``shrink_every``
     iterations to rediscover their bound-locked rows instead of starting
     shrunk). Fold accuracies are asserted identical to the unshrunk run —
-    shrinking preserves the full-set optimality contract. ``hbm_per_iter``
-    is scaled by the run's measured mean active fraction: on accelerators
-    the per-iteration bytes (and the roofline service time) shrink with
-    the working set, which is the signal this row exists to track on a
-    CPU container whose width-1 dispatch cost is overhead-dominated."""
+    shrinking preserves the full-set optimality contract."""
     ds = make_dataset(name, n_override=SIZES[name])
 
     def runner(**kw):
@@ -224,7 +184,6 @@ def _shrink_row(name: str, k: int, reps: int) -> dict:
     no_handoff = min((runner(**handoff_kw) for _ in range(reps)),
                      key=lambda r: r.total_solve_time)
 
-    frac = (on.occupancy or {}).get("mean_active_frac", 1.0)
     row = on.row()
     row.update({
         "method": "ato_shrink",
@@ -233,9 +192,7 @@ def _shrink_row(name: str, k: int, reps: int) -> dict:
         "solve_s_noshrink": round(off.total_solve_time, 4),
         "shrink_speedup": round(
             off.total_solve_time / max(on.total_solve_time, 1e-9), 3),
-        "solve_s_no_handoff": round(no_handoff.total_solve_time, 4),
-        "hbm_per_iter": _hbm_iter_estimate(on.n, ds.X.shape[1],
-                                           active_frac=frac)})
+        "solve_s_no_handoff": round(no_handoff.total_solve_time, 4)})
     if on.occupancy is not None:
         row["occupancy"] = on.occupancy
     return row
@@ -353,9 +310,6 @@ def run(k: int = 10, quick: bool = False, reps: int = 3):
                 / max(rep.total_iterations, 1), 2)
             if rep.occupancy is not None:
                 row["occupancy"] = rep.occupancy
-            if method == "cold_pallas":
-                row["hbm_per_iter"] = _hbm_iter_estimate(rep.n,
-                                                         ds.X.shape[1])
             rows.append(row)
         rows.append(_shrink_row(name, k, reps))
         rows.append(_ato_bucketed_row(name, k, reps))

@@ -52,13 +52,13 @@ import base64
 import dataclasses
 import functools
 import math
-import time
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import seeding
 from repro.svm import shrink as shrink_mod
 from repro.svm.engine import (DenseKernel, EngineState, SMOResult,
@@ -542,6 +542,7 @@ def _make_seed_fn(plan: Plan, spec: LaneSpec, resolve):
         alpha0 = fn(K, y, C, prev, **params)
         return alpha0, init_f(K, y, alpha0)
 
+    seed.transform = spec.transform       # named by the repro.pool.seed span
     return seed
 
 
@@ -832,84 +833,93 @@ def run_plan(plan: Plan, *, checkpoint: StudyCheckpoint | None = None,
     if analysis not in ("advisory", "strict", "off"):
         raise ValueError(f"unknown analysis mode {analysis!r} "
                          "(have 'advisory', 'strict', 'off')")
-    plan = resolve_source_backend(plan)
+    with obs.span(obs.PLAN) as plan_span:
+        with obs.span("repro.plan.prepare"):
+            plan = resolve_source_backend(plan)
+            specs = plan_specs(plan)
+            _validate_plan(plan, specs)
 
-    specs = plan_specs(plan)
-    _validate_plan(plan, specs)
+        plan_analysis = None
+        if analysis != "off":
+            # deferred import: plan_check imports this module for the
+            # validation surface and STUDY_BASE
+            from repro.analysis import plan_check
+            with obs.span("repro.plan.analyze"):
+                if analysis == "strict":
+                    plan_analysis = plan_check.check_plan(
+                        plan, checkpoint=checkpoint)
+                else:
+                    plan_analysis = plan_check.analyze_plan(
+                        plan, checkpoint=checkpoint)
 
-    plan_analysis = None
-    if analysis != "off":
-        # deferred import: plan_check imports this module for the
-        # validation surface and STUDY_BASE
-        from repro.analysis import plan_check
-        if analysis == "strict":
-            plan_analysis = plan_check.check_plan(plan,
-                                                  checkpoint=checkpoint)
-        else:
-            plan_analysis = plan_check.analyze_plan(plan,
-                                                    checkpoint=checkpoint)
+        with obs.span("repro.plan.prepare"):
+            step0, restored = restore_study_lanes(checkpoint)
 
-    step0, restored = restore_study_lanes(checkpoint)
+        on_snapshot = None
+        if checkpoint is not None:
+            counter = {"c": max(step0, checkpoint.base_step)}
 
-    on_snapshot = None
-    if checkpoint is not None:
-        counter = {"c": max(step0, checkpoint.base_step)}
+            def on_snapshot(pool):
+                counter["c"] += 1
+                lane_ids, tree = pool.snapshot_lanes()
+                checkpoint.manager.save(
+                    counter["c"], tree,
+                    extra_meta={"phase": checkpoint.phase,
+                                "lane_ids": lane_ids, **checkpoint.meta},
+                    blocking=False, retain_class=checkpoint.retain_class)
 
-        def on_snapshot(pool):
-            counter["c"] += 1
-            lane_ids, tree = pool.snapshot_lanes()
-            checkpoint.manager.save(
-                counter["c"], tree,
-                extra_meta={"phase": checkpoint.phase, "lane_ids": lane_ids,
-                            **checkpoint.meta},
-                blocking=False, retain_class=checkpoint.retain_class)
+        with obs.span("repro.pool.build"):
+            pool = LanePool(
+                plan.sources, plan.y, tol=plan.tol, wss=plan.wss,
+                chunk_iters=plan.chunk_iters, lane_quantum=plan.lane_quantum,
+                max_width=plan.max_width, max_resident=plan.max_resident,
+                cache_bytes=plan.cache_bytes, on_snapshot=on_snapshot,
+                snapshot_every=checkpoint.every if checkpoint else 1,
+                on_result=on_result, on_lane_chunk=on_lane_chunk,
+                shrink_every=plan.shrink_every,
+                shrink_quantum=plan.shrink_quantum,
+                shrink_caps=plan.shrink_caps,
+                shrink_on_seed=plan.shrink_on_seed)
+            pre_done = enroll_plan_lanes(pool, plan, specs, restored,
+                                         tenant=tenant)
 
-    pool = LanePool(plan.sources, plan.y, tol=plan.tol, wss=plan.wss,
-                    chunk_iters=plan.chunk_iters,
-                    lane_quantum=plan.lane_quantum, max_width=plan.max_width,
-                    max_resident=plan.max_resident,
-                    cache_bytes=plan.cache_bytes,
-                    on_snapshot=on_snapshot,
-                    snapshot_every=checkpoint.every if checkpoint else 1,
-                    on_result=on_result, on_lane_chunk=on_lane_chunk,
-                    shrink_every=plan.shrink_every,
-                    shrink_quantum=plan.shrink_quantum,
-                    shrink_caps=plan.shrink_caps,
-                    shrink_on_seed=plan.shrink_on_seed)
+        kt0 = pool.cache.kernel_time
+        with obs.span("repro.pool.run") as run_span:
+            results = pool.run()
+            with obs.span("repro.pool.wait"):
+                jax.block_until_ready([results[s.id].alpha
+                                       for s in plan.lanes])
+        # kernel materializations during the run are attributed to the cache's
+        # kernel_time (source_stats), not to seed or solve time
+        wall = run_span.seconds - (pool.cache.kernel_time - kt0)
+        if checkpoint is not None:
+            checkpoint.manager.wait()
 
-    pre_done = enroll_plan_lanes(pool, plan, specs, restored, tenant=tenant)
+        stats = {}
+        for spec in plan.lanes:
+            res = results[spec.id]
+            seed_s, solve_s = pool.lane_times(spec.id)
+            stats[spec.id] = LaneStat(
+                n_iter=int(res.n_iter), converged=bool(res.converged),
+                seed_s=seed_s, solve_s=solve_s, restored=spec.id in pre_done)
 
-    t0 = time.perf_counter()
-    kt0 = pool.cache.kernel_time
-    results = pool.run()
-    jax.block_until_ready([results[s.id].alpha for s in plan.lanes])
-    # kernel materializations during the run are attributed to the cache's
-    # kernel_time (source_stats), not to seed or solve time
-    wall = (time.perf_counter() - t0) - (pool.cache.kernel_time - kt0)
-    if checkpoint is not None:
-        checkpoint.manager.wait()
-
-    stats = {}
-    for spec in plan.lanes:
-        res = results[spec.id]
-        seed_s, solve_s = pool.lane_times(spec.id)
-        stats[spec.id] = LaneStat(
-            n_iter=int(res.n_iter), converged=bool(res.converged),
-            seed_s=seed_s, solve_s=solve_s, restored=spec.id in pre_done)
-
-    evals = run_plan_evals(pool, plan, specs, results)
-    occupancy, source_stats = pool.occupancy, pool.cache.stats
-    # the pool and its cache reference each other (eviction and trace
-    # callbacks), so they outlive this call until Python's cycle collector
-    # runs; dropping the sources now frees a dense K (4.2 GB at adult's
-    # size) when the caller lets go of it, before the next entry-point call
-    # builds its own
-    for key in list(pool.sources):
-        pool.remove_source(key)
-
-    return StudyResult(results=results, stats=stats, evals=evals,
-                       occupancy=occupancy, seed_time=pool.seed_time,
-                       solve_time=wall - pool.seed_time,
-                       restored=frozenset(pre_done),
-                       source_stats=source_stats,
-                       analysis=plan_analysis, tenant=tenant)
+        with obs.span("repro.plan.evals"):
+            evals = run_plan_evals(pool, plan, specs, results)
+        occupancy, source_stats = pool.occupancy, pool.cache.stats
+        # the pool and its cache reference each other (eviction and trace
+        # callbacks), so they outlive this call until Python's cycle collector
+        # runs; dropping the sources now frees a dense K (4.2 GB at adult's
+        # size) when the caller lets go of it, before the next entry-point call
+        # builds its own
+        with obs.span("repro.plan.release"):
+            for key in list(pool.sources):
+                pool.remove_source(key)
+        plan_span.attrs["lanes"] = tuple(
+            (lane_id, st.n_iter) for lane_id, st in stats.items()
+            if not st.restored)
+        return StudyResult(results=results, stats=stats, evals=evals,
+                           occupancy=occupancy, seed_time=pool.seed_time,
+                           solve_time=wall - pool.seed_time,
+                           restored=frozenset(pre_done),
+                           source_stats=source_stats,
+                           analysis=plan_analysis, tenant=tenant)

@@ -79,13 +79,13 @@ label vector) used by callers predating the pool.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.svm import cost_model
 from repro.svm import shrink as shrink_mod
 from repro.svm.engine import (EngineState, SMOResult, chunk_batched_jit,
@@ -561,11 +561,14 @@ class LanePool:
             # (lazy K resolution, core/study.py); that wall time is KERNEL
             # time, not seed time — subtract the cache's delta so the
             # paper's "init." column stays a seeding measurement
-            t0 = time.perf_counter()
             k0 = self.cache.kernel_time
-            alpha0, f0 = lane.seed_fn(self.results[lane.dep])
-            jax.block_until_ready((alpha0, f0))
-            dt = (time.perf_counter() - t0) - (self.cache.kernel_time - k0)
+            with obs.span("repro.pool.seed", lane=lane_id,
+                          transform=getattr(lane.seed_fn, "transform",
+                                            None)) as sp:
+                alpha0, f0 = lane.seed_fn(self.results[lane.dep])
+                jax.block_until_ready((alpha0, f0))
+                sp.attrs["kernel_s"] = self.cache.kernel_time - k0
+            dt = sp.seconds - sp.attrs["kernel_s"]
             lane.seed_s += dt
             self.seed_time += dt
             lane.state = init_state(lane.train_mask, alpha0, f0)
@@ -578,13 +581,14 @@ class LanePool:
                 and self._lanes[i].result is None]
 
     def _retire(self, lane: _Lane) -> None:
-        lane.result = finalize(lane.state, self._ys[lane.source],
-                               lane.train_mask, lane.C, self.tol)
-        self.results[lane.id] = lane.result
-        if self.on_trace is not None:     # int() syncs — only when tracing
-            self._trace("retire", lane.id, int(lane.result.n_iter))
-        if self.on_result is not None:
-            self.on_result(lane.id, lane.result)
+        with obs.span("repro.pool.retire", lane=lane.id):
+            lane.result = finalize(lane.state, self._ys[lane.source],
+                                   lane.train_mask, lane.C, self.tol)
+            self.results[lane.id] = lane.result
+            if self.on_trace is not None:  # int() syncs — only when tracing
+                self._trace("retire", lane.id, int(lane.result.n_iter))
+            if self.on_result is not None:
+                self.on_result(lane.id, lane.result)
 
     def _pack(self, key, live: list[_Lane]) -> None:
         """Gather a source group's live lanes into a compact batch of
@@ -736,20 +740,21 @@ class LanePool:
             else:
                 key, cap = gkey, 0
                 self._programs.add((key, width))
-            self._trace("dispatch", chunk, key, cap, width,
-                        tuple(ln.id for ln in lanes))
+            ids = tuple(ln.id for ln in lanes)
+            self._trace("dispatch", chunk, key, cap, width, ids)
             # dispatch may materialize the group's kernel through the
             # cache; that delta is kernel time, not solve time
-            t0 = time.perf_counter()
             k0 = self.cache.kernel_time
-            if self.shrink_every:
-                self._step_shrink(key, cap, lanes)
-            elif len(lanes) == 1:
-                self._step_single(lanes[0])
-            else:
-                self._step_batched(key, lanes)
-            dt = (time.perf_counter() - t0) \
-                - (self.cache.kernel_time - k0)
+            with obs.span("repro.pool.dispatch", chunk=chunk, width=width,
+                          lanes=ids) as sp:
+                if self.shrink_every:
+                    self._step_shrink(key, cap, lanes)
+                elif len(lanes) == 1:
+                    self._step_single(lanes[0])
+                else:
+                    self._step_batched(key, lanes)
+                sp.attrs["kernel_s"] = self.cache.kernel_time - k0
+            dt = sp.seconds - sp.attrs["kernel_s"]
             for lane in lanes:
                 lane.solve_s += dt / len(lanes)
         self._width_log.append((len(live), dispatched))
@@ -795,7 +800,9 @@ class LanePool:
                                self.tol, jnp.asarray(lane.max_iter, jnp.int64),
                                lane.state, n_iters=self.chunk_iters,
                                wss=self.wss)
-        if bool(lane.state.done):
+        with obs.span("repro.pool.wait"):
+            done = bool(lane.state.done)
+        if done:
             self._retire(lane)
 
     def _step_batched(self, key, lanes: list[_Lane]) -> None:
@@ -817,7 +824,8 @@ class LanePool:
                                    Cs, self.tol, caps, states,
                                    n_iters=self.chunk_iters, wss=self.wss)
         self._packed[key] = (ids, (masks, Cs, caps, states))
-        done = np.asarray(states.done[:len(lanes)])   # one (w,) transfer
+        with obs.span("repro.pool.wait"):
+            done = np.asarray(states.done[:len(lanes)])   # one (w,) transfer
         if done.any():
             self._writeback(key)
             for flag, lane in zip(done, lanes):
@@ -842,8 +850,9 @@ class LanePool:
             if lane.shrink.cap and lane.shrink.idx is None:
                 lane.shrink.enter(src, y, lane.state)
         if cap == 0:
-            it_caps = [ln.shrink.it_cap(int(ln.state.n_iter), ln.max_iter)
-                       for ln in lanes]
+            with obs.span("repro.pool.wait"):
+                it_caps = [ln.shrink.it_cap(int(ln.state.n_iter),
+                                            ln.max_iter) for ln in lanes]
             if len(lanes) == 1:
                 ln = lanes[0]
                 ln.state = chunk_jit(src, y, ln.train_mask, ln.C, self.tol,
@@ -870,8 +879,9 @@ class LanePool:
                     ln.state = out.lane(i)
         else:
             stol = 10.0 * self.tol
-            it_caps = [ln.shrink.it_cap(int(ln.shrink.cstate.n_iter),
-                                        ln.max_iter) for ln in lanes]
+            with obs.span("repro.pool.wait"):
+                it_caps = [ln.shrink.it_cap(int(ln.shrink.cstate.n_iter),
+                                            ln.max_iter) for ln in lanes]
             if len(lanes) == 1:
                 ls = lanes[0].shrink
                 ls.cstate = chunk_jit(ls.csrc, ls.cy, ls.cmask, lanes[0].C,

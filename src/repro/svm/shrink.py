@@ -64,6 +64,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.svm.engine import (EngineState, SMOResult, _INF, _sets, chunk_jit,
                               finalize, init_state, optimality)
 from repro.svm.precision import kdot
@@ -316,7 +317,9 @@ def advance(ls: LaneShrink, source, y, train_mask, C, full: EngineState, *,
     if ls.shrunk:
         st = ls.cstate
         full = ls.scatter(full)
-        if not bool(st.done):
+        with obs.span("repro.pool.wait"):
+            done = bool(st.done)
+        if not done:
             return full, "run"
         n_it = int(st.n_iter)
         Cd = jnp.asarray(C, st.alpha.dtype)
@@ -344,7 +347,9 @@ def advance(ls: LaneShrink, source, y, train_mask, C, full: EngineState, *,
             ls.tighten(act_c, m_new)
         return full, "run"
 
-    if not bool(full.done):
+    with obs.span("repro.pool.wait"):
+        done = bool(full.done)
+    if not done:
         return full, "run"
     n_it = int(full.n_iter)
     gap = float(_gap_of(full.alpha, full.f, y, train_mask,

@@ -35,12 +35,12 @@ source is written back to the lanes *before* the kernel is dropped
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.svm.engine import DenseKernel, PallasRBF
 from repro.svm.kernels import kernel_matrix
 
@@ -357,12 +357,12 @@ class SourceCache:
             return src
         spec = self._entries[key]
         self._evict_for(_source_nbytes(spec))
-        t0 = time.perf_counter()
         # sources are pytrees: block on the product so kernel_time measures
         # the materialization, not its dispatch (the dense path blocks
         # inside materialize; the row-streaming path holds only X)
-        src = jax.block_until_ready(spec.materialize())
-        self.kernel_time += time.perf_counter() - t0
+        with obs.span("repro.cache.materialize", source=key) as sp:
+            src = jax.block_until_ready(spec.materialize())
+        self.kernel_time += sp.seconds
         self.materializations += 1
         self.check_fused(key, src)
         self._resident[key] = src
