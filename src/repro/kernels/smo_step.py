@@ -7,12 +7,14 @@ for the maximal-violating pair plus the indicator update
 HBM per iteration (two kernel rows + the f read-modify-write) *after*
 having paid n^2 bytes to materialize K. This kernel never forms K at all:
 one blocked pass over X computes both rows — the cross-term
-``X @ [x_i; x_j]^T`` runs on the MXU over (BM, 2) output tiles with a
-BK-chunked contraction accumulated in VMEM scratch, row norms stream in
-as (BM, 1) tiles, and the ``exp`` fuses on the VPU at the final
-contraction step. One HBM stream (X plus three n-vectors) per iteration,
-O(n*d) resident bytes instead of O(n^2): the TPU-native version of
-``FusedRBF.rows2``.
+``X @ [x_i; x_j]^T`` runs on the MXU into a (BM, 2) VMEM accumulator over
+a BK-chunked contraction, and at the final contraction step the finished
+tile is transposed to (2, BM) and the ``exp`` fuses on the VPU. Row norms
+stream in and the row pair streams out lane-dense, as (1, BM) and
+(2, BM) blocks of (G, 1, BM) and (G, 2, BM) arrays (G = N / BM), so no
+per-row vector is padded to 128 lanes in HBM. One HBM stream (X plus
+three n-vectors) per iteration, O(n*d) resident bytes instead of O(n^2):
+the TPU-native version of ``FusedRBF.rows2``.
 
 Precision (``svm/precision.py``): the launch takes the kernel-value
 operands only (X, norms, the pair rows — float32 on the chip) and returns
@@ -25,8 +27,9 @@ traffic is two n-vectors, against the n*d bytes of X.
 Parity contract (the acceptance bar for ``PallasRBF``): with full-array
 blocks (``bm=n``, ``bk=d`` — the interpret-mode default) there is no
 padding and a single grid step, so the kernel body is the jnp expression
-``exp(-g*d2)`` that ``FusedRBF.rows2`` evaluates — same ops, same shapes,
-same accumulation order — and the update outside is the engine's own
+``exp(-g*d2)`` that ``FusedRBF.rows2`` evaluates — same ops, same
+accumulation order, each element of d2 from the same operands (the
+transpose only moves values) — and the update outside is the engine's own
 expression. The output agrees with the oracle to within 1 ulp: XLA may
 fuse the oracle's update differently from this function's (jax 0.9 moves
 1 element in 150 by 1 ulp), and the engine-level FusedRBF/PallasRBF
@@ -57,6 +60,7 @@ VMEM_BUDGET = 8 * 1024 * 1024
 #: tallest row block; beyond it the per-step overhead is already small
 MAX_BM = 4096
 _LANES = 128
+_SUBLANES = 8
 
 
 def compiled_blocks(n: int, d: int, itemsize: int = 4) -> tuple[int, int]:
@@ -64,14 +68,15 @@ def compiled_blocks(n: int, d: int, itemsize: int = 4) -> tuple[int, int]:
 
     ``bk`` is the whole feature axis (a block dim equal to the array dim
     needs no 128-alignment) up to 512 columns, else 512. Per row of ``bm``
-    a step holds the X tile and the (bm, 1) norm, (bm, 2) output and
-    (bm, 2) accumulator blocks, each lane-padded to 128 and all but the
-    scratch double-buffered. ``bm`` is the largest multiple of 8 dividing
-    n that fits the budget (no padding), else the largest power of two.
+    a step holds the X tile and the (bm, 2) accumulator, each lane-padded
+    to 128, and a column of the (1, bm) norm and (2, bm) output blocks,
+    each sublane-padded to 8; all but the scratch are double-buffered.
+    ``bm`` is the largest multiple of 8 dividing n that fits the budget (no
+    padding), else the largest power of two.
     """
     bk = d if d <= 512 else 512
     lanes_x = -(-bk // _LANES) * _LANES
-    per_row = (2 * lanes_x + 2 * _LANES + 2 * _LANES + _LANES) * itemsize
+    per_row = (2 * lanes_x + 2 * _SUBLANES + 2 * _SUBLANES + _LANES) * itemsize
     cap = min(MAX_BM, VMEM_BUDGET // per_row)
     cap -= cap % 8
     divisors = [m for m in range(8, min(cap, n) + 1, 8) if n % m == 0]
@@ -100,9 +105,11 @@ def _smo_step_kernel(xn_ref, sn2_ref, x_ref, xij_ref, o_ref, acc_ref, *,
 
     @pl.when(k_step == n_k_steps - 1)
     def _finalize():
-        d2 = jnp.maximum(xn_ref[...] + sn2_ref[...] - 2.0 * acc_ref[...],
-                         0.0)
-        o_ref[...] = jnp.exp(-gamma * d2).astype(o_ref.dtype)
+        # the finished (bm, 2) cross term turned lane-dense; a transpose
+        # moves values without rounding, so each element sees the same ops
+        cross = acc_ref[...].T                                  # (2, bm)
+        d2 = jnp.maximum(xn_ref[0] + sn2_ref[...] - 2.0 * cross, 0.0)
+        o_ref[0] = jnp.exp(-gamma * d2).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -136,7 +143,7 @@ def fused_smo_step(f, X, xij, sq_norms, delta, *, gamma: float,
     # norms of the pair rows, computed before any padding so the reduction
     # matches FusedRBF.rows2 verbatim
     acc_dtype = jnp.float64 if X.dtype == jnp.float64 else jnp.float32
-    sn2 = jnp.sum(xij * xij, 1)[None].astype(acc_dtype)          # (1, 2)
+    sn2 = jnp.sum(xij * xij, 1)[:, None].astype(acc_dtype)       # (2, 1)
     pad_n, pad_d = (-n) % bm, (-d) % bk
     # zero feature columns leave cross-terms and norms unchanged; padded
     # rows are sliced off the output. A block that divides the array
@@ -145,24 +152,26 @@ def fused_smo_step(f, X, xij, sq_norms, delta, *, gamma: float,
     if pad_n or pad_d:
         Xp = jnp.pad(X, ((0, pad_n), (0, pad_d)))
         xijp = jnp.pad(xij, ((0, 0), (0, pad_d)))
-    xn = jnp.pad(sq_norms, (0, pad_n))[:, None].astype(acc_dtype)
     N, D = n + pad_n, d + pad_d
-    n_k_steps = D // bk
+    G, n_k_steps = N // bm, D // bk
+    # row norms along lanes, one (1, bm) row per row block: a block dim
+    # equal to the array dim needs no 128-alignment, so any bm is legal
+    xn = jnp.pad(sq_norms, (0, pad_n)).astype(acc_dtype).reshape(G, 1, bm)
 
     K2 = pl.pallas_call(
         functools.partial(_smo_step_kernel, gamma=gamma,
                           n_k_steps=n_k_steps),
-        grid=(N // bm, n_k_steps),
+        grid=(G, n_k_steps),
         in_specs=[
-            pl.BlockSpec((bm, 1), lambda i, k: (i, _I0)),     # row norms
-            pl.BlockSpec((1, 2), lambda i, k: (_I0, _I0)),    # pair norms
-            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),      # X
-            pl.BlockSpec((2, bk), lambda i, k: (_I0, k)),     # pair rows
+            pl.BlockSpec((1, 1, bm), lambda i, k: (i, _I0, _I0)),  # row norms
+            pl.BlockSpec((2, 1), lambda i, k: (_I0, _I0)),     # pair norms
+            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),       # X
+            pl.BlockSpec((2, bk), lambda i, k: (_I0, k)),      # pair rows
         ],
-        out_specs=pl.BlockSpec((bm, 2), lambda i, k: (i, _I0)),
-        out_shape=jax.ShapeDtypeStruct((N, 2), X.dtype),
+        out_specs=pl.BlockSpec((1, 2, bm), lambda i, k: (i, _I0, _I0)),
+        out_shape=jax.ShapeDtypeStruct((G, 2, bm), X.dtype),
         scratch_shapes=[pltpu.VMEM((bm, 2), acc_dtype)],
         interpret=interpret,
     )(xn, sn2, Xp, xijp)
-    K2 = K2[:n].astype(f.dtype)
-    return f + jnp.asarray(delta, f.dtype) * (K2[:, 0] - K2[:, 1])
+    K2 = jnp.swapaxes(K2, 0, 1).reshape(2, N)[:, :n].astype(f.dtype)
+    return f + jnp.asarray(delta, f.dtype) * (K2[0] - K2[1])

@@ -8,6 +8,7 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and pytest-xdist workers all
 import this file."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +17,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.rbf import rbf_kernel_matrix
 from repro.kernels.smo_step import compiled_blocks, fused_smo_step
-from repro.svm.engine import DenseKernel, EngineState, PallasRBF, chunk_jit
+from repro.svm.engine import (DenseKernel, EngineState, PallasRBF,
+                              chunk_batched_jit, chunk_jit)
 
 N, D = 32560, 123
 HBM_BYTES = 16 * 10**9
@@ -66,7 +68,7 @@ def _state(spec):
 
 
 def test_fused_smo_step_compiles_for_v5e(spec):
-    assert compiled_blocks(N, D) == (1480, D)   # divides n: no padded X copy
+    assert compiled_blocks(N, D) == (3256, D)   # divides n: no padded X copy
     compiled = _compile(
         lambda f, X, xij, sq: fused_smo_step(f, X, xij, sq, 0.37, gamma=0.5,
                                              interpret=False),
@@ -92,13 +94,37 @@ def test_dense_chunk_compiles_for_v5e(spec):
         spec((N,), bool), _state(spec))
 
 
+def _assert_lane_dense(text):
+    """The kernel is in the program, and its row norms and row pair travel
+    lane-dense: no (n, 1) or (n, 2) array, padded to 128 lanes in HBM
+    (16.7 MB at adult's n), is built or split in the SMO loop."""
+    assert "tpu_custom_call" in text
+    assert re.search(rf"f32\[(\d+,)?{N},[12]\]", text) is None
+
+
 def test_pallas_chunk_compiles_for_v5e(spec):
     compiled = _compile(lambda X, sq, y, mask, st: chunk_jit(
         PallasRBF(X, 0.5, sq, interpret=False), y, mask, 100.0, 1e-3,
         jnp.asarray(10**6, jnp.int64), st, n_iters=4096, wss="1"),
         spec((N, D), jnp.float32), spec((N,), jnp.float32),
         spec((N,), jnp.float64), spec((N,), bool), _state(spec))
-    assert "tpu_custom_call" in compiled.as_text()
+    _assert_lane_dense(compiled.as_text())
+
+
+def test_batched_pallas_chunk_compiles_for_v5e(spec):
+    """Vmapped lanes: the pallas_call batching rule adds a grid axis, and
+    the layout holds across it."""
+    lanes = 3
+    states = EngineState(spec((lanes, N), jnp.float64),
+                         spec((lanes, N), jnp.float64),
+                         spec((lanes,), jnp.int64), spec((lanes,), bool))
+    compiled = _compile(lambda X, sq, y, masks, Cs, st: chunk_batched_jit(
+        PallasRBF(X, 0.5, sq, interpret=False), y, masks, Cs, 1e-3,
+        jnp.asarray(10**6, jnp.int64), st, n_iters=4096, wss="1"),
+        spec((N, D), jnp.float32), spec((N,), jnp.float32),
+        spec((N,), jnp.float64), spec((lanes, N), bool),
+        spec((lanes,), jnp.float64), states)
+    _assert_lane_dense(compiled.as_text())
 
 
 def test_compiled_launch_refuses_f64_operands(spec):

@@ -81,6 +81,8 @@ def _step_problem(n, d, dtype):
     (257, 9, 64, 64),     # ragged n, d < bk (feature axis fully padded)
     (100, 130, 64, 64),   # ragged on both axes, multi-step k loop
     (120, 40, 32, 16),    # multi-block on both axes
+    (160, 13, 40, 13),    # bm divides n over 4 lane-dense blocks, bk = d
+    (150, 13, 40, 13),    # bm does not divide n: padded rows, bk = d
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
 def test_fused_smo_step_ragged(n, d, bm, bk, dtype):
