@@ -45,9 +45,9 @@ from repro.core import seeding
 from repro.core.study import Plan, StudyCheckpoint, run_plan
 from repro.data.svm_suite import SVMDataset, kfold_chunks
 from repro.svm import (DenseKernel, PallasRBF, bias_from_solution,
-                       dual_objective, kernel_matrix, predict,
+                       kernel_matrix, predict,
                        smo_solve_batched)
-from repro.svm.precision import STATE_DTYPE, kernel_input
+from repro.svm.precision import STATE_DTYPE, kdot, kernel_input
 
 # step numbering inside a checkpoint directory: fold h's mid-fold chunk
 # snapshots live at h*_FOLD_STRIDE + 1 + chunk, its completion record at
@@ -139,13 +139,22 @@ def _transition_idx(chunks: np.ndarray, g: int, h: int):
 def _eval_fold(K, y, chunks, h, res, C) -> tuple[int, int, float]:
     """(acc_correct, acc_total, objective) of fold h's held-out chunk —
     the one evaluation path shared by the live CV loop, the batched driver
-    and the checkpoint-restore rebuild, so they cannot drift apart."""
+    and the checkpoint-restore rebuild, so they cannot drift apart.
+
+    One product ``K @ (alpha * y)`` gives every row's decision value and
+    the dual objective's quadratic term (``dual_objective``'s own
+    expression); the held-out rows are kept. A gather of K's test rows
+    would be a second (n / k, n) buffer: 1.44 GB beside mnist's 14.4 GB
+    K, more than one chip holds."""
     test_idx = jnp.asarray(chunks[h])
     train_mask = jnp.ones(chunks.size, bool).at[test_idx].set(False)
     b = bias_from_solution(res, y, train_mask, C)
-    pred = predict(K[test_idx], y, res.alpha, b)
+    v = res.alpha * y
+    Kv = kdot(K, v)
+    pred = jnp.where(Kv[test_idx] + b >= 0, 1, -1)
+    obj = jnp.sum(res.alpha) - 0.5 * (v @ Kv)
     return (int(jnp.sum(pred == y[test_idx])), int(test_idx.shape[0]),
-            float(dual_objective(K, y, res.alpha)))
+            float(obj))
 
 
 def _eval_fold_rows(source, y, chunks, h, res, C) -> tuple[int, int, float]:
@@ -225,6 +234,7 @@ def run_cv(ds: SVMDataset, k: int = 10, method: str = "sir",
                       backend=kernel_backend)
     K.block_until_ready()
     kernel_time = time.perf_counter() - t0
+    del X   # only K is read from here on
     y = y[:n]
     masks = jnp.asarray(_fold_masks(chunks))
 

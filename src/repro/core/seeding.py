@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import jax.scipy.linalg
 import numpy as np
 
+from repro import obs
 from repro.svm.precision import STATE_DTYPE, kdot
 from repro.svm.smo import SMOResult
 
@@ -151,28 +152,108 @@ def cold_seed(K, y, C, prev, S_idx, R_idx, T_idx, **_):
 #: directions alone (DESIGN.md §Precision policy)
 MIR_RIDGE = 1e-4
 
+#: kernel columns per block of MIR's assembly: a block is one (n, 512)
+#: column slab of K (123 MB at mnist's n = 60,000) and the (|T| + |R|, 512)
+#: rows of it that the system reads
+MIR_BLOCK = 512
 
-def _least_squares(A, b):
-    """``argmin ||A x - b||`` in ``A``'s dtype, returned as f64 state.
 
-    An f64 ``A`` (the reference path) takes LAPACK-style ``lstsq``. An
-    f32 ``A`` takes the ridge-regularized normal equations through a
-    Cholesky factor: the TPU compiler aborts on f32 SVD (which ``lstsq``
-    needs), f64 ``lstsq`` compiles for minutes even at 3,001 x 300, and
-    QR takes 90 s to compile at adult's 32,561 x 3,256 — while ``A^T A``
-    is one MXU matmul and its Cholesky compiles in about 20 s. A
+def _mir_rhs(y, C, prev: SMOResult, S_idx, R_idx):
+    """``(df, beta_R)`` of Eq. 17: df_i = b - f_i on I_u + I_l and 0 on
+    I_m, over all n rows (only those of X = S + R are read), and the
+    removed rows' beta = y * alpha."""
+    X_idx = jnp.concatenate([S_idx, R_idx])
+    alpha, f = prev.alpha, prev.f
+    mask_prev = jnp.zeros(y.shape, bool).at[X_idx].set(True)
+    b = _bias(prev, y, mask_prev, C)
+    free = (alpha > 0) & (alpha < C)
+    return jnp.where(free, 0.0, b - f), (y * alpha)[R_idx]
+
+
+def _mir_start(beta_T, y, C, alpha, S_idx, R_idx, T_idx):
+    """MIR's start from its least-squares ``beta_T``: alpha_S kept, the
+    box and equality constraints repaired per the paper's AdjustAlpha."""
+    beta_R = (y * alpha)[R_idx]
+    lo, hi = _box(y[T_idx], C)
+    beta_T = water_fill(jnp.clip(beta_T, lo, hi), lo, hi, jnp.sum(beta_R))
+    alpha0 = jnp.zeros_like(alpha).at[S_idx].set(alpha[S_idx])
+    alpha0 = alpha0.at[T_idx].set(y[T_idx] * beta_T)
+    return repair_equality(alpha0, y, C, S_idx, T_idx)
+
+
+def _ridge_solve(G, Atb):
+    """``argmin ||A x - b||`` from its normal equations ``G = A^T A`` and
+    ``Atb = A^T b``, ridge-regularized and solved through a Cholesky
+    factor in ``G``'s dtype (f32), returned as f64 state. The TPU compiler
+    aborts on f32 SVD (which ``lstsq`` needs), f64 ``lstsq`` compiles for
+    minutes even at 3,001 x 300, and QR takes 90 s to compile at adult's
+    32,561 x 3,256 — while the Cholesky compiles in about 20 s. A
     non-finite solve falls back to zeros, which the repair then fills."""
-    if A.dtype == STATE_DTYPE:
-        return jnp.linalg.lstsq(A, b)[0]
-    G = jnp.dot(A.T, A, precision=jax.lax.Precision.HIGHEST)
     lam = MIR_RIDGE * jnp.trace(G) / G.shape[0]
     G = G + lam * jnp.eye(G.shape[0], dtype=G.dtype)
     x = jax.scipy.linalg.cho_solve(jax.scipy.linalg.cho_factor(G),
-                                   kdot(A.T, b).astype(A.dtype))
+                                   Atb.astype(G.dtype))
     return jnp.where(jnp.isfinite(x), x, 0.0).astype(STATE_DTYPE)
 
 
+@functools.partial(jax.jit, static_argnames=("block",))
+def _mir_assemble(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx, *,
+                  block: int):
+    """MIR's normal equations, ``G = A^T A + 1 1^T`` (|T|, |T|) in f32 and
+    ``A^T rhs + sum(beta_R)`` (|T|,) in f64, for ``A = K[X, T]`` and
+    ``rhs = df + K[X, R] @ beta_R`` over the previous training set
+    X = S + R (``mir_seed``), streamed from K in blocks of ``block``
+    consecutive kernel indices, so that no (|X|, |T|) slab exists.
+
+    Block j is the column slab ``K[:, j*block:(j+1)*block]`` (one strided
+    copy) and its rows T and R (a row gather); by the kernel's symmetry
+    those are row block j of ``K[:, T]`` and ``K[:, R]``, and its rows
+    outside X (T's own, and those a last block's start, clamped back into
+    K, repeats) are masked."""
+    n, t = y.shape[0], T_idx.shape[0]
+    df, beta_R = _mir_rhs(y, C, prev, S_idx, R_idx)
+    in_X = jnp.zeros(n, bool).at[S_idx].set(True).at[R_idx].set(True)
+    TR = jnp.concatenate([T_idx, R_idx])
+
+    def body(j, carry):
+        G, Atb = carry
+        start = jnp.minimum(j * block, n - block)
+        cols = start + jnp.arange(block)
+        rows = in_X[cols] & (cols >= j * block)
+        seg = jax.lax.dynamic_slice_in_dim(K, start, block, axis=1)[TR]
+        A_T = jnp.where(rows[None, :], seg[:t], 0.0)        # (|T|, block)
+        rhs = jnp.where(rows, df[cols] + kdot(seg[t:].T, beta_R), 0.0)
+        G = G + jnp.dot(A_T, A_T.T, precision=jax.lax.Precision.HIGHEST)
+        return G, Atb + kdot(A_T, rhs)
+
+    G, Atb = jax.lax.fori_loop(
+        0, -(-n // block), body,
+        (jnp.zeros((t, t), K.dtype), jnp.zeros(t, STATE_DTYPE)))
+    # the equality constraint, one more row of ones in A
+    return G + 1.0, Atb + jnp.sum(beta_R)
+
+
 @jax.jit
+def _mir_solve(G, Atb, y, C, alpha, S_idx, R_idx, T_idx):
+    """MIR's least squares from its normal equations, then the repair."""
+    return _mir_start(_ridge_solve(G, Atb), y, C, alpha, S_idx, R_idx, T_idx)
+
+
+@jax.jit
+def _mir_seed_lstsq(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx):
+    """``mir_seed`` for an f64 K, the reference path: the least-squares
+    system built as slabs of K and solved by LAPACK-style ``lstsq``."""
+    X_idx = jnp.concatenate([S_idx, R_idx])
+    df, beta_R = _mir_rhs(y, C, prev, S_idx, R_idx)
+    rhs = df[X_idx] + kdot(K[jnp.ix_(X_idx, R_idx)], beta_R)
+    A = K[jnp.ix_(X_idx, T_idx)]
+    # append the equality constraint as one more row of the LS system
+    A_full = jnp.concatenate([A, jnp.ones((1, T_idx.shape[0]), K.dtype)], 0)
+    rhs_full = jnp.concatenate([rhs, jnp.sum(beta_R)[None]], 0)
+    beta_T = jnp.linalg.lstsq(A_full, rhs_full)[0]
+    return _mir_start(beta_T, y, C, prev.alpha, S_idx, R_idx, T_idx)
+
+
 def mir_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx):
     """Keep alpha_S; solve one least-squares system for alpha'_T.
 
@@ -180,35 +261,26 @@ def mir_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx):
     beta_t = y_t alpha'_t:   K[X,T] @ beta_T  =  df + K[X,R] @ beta_R
     plus the equality row    1^T beta_T       =  1^T beta_R
     with df_i = b - f_i on I_u + I_l and 0 on I_m (rows i over the previous
-    training set X = S + R). Solved by lstsq; the box/equality constraints
-    are then repaired per the paper's AdjustAlpha.
+    training set X = S + R). The box/equality constraints are then
+    repaired per the paper's AdjustAlpha.
 
-    The least-squares solve runs in the kernel dtype (``_least_squares``).
-    Its result is only a start point: the f64 repair below and the solver
-    that follows fix the equality constraint and the optimum.
+    Over an f32 K the system is solved in two programs: the assembly of
+    its normal equations, streamed from K in blocks (``_mir_assemble``,
+    timed by the ``repro.seed.assemble`` span, synced), and the
+    ridge-Cholesky solve with the repair (``_mir_solve``). Its result is
+    only a start point: the f64 repair and the solver that follows fix
+    the equality constraint and the optimum. An f64 K takes ``lstsq``
+    over slabs of K (``_mir_seed_lstsq``).
     """
-    X_idx = jnp.concatenate([S_idx, R_idx])
-    alpha, f = prev.alpha, prev.f
-    mask_prev = jnp.zeros(y.shape, bool).at[X_idx].set(True)
-    b = _bias(prev, y, mask_prev, C)
-    free = (alpha > 0) & (alpha < C)
-    df = jnp.where(free, 0.0, b - f)[X_idx]
-
-    beta_R = (y * alpha)[R_idx]
-    # one 2-D gather per slab: K[X_idx] first would materialize a
-    # (|S+R|, n) copy — 3.8 GB at adult's size
-    rhs = df + kdot(K[jnp.ix_(X_idx, R_idx)], beta_R)
-    A = K[jnp.ix_(X_idx, T_idx)]
-    # append the equality constraint as one more row of the LS system
-    A_full = jnp.concatenate([A, jnp.ones((1, T_idx.shape[0]), K.dtype)], 0)
-    rhs_full = jnp.concatenate([rhs, jnp.sum(beta_R)[None]], 0)
-    beta_T = _least_squares(A_full, rhs_full)
-
-    lo, hi = _box(y[T_idx], C)
-    beta_T = water_fill(jnp.clip(beta_T, lo, hi), lo, hi, jnp.sum(beta_R))
-    alpha0 = jnp.zeros_like(alpha).at[S_idx].set(alpha[S_idx])
-    alpha0 = alpha0.at[T_idx].set(y[T_idx] * beta_T)
-    return repair_equality(alpha0, y, C, S_idx, T_idx)
+    if K.dtype == STATE_DTYPE:
+        return _mir_seed_lstsq(K, y, C, prev, S_idx, R_idx, T_idx)
+    n = y.shape[0]
+    block = min(MIR_BLOCK, n)
+    with obs.span("repro.seed.assemble", blocks=-(-n // block),
+                  rows=int(S_idx.shape[0] + R_idx.shape[0])):
+        G, Atb = jax.block_until_ready(_mir_assemble(
+            K, y, C, prev, S_idx, R_idx, T_idx, block=block))
+    return _mir_solve(G, Atb, y, C, prev.alpha, S_idx, R_idx, T_idx)
 
 
 # --------------------------------------------------------------------------

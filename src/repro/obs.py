@@ -28,7 +28,8 @@ time spent on programs with no second counted twice.
 Span names (DESIGN.md §Spans): ``repro.plan`` and its children
 ``repro.plan.prepare``, ``.analyze``, ``.evals``, ``.release``;
 ``repro.pool.build``, ``.run``, ``.seed``, ``.dispatch``, ``.wait``,
-``.retire``; ``repro.cache.materialize``.
+``.retire``; ``repro.seed.assemble`` (MIR's assembly, inside
+``repro.pool.seed``); ``repro.cache.materialize``.
 """
 from __future__ import annotations
 

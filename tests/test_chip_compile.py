@@ -1,6 +1,7 @@
 """Compile the main path's device programs for a described TPU v5e chip at
-adult's published shape (32,560 x 123 after the 10-fold truncation), with
-Pallas compiled (``interpret=False``). Nothing runs: the TPU compiler
+adult's published shape (32,560 x 123 after the 10-fold truncation), and
+MIR's seeding at mnist's (60,000 x 780), with Pallas compiled
+(``interpret=False``). Nothing runs: the TPU compiler
 refuses here what the chip would refuse (f64 Pallas operands, int64 index
 maps, blocks over VMEM, programs over HBM), at no chip time.
 
@@ -15,10 +16,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import seeding
 from repro.kernels.rbf import rbf_kernel_matrix
 from repro.kernels.smo_step import compiled_blocks, fused_smo_step
 from repro.svm.engine import (DenseKernel, EngineState, PallasRBF,
-                              chunk_batched_jit, chunk_jit)
+                              SMOResult, chunk_batched_jit, chunk_jit)
 
 N, D = 32560, 123
 HBM_BYTES = 16 * 10**9
@@ -133,3 +135,24 @@ def test_compiled_launch_refuses_f64_operands(spec):
             f, X, xij, sq, 0.37, gamma=0.5, interpret=False)).lower(
                 spec((N,), jnp.float64), spec((N, D), jnp.float64),
                 spec((2, D), jnp.float64), spec((N,), jnp.float64))
+
+
+def test_mir_programs_compile_for_v5e_at_mnist(spec):
+    """MIR over mnist's dense K (60,000 squared, 14.4 GB; |S + R| 54,000,
+    |T| 6,000): the assembly streams K in column blocks and the solve takes
+    the (6,000)-square normal equations, each with under 0.5 GB of
+    temporaries. The gathered form held (|S + R|, |T|) slabs of 1.30 GB
+    each, at least 2.6 GB beside K."""
+    n, t = 60000, 6000
+    f32, f64, i64 = jnp.float32, jnp.float64, jnp.int64
+    prev = SMOResult(spec((n,), f64), spec((n,), f64), spec((), i64),
+                     spec((), bool), spec((), f64), spec((), f64))
+    sets = (spec((n - 2 * t,), i64), spec((t,), i64), spec((t,), i64))
+    assemble = _compile(
+        lambda K, y, C, p, S, R, T: seeding._mir_assemble(
+            K, y, C, p, S, R, T, block=seeding.MIR_BLOCK),
+        spec((n, n), f32), spec((n,), f64), spec((), f64), prev, *sets)
+    solve = _compile(seeding._mir_solve, spec((t, t), f32), spec((t,), f64),
+                     spec((n,), f64), spec((), f64), spec((n,), f64), *sets)
+    for compiled in (assemble, solve):
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
