@@ -120,8 +120,9 @@ def fused_smo_step(f, X, xij, sq_norms, delta, *, gamma: float,
     """One fused SMO step: ``f + delta * (K_i - K_j)`` without rows in HBM.
 
     ``f`` (n,) indicator vector, any float dtype; ``X`` (n, d) training
-    matrix; ``xij`` (2, d) the WSS-1 pair's feature rows (gathered by the
-    caller — the engine's onehot idiom keeps this sharding-friendly);
+    matrix; ``xij`` (2, d) the WSS-1 pair's feature rows, read by the
+    caller (``PallasRBF._pair`` indexes X, so this launch is the one
+    pass over X per SMO iteration);
     ``sq_norms`` (n,) precomputed row norms of X; ``delta`` the clipped
     2-variable step. Returns the updated f in f's dtype.
 

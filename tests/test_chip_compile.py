@@ -104,6 +104,14 @@ def _assert_lane_dense(text):
     assert re.search(rf"f32\[(\d+,)?{N},[12]\]", text) is None
 
 
+def _assert_one_pass_over_x(text):
+    """The kernel's is the loop's only pass over X: the WSS pair's two rows
+    are read by index, with no one-hot product of an (n,)-long indicator
+    with X (a ``convolution`` of f32[2,n] by X, a second 16 MB stream per
+    SMO iteration at adult's n)."""
+    assert "convolution" not in text
+
+
 def test_pallas_chunk_compiles_for_v5e(spec):
     compiled = _compile(lambda X, sq, y, mask, st: chunk_jit(
         PallasRBF(X, 0.5, sq, interpret=False), y, mask, 100.0, 1e-3,
@@ -111,6 +119,7 @@ def test_pallas_chunk_compiles_for_v5e(spec):
         spec((N, D), jnp.float32), spec((N,), jnp.float32),
         spec((N,), jnp.float64), spec((N,), bool), _state(spec))
     _assert_lane_dense(compiled.as_text())
+    _assert_one_pass_over_x(compiled.as_text())
 
 
 def test_batched_pallas_chunk_compiles_for_v5e(spec):
@@ -127,6 +136,7 @@ def test_batched_pallas_chunk_compiles_for_v5e(spec):
         spec((N,), jnp.float64), spec((lanes, N), bool),
         spec((lanes,), jnp.float64), states)
     _assert_lane_dense(compiled.as_text())
+    _assert_one_pass_over_x(compiled.as_text())
 
 
 def test_compiled_launch_refuses_f64_operands(spec):

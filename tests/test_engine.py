@@ -330,6 +330,35 @@ def test_pallas_wide_batch_tolerance():
                                atol=1e-10)
 
 
+@pytest.mark.parametrize("case", ["i<j", "i>j", "i=j", "vmapped",
+                                  "compact"])
+def test_pallas_pair_reads_rows_by_index(case):
+    """The WSS pair's feature rows are X[[i, j]], bit for bit, with traced
+    indices as in the SMO loop: solo, under vmap (the pool's batched
+    dispatch) and on a source from ``compact`` (the shrinking scheduler's
+    active set, whose pads, index n, clamp to the last row)."""
+    ds = make_dataset("heart", n_override=150)
+    X = jnp.asarray(ds.X, jnp.float32)
+    src = PallasRBF(X, ds.gamma)
+    Xn = np.asarray(X)
+    pair = jax.jit(lambda s, i, j: s._pair(i, j))
+    if case == "vmapped":
+        i, j = np.array([0, 149, 31, 80]), np.array([7, 2, 31, 149])
+        got = jax.jit(jax.vmap(src._pair))(jnp.asarray(i), jnp.asarray(j))
+        want = Xn[np.stack([i, j], 1)]
+    elif case == "compact":
+        idx = np.array([3, 17, 40, 99, 148, 150, 150])
+        i, j = 4, 1
+        got = pair(src.compact(idx), jnp.asarray(i), jnp.asarray(j))
+        want = Xn[np.minimum(idx, 149)][[i, j]]
+    else:
+        i, j = {"i<j": (5, 120), "i>j": (149, 0), "i=j": (63, 63)}[case]
+        got = pair(src, jnp.asarray(i), jnp.asarray(j))
+        want = Xn[[i, j]]
+    assert got.dtype == X.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 def test_pallas_nbytes_is_data_not_matrix():
     """The cache budget must account X's bytes, not n² kernel bytes."""
     from repro.svm.sources import KernelSpec
