@@ -3,8 +3,8 @@
 A run on the chip compiles every engine program from scratch unless the
 compiled executables survive in a persistent cache; the cache key includes
 the directory, so a cache that moves never hits. Entry points (scripts,
-examples, benchmarks, ``chip_smoke.py``) call :func:`enable_compile_cache`
-before their first compile. Importing the library never does.
+examples, ``chip_smoke.py``) call :func:`enable_compile_cache` before
+their first compile. Importing the library never does.
 
 ``python -c "from repro.compile_cache import enable_compile_cache as e; print(e())"``
 prints where the cache lives.

@@ -5,7 +5,7 @@ fold h's dual solution to warm-start fold h+1, plus the two prior
 leave-one-out baselines (AVG, TOP) and the cold-start reference.
 """
 from repro.core.seeding import (  # noqa: F401
-    cold_seed, mir_seed, sir_seed, ato_seed, ato_seed_ref, ato_seed_batch,
+    cold_seed, mir_seed, sir_seed, ato_seed, ato_seed_ref,
     avg_seed_loo, top_seed_loo, water_fill, repair_equality, SEEDERS,
 )
 from repro.core.study import (  # noqa: F401

@@ -37,7 +37,6 @@ from __future__ import annotations
 import dataclasses
 import time
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -45,8 +44,7 @@ from repro.core import seeding
 from repro.core.study import Plan, StudyCheckpoint, run_plan
 from repro.data.svm_suite import SVMDataset, kfold_chunks
 from repro.svm import (DenseKernel, PallasRBF, bias_from_solution,
-                       kernel_matrix, predict,
-                       smo_solve_batched)
+                       kernel_matrix, predict)
 from repro.svm.precision import STATE_DTYPE, kdot, kernel_input
 
 # step numbering inside a checkpoint directory: fold h's mid-fold chunk
@@ -87,7 +85,7 @@ class CVReport:
     folds: list[FoldStat]
     #: lane-pool width stats (mean/peak live width, program count; with
     #: shrinking, the shrink-chunk count and mean active fraction) from the
-    #: run's pool; None for the plain-batched schedule, which bypasses it
+    #: run's pool
     occupancy: dict | None = None
 
     @property
@@ -420,8 +418,7 @@ def run_cv(ds: SVMDataset, k: int = 10, method: str = "sir",
 def run_cv_batched(ds: SVMDataset, k: int = 10, tol: float = 1e-3,
                    max_iter: int = 5_000_000, seed: int = 0,
                    kernel_backend: str = "jnp", chunk_iters: int = 4096,
-                   schedule: str = "repacked", lane_quantum: int = 4,
-                   max_width: int | None = None,
+                   lane_quantum: int = 4, max_width: int | None = None,
                    source_backend: str = "dense", checkpoint_manager=None,
                    checkpoint_every: int = 1, shrink_every: int | str = 0,
                    shrink_quantum: int = 128, shrink_caps=None,
@@ -429,55 +426,37 @@ def run_cv_batched(ds: SVMDataset, k: int = 10, tol: float = 1e-3,
     """Cold k-fold CV with all folds solved concurrently: independent
     solves are a batch, not a loop.
 
-    ``schedule`` picks the dispatch strategy:
+    The folds are a k-lane plan (method "cold_batched_repacked") executed
+    by ``run_plan`` on the lane pool: converged folds retire between
+    chunks, the live batch is compacted (bucketed widths) and the dispatch
+    width is capped by the backend cost model (``max_width``; on CPU the
+    default is a width-1 round-robin through the sequential program), so
+    device work tracks ``sum_h n_iter_h`` (DESIGN.md §Lane scheduler /
+    §Study API).
 
-    * ``"repacked"`` (default, method "cold_batched_repacked") — the folds
-      are a k-lane plan executed by ``run_plan`` on the lane pool:
-      converged folds retire between chunks, the live batch is compacted
-      (bucketed widths) and the dispatch width is capped by the backend
-      cost model (``max_width``; on CPU the default is a width-1
-      round-robin through the sequential program), so device work tracks
-      ``sum_h n_iter_h`` (DESIGN.md §Lane scheduler / §Study API);
-    * ``"batched"`` (method "cold_batched") — the fixed-width
-      ``engine.solve_batched`` batch kept as the repack baseline.
+    ``source_backend="pallas_rbf"`` (method "cold_pallas") swaps the dense
+    precomputed matrix for the row-streaming ``PallasRBF`` source: no
+    (n, n) kernel is ever built (``kernel_time`` then covers only the
+    O(n·d) row-norm precompute), the folds solve under WSS-1 with the
+    fused kernel-row + f-update Pallas step, and held-out evaluation
+    streams test-chunk rows via ``rows_at`` / the dual objective via
+    ``matvec``. Alphas match the dense WSS-1 solve bit-for-bit in
+    interpret mode (DESIGN.md §Pallas sources); they differ from the
+    default WSS-2 methods' iterate sequence, as any WSS choice does.
 
-    ``source_backend="pallas_rbf"`` (repacked schedule only, method
-    "cold_pallas") swaps the dense precomputed matrix for the
-    row-streaming ``PallasRBF`` source: no (n, n) kernel is ever built
-    (``kernel_time`` then covers only the O(n·d) row-norm precompute),
-    the folds solve under WSS-1 with the fused kernel-row + f-update
-    Pallas step, and held-out evaluation streams test-chunk rows via
-    ``rows_at`` / the dual objective via ``matvec``. Alphas match the
-    dense WSS-1 solve bit-for-bit in interpret mode (DESIGN.md §Pallas
-    sources); they differ from the default WSS-2 methods' iterate
-    sequence, as any WSS choice does.
-
-    Both produce the same per-fold fixed points as ``run_cv(method="cold")``
+    The per-fold fixed points equal ``run_cv(method="cold")``'s
     (bit-identical alphas — the engine body is shared); only the schedule
     differs. Seeded chains stay sequential by nature — their concurrency
     axis is the hyper-parameter grid (see ``repro.core.grid``).
 
-    With a checkpoint manager (repacked schedule only), every
-    ``checkpoint_every``-th chunk snapshots ALL lanes' (alpha, f, n_iter,
-    done) keyed by **original fold id** — not packed position — as one
-    ``phase: "batch_mid"`` record (retain_class "batch"), so a crashed
-    mid-batch run resumes each fold's exact iterate sequence regardless of
-    how lanes were packed at the crash."""
-    if schedule not in ("repacked", "batched"):
-        raise ValueError(f"unknown schedule {schedule!r}")
-    if checkpoint_manager is not None and schedule != "repacked":
-        raise ValueError("mid-batch checkpointing requires the repacked "
-                         "schedule (snapshots are keyed by scheduler lane)")
+    With a checkpoint manager, every ``checkpoint_every``-th chunk
+    snapshots ALL lanes' (alpha, f, n_iter, done) keyed by **original fold
+    id** — not packed position — as one ``phase: "batch_mid"`` record
+    (retain_class "batch"), so a crashed mid-batch run resumes each fold's
+    exact iterate sequence regardless of how lanes were packed at the
+    crash."""
     if source_backend not in ("dense", "pallas_rbf"):
         raise ValueError(f"unknown source_backend {source_backend!r}")
-    if source_backend == "pallas_rbf" and schedule != "repacked":
-        raise ValueError("source_backend='pallas_rbf' requires the repacked "
-                         "schedule: the streaming source runs through the "
-                         "lane pool, not engine.solve_batched on a matrix")
-    if shrink_every and schedule != "repacked":
-        raise ValueError("shrink_every requires the repacked schedule: "
-                         "shrinking is a lane-pool transformation, not an "
-                         "engine.solve_batched feature")
     X = kernel_input(ds.X)
     y = jnp.asarray(ds.y, STATE_DTYPE)
 
@@ -499,28 +478,6 @@ def run_cv_batched(ds: SVMDataset, k: int = 10, tol: float = 1e-3,
     y = y[:n]
     masks = jnp.asarray(_fold_masks(chunks))
 
-    if schedule == "batched":
-        t0 = time.perf_counter()
-        res = smo_solve_batched(K, y, masks, ds.C,
-                                jnp.zeros((k, n), STATE_DTYPE),
-                                jnp.tile(-y, (k, 1)), tol=tol,
-                                max_iter=max_iter, chunk_iters=chunk_iters)
-        jax.block_until_ready(res)
-        solve_time = time.perf_counter() - t0
-
-        folds = []
-        for h in range(k):
-            fold_res = jax.tree.map(lambda a: a[h], res)
-            correct, total, obj = _eval_fold(K, y, chunks, h, fold_res, ds.C)
-            folds.append(FoldStat(
-                fold=h, seed_from=-1, n_iter=int(fold_res.n_iter),
-                init_time=0.0, solve_time=solve_time / k,
-                acc_correct=correct, acc_total=total, objective=obj,
-                converged=bool(fold_res.converged)))
-        return CVReport(dataset=ds.name, method="cold_batched", k=k, n=n,
-                        kernel_time=kernel_time, folds=folds)
-
-    # ---- repacked schedule: a k-lane cold plan ----
     method = ("cold_pallas" if source_backend == "pallas_rbf"
               else "cold_batched_repacked")
     plan = Plan(sources={"cv": source}, y=y, tol=tol,

@@ -14,16 +14,13 @@ ONE Study plan (``repro.core.study``):
 * **C-adjacent seeding** (``seed_across_C=True``) — fold 0 of cell
   (C_m, gamma) warm-starts from fold 0 of (C_{m-1}, gamma) via the
   ``"scale_C"`` transform (bounded-SV alphas scale ~linearly with C);
-* **cross-gamma pooling** (``pool="cross_gamma"``, the default) — every
-  (gamma, cell, fold) solve is one lane of a single multi-source
-  ``LanePool``: lanes carry their gamma's source key, packing buckets by
-  (source, width), and admission is shared across sources. A straggler
-  cell no longer bounds its gamma row's wall-clock — cells from OTHER
-  gammas fill the schedule while it converges. ``pool="per_gamma"`` keeps
-  the PR 3 row-scheduler baseline (one pool per gamma row; the
-  ``grid_pooled`` benchmark row compares the two), and per-lane results
-  are bit-identical either way — a lane's iterate sequence depends only on
-  its own (source, mask, C, state).
+* **cross-gamma pooling** — every (gamma, cell, fold) solve is one lane
+  of a single multi-source ``LanePool``: lanes carry their gamma's source
+  key, packing buckets by (source, width), and admission is shared across
+  sources. A straggler cell does not bound its gamma row's wall-clock —
+  cells from OTHER gammas fill the schedule while it converges. A lane's
+  iterate sequence depends only on its own (source, mask, C, state), so
+  per-lane results are bit-identical to a one-gamma grid's.
 
 The fold chain inside a cell stays sequential — that is the paper's
 algorithm — but the grid turns its breadth axes into scheduler lanes:
@@ -34,9 +31,9 @@ Per-lane evaluation is declared as plan ``EvalSpec``s: one jitted vmap per
 (gamma, test-size) group computes every lane's held-out correct-count on
 device, and a single transfer brings the counts back.
 
-With a checkpoint manager (cross-gamma pool only), the whole grid
-checkpoints as one study (plan-keyed ``"study"`` records, lane ids stable
-under resume): a killed grid resumes every cell's exact iterate sequence.
+With a checkpoint manager, the whole grid checkpoints as one study
+(plan-keyed ``"study"`` records, lane ids stable under resume): a killed
+grid resumes every cell's exact iterate sequence.
 """
 from __future__ import annotations
 
@@ -75,13 +72,11 @@ class GridReport:
     seed_time: float
     solve_time: float
     cells: list[GridCell]
-    #: LanePool width stats; the cross-gamma pool reports ``per_source``
-    #: live widths (one entry per gamma), the per-gamma baseline aggregates
-    #: its row pools
+    #: LanePool width stats, with ``per_source`` live widths (one entry
+    #: per gamma)
     occupancy: dict | None = None
-    #: kernel-source cache account (materializations, kernel wall time,
-    #: peak resident sources/bytes) summed over the grid's studies — the
-    #: memory-ceiling signal the ``grid_pooled_lru`` bench row publishes
+    #: kernel-source cache account (materializations, evictions, peak
+    #: resident sources/bytes) — the grid's memory-ceiling signal
     resident: dict | None = None
 
     @property
@@ -97,43 +92,6 @@ class GridReport:
                  "iterations": c.iterations,
                  "accuracy": round(c.accuracy, 4),
                  "converged": c.converged} for c in self.cells]
-
-
-def _merge_occupancy(rows: list[dict]) -> dict | None:
-    """Aggregate per-pool occupancy dicts into one report. ``programs`` is
-    SUMMED — each pool compiled its own distinct programs, and the stat
-    exists to bound total compiled-program count (the old ``max`` silently
-    undercounted it). ``per_source`` blocks are merged by source key
-    (chunk-weighted mean live width, max peak) instead of being dropped."""
-    if not rows:
-        return None
-    chunks = sum(r["chunks"] for r in rows)
-    if chunks == 0:
-        return {"chunks": 0, "mean_live_width": 0.0, "peak_width": 0}
-    merged = {
-        "chunks": chunks,
-        "mean_live_width": round(
-            sum(r["mean_live_width"] * r["chunks"] for r in rows) / chunks, 3),
-        "mean_packed_width": round(
-            sum(r["mean_packed_width"] * r["chunks"] for r in rows) / chunks,
-            3),
-        "peak_width": max(r["peak_width"] for r in rows),
-        "programs": sum(r["programs"] for r in rows),
-    }
-    per_source: dict[str, list] = {}
-    for r in rows:
-        for key, s in (r.get("per_source") or {}).items():
-            rec = per_source.setdefault(key, [0.0, 0, 0])  # [sum, n, peak]
-            rec[0] += s["mean_live_width"] * s["chunks"]
-            rec[1] += s["chunks"]
-            rec[2] = max(rec[2], s["peak_live_width"])
-    if per_source:
-        merged["per_source"] = {
-            key: {"chunks": n,
-                  "mean_live_width": round(s / max(n, 1), 3),
-                  "peak_live_width": peak}
-            for key, (s, n, peak) in per_source.items()}
-    return merged
 
 
 def _row_lanes(plan: Plan, gi: int, Cs, masks, transitions, method: str,
@@ -165,11 +123,9 @@ def _row_lanes(plan: Plan, gi: int, Cs, masks, transitions, method: str,
             plan.evaluate((gi, ci, h), chunks[h])
 
 
-def _check_grid_args(pool: str, source_backend: str, method: str) -> None:
+def _check_grid_args(source_backend: str, method: str) -> None:
     """The grid's own entry contract — checked before any plan is built
     or any kernel spec could resolve, so a typo fails at call time."""
-    if pool not in ("cross_gamma", "per_gamma"):
-        raise ValueError(f"unknown pool {pool!r}")
     if source_backend not in ("dense", "pallas_rbf"):
         raise ValueError(f"unknown source_backend {source_backend!r} "
                          "(have 'dense', 'pallas_rbf')")
@@ -184,18 +140,17 @@ def grid_plans(ds: SVMDataset, Cs, gammas, k: int = 10,
                max_iter: int = 5_000_000, seed: int = 0,
                seed_across_C: bool = False, chunk_iters: int = 4096,
                kernel_backend: str = "jnp", lane_quantum: int = 4,
-               max_width: int | None = None, pool: str = "cross_gamma",
+               max_width: int | None = None,
                max_resident: int = 0, cache_bytes: int = 0,
                source_backend: str = "dense", shrink_every: int | str = 0,
                shrink_quantum: int = 128, shrink_caps=None,
                shrink_on_seed: bool = True) -> list:
-    """The exact ``Plan``(s) ``run_grid`` executes for these arguments —
-    one multi-source plan for ``pool="cross_gamma"``, one single-source
-    plan per gamma for ``pool="per_gamma"`` — built but not run. This is
-    the static-analysis entry point: feed them to
+    """The exact multi-source ``Plan`` ``run_grid`` executes for these
+    arguments, as a one-element list — built but not run. This is the
+    static-analysis entry point: feed it to
     ``repro.analysis.plan_check.analyze_plan`` to enumerate compile
     shapes or budget feasibility without solving anything."""
-    _check_grid_args(pool, source_backend, method)
+    _check_grid_args(source_backend, method)
     Cs = sorted(float(c) for c in Cs)
     gammas = [float(g) for g in gammas]
     y_all = jnp.asarray(ds.y, STATE_DTYPE)
@@ -216,29 +171,24 @@ def grid_plans(ds: SVMDataset, Cs, gammas, k: int = 10,
     # cold-start alphas in the state dtype, matching run_cv's
     zeros = jnp.zeros(n, STATE_DTYPE)
 
-    def make_plan(keys) -> Plan:
-        plan = Plan(sources={gi: sources[gi] for gi in keys}, y=y, tol=tol,
-                    wss="1" if source_backend == "pallas_rbf" else "2",
-                    chunk_iters=chunk_iters, lane_quantum=lane_quantum,
-                    max_width=max_width, max_resident=max_resident,
-                    cache_bytes=cache_bytes, source_backend=source_backend,
-                    shrink_every=shrink_every, shrink_quantum=shrink_quantum,
-                    shrink_caps=shrink_caps, shrink_on_seed=shrink_on_seed)
-        for gi in keys:
-            _row_lanes(plan, gi, Cs, masks, transitions, method,
-                       seed_across_C, max_iter, zeros, y, chunks)
-        return plan
-
-    if pool == "cross_gamma":
-        return [make_plan(range(len(gammas)))]
-    return [make_plan([gi]) for gi in range(len(gammas))]
+    plan = Plan(sources=sources, y=y, tol=tol,
+                wss="1" if source_backend == "pallas_rbf" else "2",
+                chunk_iters=chunk_iters, lane_quantum=lane_quantum,
+                max_width=max_width, max_resident=max_resident,
+                cache_bytes=cache_bytes, source_backend=source_backend,
+                shrink_every=shrink_every, shrink_quantum=shrink_quantum,
+                shrink_caps=shrink_caps, shrink_on_seed=shrink_on_seed)
+    for gi in sources:
+        _row_lanes(plan, gi, Cs, masks, transitions, method,
+                   seed_across_C, max_iter, zeros, y, chunks)
+    return [plan]
 
 
 def run_grid(ds: SVMDataset, Cs, gammas, k: int = 10, method: str = "sir",
              tol: float = 1e-3, max_iter: int = 5_000_000, seed: int = 0,
              seed_across_C: bool = False, chunk_iters: int = 4096,
              kernel_backend: str = "jnp", lane_quantum: int = 4,
-             max_width: int | None = None, pool: str = "cross_gamma",
+             max_width: int | None = None,
              max_resident: int = 0, cache_bytes: int = 0,
              source_backend: str = "dense",
              checkpoint_manager=None,
@@ -254,12 +204,10 @@ def run_grid(ds: SVMDataset, Cs, gammas, k: int = 10, method: str = "sir",
     trades fold-0 concurrency for warm starts, which wins when C values
     are dense (adjacent cells share most of their support vectors).
 
-    ``pool`` picks the schedule: ``"cross_gamma"`` (default) runs the whole
-    grid as ONE multi-source lane pool — no per-row barrier, one study
-    checkpoint; ``"per_gamma"`` runs one pool per gamma row (the historical
-    schedule, kept as the benchmark baseline). Per-cell results match
-    ``run_cv`` on the same hyper-parameters under either pool (same
-    seeders, same engine, bit-identical solves).
+    The whole grid runs as ONE multi-source lane pool — no per-row
+    barrier, one study checkpoint. Per-cell results match ``run_cv`` on
+    the same hyper-parameters (same seeders, same engine, bit-identical
+    solves).
 
     Kernels are declared as factories (one ``KernelSpec`` per gamma) and
     materialize on demand through the pool's source cache.
@@ -287,68 +235,44 @@ def run_grid(ds: SVMDataset, Cs, gammas, k: int = 10, method: str = "sir",
     SV sets match the unshrunk grid; 0 (default) keeps every iterate
     bit-identical to today.
     """
-    _check_grid_args(pool, source_backend, method)
-    if checkpoint_manager is not None and pool != "cross_gamma":
-        raise ValueError("grid checkpointing is plan-keyed and needs the "
-                         "cross-gamma pool (one study = one record stream)")
+    _check_grid_args(source_backend, method)
     Cs = sorted(float(c) for c in Cs)
     gammas = [float(g) for g in gammas]
     m = len(Cs)
     chunks = kfold_chunks(ds.n, k, seed=seed)
     n = chunks.size
 
-    # one builder for the declared plans — grid_plans is also the static
+    # one builder for the declared plan — grid_plans is also the static
     # analyzer's entry point, so what plan_check enumerates is exactly
     # what executes here
-    plans = grid_plans(ds, Cs, gammas, k=k, method=method, tol=tol,
-                       max_iter=max_iter, seed=seed,
-                       seed_across_C=seed_across_C, chunk_iters=chunk_iters,
-                       kernel_backend=kernel_backend,
-                       lane_quantum=lane_quantum, max_width=max_width,
-                       pool=pool, max_resident=max_resident,
-                       cache_bytes=cache_bytes,
-                       source_backend=source_backend,
-                       shrink_every=shrink_every,
-                       shrink_quantum=shrink_quantum,
-                       shrink_caps=shrink_caps,
-                       shrink_on_seed=shrink_on_seed)
+    (plan,) = grid_plans(
+        ds, Cs, gammas, k=k, method=method, tol=tol, max_iter=max_iter,
+        seed=seed, seed_across_C=seed_across_C, chunk_iters=chunk_iters,
+        kernel_backend=kernel_backend, lane_quantum=lane_quantum,
+        max_width=max_width, max_resident=max_resident,
+        cache_bytes=cache_bytes, source_backend=source_backend,
+        shrink_every=shrink_every, shrink_quantum=shrink_quantum,
+        shrink_caps=shrink_caps, shrink_on_seed=shrink_on_seed)
 
-    if pool == "cross_gamma":
-        checkpoint = None
-        if checkpoint_manager is not None:
-            checkpoint = StudyCheckpoint(
-                manager=checkpoint_manager, every=checkpoint_every,
-                meta={"bench": "grid", "dataset": ds.name, "method": method,
-                      "k": k, "seed": seed, "tol": tol, "max_iter": max_iter,
-                      "Cs": Cs, "gammas": gammas,
-                      "seed_across_C": seed_across_C,
-                      "shrink_every": shrink_every})
-        study_results = [run_plan(plans[0], checkpoint=checkpoint)]
-        occupancy = study_results[0].occupancy
-    else:
-        study_results = [run_plan(p) for p in plans]
-        occupancy = _merge_occupancy([s.occupancy for s in study_results])
+    checkpoint = None
+    if checkpoint_manager is not None:
+        checkpoint = StudyCheckpoint(
+            manager=checkpoint_manager, every=checkpoint_every,
+            meta={"bench": "grid", "dataset": ds.name, "method": method,
+                  "k": k, "seed": seed, "tol": tol, "max_iter": max_iter,
+                  "Cs": Cs, "gammas": gammas,
+                  "seed_across_C": seed_across_C,
+                  "shrink_every": shrink_every})
+    sres = run_plan(plan, checkpoint=checkpoint)
 
-    seed_time = sum(s.seed_time for s in study_results)
-    solve_time = sum(s.solve_time for s in study_results)
+    src = sres.source_stats
     # kernel_time is attributed per MATERIALIZATION: each gamma's first
     # use, plus any re-materialization after eviction or a cold-cache
     # resume — the honest cost of the compute-on-demand schedule
-    kernel_time = sum(s.source_stats.get("kernel_time", 0.0)
-                      for s in study_results)
-    resident = {
-        "materializations": sum(s.source_stats.get("materializations", 0)
-                                for s in study_results),
-        "evictions": sum(s.source_stats.get("evictions", 0)
-                         for s in study_results),
-        "peak_resident": max(s.source_stats.get("peak_resident", 0)
-                             for s in study_results),
-        "peak_resident_bytes": max(
-            s.source_stats.get("peak_resident_bytes", 0)
-            for s in study_results),
-    }
-    stats = {lid: st for s in study_results for lid, st in s.stats.items()}
-    evals = {lid: ev for s in study_results for lid, ev in s.evals.items()}
+    kernel_time = src.get("kernel_time", 0.0)
+    resident = {key: src.get(key, 0)
+                for key in ("materializations", "evictions", "peak_resident",
+                            "peak_resident_bytes")}
 
     t_sz = chunks.shape[1]
     cells: list[GridCell] = []
@@ -357,12 +281,12 @@ def run_grid(ds: SVMDataset, Cs, gammas, k: int = 10, method: str = "sir",
             lids = [(gi, ci, h) for h in range(k)]
             cells.append(GridCell(
                 C=Cs[ci], gamma=gamma,
-                iterations=int(sum(stats[lid].n_iter for lid in lids)),
-                acc_correct=int(sum(evals[lid][0] for lid in lids)),
+                iterations=int(sum(sres.stats[lid].n_iter for lid in lids)),
+                acc_correct=int(sum(sres.evals[lid][0] for lid in lids)),
                 acc_total=int(t_sz * k),
-                converged=all(stats[lid].converged for lid in lids)))
+                converged=all(sres.stats[lid].converged for lid in lids)))
 
     return GridReport(dataset=ds.name, method=method, k=k, n=n,
-                      kernel_time=kernel_time, seed_time=seed_time,
-                      solve_time=solve_time, cells=cells,
-                      occupancy=occupancy, resident=resident)
+                      kernel_time=kernel_time, seed_time=sres.seed_time,
+                      solve_time=sres.solve_time, cells=cells,
+                      occupancy=sres.occupancy, resident=resident)

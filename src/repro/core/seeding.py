@@ -29,7 +29,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import jax.scipy.linalg
-import numpy as np
 
 from repro import obs
 from repro.svm.precision import STATE_DTYPE, kdot
@@ -301,8 +300,7 @@ def sir_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx,
     * ``"skip"`` — beyond-paper: drop that alpha and let the (uniform,
       diffuse) repair absorb the mass. Avoids poisoning single coordinates
       with large wrong-sign alphas, which SMO then diffuses over the whole
-      free set (iteration counts for both variants come from
-      ``benchmarks.table1_kfold``; see DESIGN.md §Benchmarks).
+      free set.
     """
     if rng_key is None:
         rng_key = jax.random.PRNGKey(0)
@@ -485,7 +483,7 @@ def _ato_ramp(K, y, C, alpha, f, b_fallback, in_S, in_T, in_R, tol,
               m_cap: int, max_steps: int, coupled: bool = False):
     """Fixed-shape ATO ramp: ``ato_seed_ref``'s loop with masks for the
     M/T/R sets and a bordered KKT solve for Phi. Pure traced function —
-    jit- and vmap-safe (the grid batches it across a C row).
+    jit- and vmap-safe.
 
     The free set M is always a subset of (initially-free S rows) + T: a
     bounded row's alpha never moves (only M/T-active/R-active alphas do), so
@@ -596,20 +594,6 @@ def _ato_seed_jit(K, y, C, alpha, f, b_fallback, in_S, in_T, in_R,
     return repair_equality(out, y, jnp.asarray(C, STATE_DTYPE), S_idx, T_idx)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("m_cap", "max_steps", "coupled"))
-def _ato_seed_batch_jit(K, y, Cs, alphas, fs, b_fallbacks, in_S, in_T, in_R,
-                        S_idx, T_idx, tol, *, m_cap, max_steps,
-                        coupled=False):
-    def one(C, alpha, f, b_fb):
-        out = _ato_ramp(K, y, C, alpha, f, b_fb, in_S, in_T, in_R, tol,
-                        m_cap, max_steps, coupled)
-        return repair_equality(out, y, jnp.asarray(C, STATE_DTYPE), S_idx,
-                               T_idx)
-
-    return jax.vmap(one)(Cs, alphas, fs, b_fallbacks)
-
-
 def _transition_masks(n, S_idx, R_idx, T_idx):
     in_T = jnp.zeros(n, bool).at[T_idx].set(True)
     in_R = jnp.zeros(n, bool).at[R_idx].set(True)
@@ -633,56 +617,6 @@ def ato_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx,
     return _ato_seed_jit(K, y, C, prev.alpha, prev.f, b_fb, in_S, in_T, in_R,
                          S_idx, T_idx, tol, m_cap=m_cap,
                          max_steps=int(max_steps), coupled=coupled)
-
-
-def ato_seed_batch(K, y, Cs, prev: SMOResult, S_idx, R_idx, T_idx,
-                   max_steps: int = 30, tol: float = 1e-3,
-                   bucket_by_lane: bool = True):
-    """Batched ATO over lanes sharing one fold transition — the grid's
-    C-row case: ``prev`` is a batched ``SMOResult`` (leading axis = lane,
-    one per C value) and ``Cs`` its per-lane C. One vmapped while_loop
-    ramps a group of lanes concurrently (lanes that finish freeze via the
-    batching rule's select).
-
-    ``bucket_by_lane=True`` (default) applies the scheduler's repacking
-    idea to the ramp pad: each lane's working-set cap is computed from ITS
-    OWN free set (``_bucket_cap(|free S|_i + |T|, n)`` — the same exact
-    bound the solo ``ato_seed`` uses), lanes are grouped by cap, and one
-    program is dispatched per bucket. Lanes with a small free set no
-    longer pay the widest lane's O(m_cap^3) bordered solve; since caps are
-    already bucketed on a sqrt(2) ladder, the group count (and the jit
-    retrace count) stays O(log n). ``bucket_by_lane=False`` keeps the
-    historical behaviour — every lane padded to the widest cap in one
-    program (the baseline the ``ato_bucketed`` benchmark row compares
-    against).
-    """
-    y = jnp.asarray(y, STATE_DTYPE)
-    n = y.shape[0]
-    Cs = jnp.asarray(Cs, STATE_DTYPE)
-    in_S, in_T, in_R = _transition_masks(n, S_idx, R_idx, T_idx)
-    free0 = in_S[None] & (prev.alpha > 0) & (prev.alpha < Cs[:, None])
-    nf0s = np.asarray(jnp.sum(free0, axis=1))   # one (lanes,) transfer
-    t_sz = int(T_idx.shape[0])
-    b_fbs = 0.5 * (prev.b_up + prev.b_low)
-    if bucket_by_lane:
-        caps = [_ramp_cap(int(nf) + t_sz, n) for nf in nf0s]
-    else:
-        caps = [_ramp_cap(int(nf0s.max()) + t_sz, n)] * nf0s.shape[0]
-    out = jnp.zeros(prev.alpha.shape, STATE_DTYPE)
-    # the trace key is (m_cap, group size): caps are monotone in C, so
-    # bucket membership is a contiguous C-range and the distinct
-    # (cap, size) combinations stay small for realistic rows. Padding
-    # group sizes would bound the key space further but costs a full
-    # O(m_cap^3)-per-step ramp lane per pad — not worth it at C-row scale.
-    for cap, coupled in sorted(set(caps)):
-        idx = jnp.asarray([i for i, c in enumerate(caps)
-                           if c == (cap, coupled)])
-        sub = _ato_seed_batch_jit(K, y, Cs[idx], prev.alpha[idx],
-                                  prev.f[idx], b_fbs[idx], in_S, in_T, in_R,
-                                  S_idx, T_idx, tol, m_cap=int(cap),
-                                  max_steps=int(max_steps), coupled=coupled)
-        out = out.at[idx].set(sub)
-    return out
 
 
 # --------------------------------------------------------------------------

@@ -29,11 +29,6 @@ def flash_attention_ref(q, k, v, *, causal=True, window=None):
     return jnp.einsum("bhst,bhtd->bhsd", probs.astype(q.dtype), v)
 
 
-def smo_f_update_ref(f, K_i, K_j, delta):
-    """The SMO inner-loop rank-2 indicator update (paper Eq. 2 delta)."""
-    return f + delta * (K_i - K_j)
-
-
 def fused_smo_step_ref(f, X, xij, sq_norms, delta, gamma):
     """Fused pair-rows + rank-2 update: the FusedRBF.rows2 expression,
     rows upcast to f's dtype before the update (the engine's order)."""

@@ -18,8 +18,8 @@ from repro.svm.engine import (  # noqa: E402,F401
 from repro.svm.sources import KernelSpec, SourceCache  # noqa: E402,F401
 from repro.svm.shrink import (  # noqa: E402,F401
     LaneShrink, bucket_cap, possible_caps, seed_active_mask, solve_shrunk)
-from repro.svm.scheduler import LanePool, LaneScheduler  # noqa: E402,F401
+from repro.svm.scheduler import LanePool  # noqa: E402,F401
 from repro.svm.smo import (  # noqa: E402,F401
-    SMOResult, smo_solve, smo_solve_batched, init_f, dual_objective)
+    SMOResult, smo_solve, init_f, dual_objective)
 from repro.svm.svc import (  # noqa: E402,F401
     SVC, decision_function, predict, accuracy, bias_from_solution)

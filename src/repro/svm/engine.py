@@ -104,7 +104,7 @@ class SMOResult(NamedTuple):
 
 class EngineState(NamedTuple):
     """Resumable solver state — the unit chunks pass between themselves,
-    checkpoints serialize, and the batched driver stacks along axis 0.
+    checkpoints serialize, and the lane pool stacks along axis 0.
 
     The lane helpers below are the batched-state vocabulary: the scheduler
     ``stack``s single-lane states into a packed batch and ``lane``-extracts
@@ -517,7 +517,7 @@ def _step(source, y, train_mask, C, diag, tol, it_cap, wss, state):
     gap = jnp.where(has, b_low - b_up, -_INF)
     # a NaN gap (NaN in f on an active row) can never satisfy gap <= tol, so
     # the solver would burn max_iter on a poisoned state; halt instead and
-    # let _finalize report converged=False (the bad state surfaces)
+    # let finalize report converged=False (the bad state surfaces)
     done = done | (gap <= tol) | (it >= it_cap) | jnp.isnan(gap)
 
     # --- select i: minimal f over I_up ---
@@ -683,7 +683,7 @@ def chunk_batched_sources_jit(sources, ys, train_masks, Cs, tol, it_caps,
 
 
 # --------------------------------------------------------------------------
-# drivers: single solve / batched solve
+# single solve
 # --------------------------------------------------------------------------
 
 def init_state(train_mask, alpha0, f0, n_iter0=0) -> EngineState:
@@ -702,13 +702,6 @@ def finalize(state: EngineState, y, train_mask, C, tol) -> SMOResult:
     b_up, b_low, gap = optimality(state.alpha, state.f, y, train_mask, C)
     return SMOResult(alpha=state.alpha, f=state.f, n_iter=state.n_iter,
                      converged=gap <= tol, b_up=b_up, b_low=b_low)
-
-
-# historical private names, kept for callers/tests written before the lane
-# pool made these part of the public dispatch vocabulary
-_chunk_jit = chunk_jit
-_chunk_batched_jit = chunk_batched_jit
-_finalize = finalize
 
 
 def solve(source, y, train_mask, C, alpha0, f0, *, tol: float = 1e-3,
@@ -730,53 +723,11 @@ def solve(source, y, train_mask, C, alpha0, f0, *, tol: float = 1e-3,
     # solve stops exactly where the uninterrupted one would have
     it_cap = jnp.asarray(max_iter, jnp.int64)
     while True:
-        state = _chunk_jit(source, y, train_mask, C, tol, it_cap, state,
-                           n_iters=n, wss=wss)
+        state = chunk_jit(source, y, train_mask, C, tol, it_cap, state,
+                          n_iters=n, wss=wss)
         if chunk_iters is None or bool(state.done):
             break
         if on_chunk is not None:
             on_chunk(state)
-    return _finalize(state, y, train_mask, C, tol)
+    return finalize(state, y, train_mask, C, tol)
 
-
-def solve_batched(source, y, train_masks, Cs, alpha0s, f0s, *,
-                  tol: float = 1e-3, max_iter: int = 10_000_000,
-                  wss: str = "2", chunk_iters: int = 4096,
-                  on_chunk=None, n_iter0s=None) -> SMOResult:
-    """Solve a batch of folds concurrently over one shared kernel source.
-
-    ``train_masks`` (b, n), ``Cs`` () or (b,), ``alpha0s``/``f0s`` (b, n).
-    One vmapped chunk advances every unconverged fold ~chunk_iters
-    iterations; folds that converge freeze (their state passes through the
-    body untouched) while stragglers keep iterating, so total device work
-    is b * max(n_iter_b), not b * sum. Returns a batched ``SMOResult``
-    (leading axis = fold).
-
-    ``n_iter0s`` (() or (b,)) pre-loads per-lane iteration counters when
-    resuming a checkpointed batched run, mirroring the single-lane
-    ``solve(..., n_iter0=...)`` path: ``max_iter`` caps TOTAL updates
-    including the preload, so a resumed batch stops exactly where the
-    uninterrupted one would have.
-    """
-    if source.fused and wss == "2":
-        raise ValueError("fused kernel sources require WSS-1 (wss='1')")
-    b, n = train_masks.shape
-    Cs = jnp.broadcast_to(jnp.asarray(Cs, STATE_DTYPE), (b,))
-    alpha0s = jnp.where(train_masks, alpha0s, 0.0).astype(STATE_DTYPE)
-    n_iter0s = jnp.broadcast_to(
-        jnp.asarray(0 if n_iter0s is None else n_iter0s, jnp.int64), (b,))
-    states = EngineState(alpha0s, f0s.astype(STATE_DTYPE),
-                         n_iter0s, jnp.zeros(b, bool))
-    it_cap = jnp.asarray(max_iter, jnp.int64)
-    while True:
-        states = _chunk_batched_jit(source, y, train_masks, Cs, tol, it_cap,
-                                    states, n_iters=chunk_iters, wss=wss)
-        if bool(jnp.all(states.done)):
-            break
-        if on_chunk is not None:
-            on_chunk(states)
-    b_up, b_low, gap = jax.vmap(
-        lambda a, f, m, c: optimality(a, f, y, m, c))(
-            states.alpha, states.f, train_masks, Cs)
-    return SMOResult(alpha=states.alpha, f=states.f, n_iter=states.n_iter,
-                     converged=gap <= tol, b_up=b_up, b_low=b_low)

@@ -1,13 +1,11 @@
 """Lane pool: multi-source repacked batched dispatch with incremental
 admission.
 
-The engine's batched driver (``engine.solve_batched``) advances every lane
-of a fixed-width batch until the LAST lane converges — converged lanes
-freeze but still flow through the vmapped body, so on CPU the batch was
-measured slower than the sequential fold loop (DESIGN.md §Batched folds).
-This module replaces the fixed batch with a **schedule** over a pool of
-lanes that may span SEVERAL kernel sources (e.g. one RBF matrix per gamma
-of a hyper-parameter grid):
+A fixed-width batch would advance every lane until the LAST lane
+converges — converged lanes freeze but still flow through the vmapped
+body. This module drives the engine's chunk programs by a **schedule**
+over a pool of lanes that may span SEVERAL kernel sources (e.g. one RBF
+matrix per gamma of a hyper-parameter grid):
 
 * **repacking** — between chunks, converged lanes are *retired* (their
   state finalized into an ``SMOResult`` keyed by original lane id) and the
@@ -72,9 +70,6 @@ Checkpointing: ``snapshot_lanes()`` serializes every admitted lane's
 (alpha, f, n_iter, done) stacked **in lane-id order**, not packed
 position, so a mid-batch snapshot survives any repack/resume boundary;
 ``core/study.py:run_plan`` wires it to the checkpoint manager.
-
-``LaneScheduler`` remains as the single-source facade (one source, one
-label vector) used by callers predating the pool.
 """
 from __future__ import annotations
 
@@ -1032,8 +1027,8 @@ class LanePool:
                "programs": len(self._programs)}
         if self.shrink_every:
             # HBM-roofline hook: a lane-dispatch at cap streams cap/n of
-            # the full operand bytes, so this mean scales ``hbm_per_iter``
-            # (benchmarks/table1_kfold.py reads it)
+            # the full operand bytes, so this mean scales the bytes each
+            # iteration reads
             occ["shrink_lane_chunks"] = len(self._frac_log)
             occ["mean_active_frac"] = round(
                 sum(self._frac_log) / max(len(self._frac_log), 1), 4)
@@ -1045,20 +1040,3 @@ class LanePool:
                 for key, (s, n, peak) in self._src_live.items()}
         return occ
 
-
-class LaneScheduler(LanePool):
-    """Single-source facade over ``LanePool`` — the historical interface
-    (one kernel source, one label vector); lanes omit the source key."""
-
-    _SOLO = "_solo"
-
-    def __init__(self, source, y, **kwargs):
-        super().__init__({self._SOLO: source}, y, **kwargs)
-
-    @property
-    def source(self):
-        return self.resolve_source(self._SOLO)
-
-    @property
-    def y(self):
-        return self._ys[self._SOLO]

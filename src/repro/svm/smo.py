@@ -30,7 +30,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.svm.engine import (DenseKernel, SMOResult, _sets,  # noqa: F401
-                              solve, solve_batched)
+                              solve)
 from repro.svm.precision import kdot
 
 
@@ -60,21 +60,3 @@ def smo_solve(K: jnp.ndarray, y: jnp.ndarray, train_mask: jnp.ndarray,
                  max_iter=max_iter, wss=wss, chunk_iters=chunk_iters,
                  on_chunk=on_chunk, n_iter0=n_iter0)
 
-
-def smo_solve_batched(K: jnp.ndarray, y: jnp.ndarray, train_masks: jnp.ndarray,
-                      Cs, alpha0s: jnp.ndarray, f0s: jnp.ndarray,
-                      tol: float = 1e-3, max_iter: int = 10_000_000,
-                      wss: str = "2", chunk_iters: int = 4096,
-                      n_iter0s=None) -> SMOResult:
-    """Solve a batch of folds over one shared kernel matrix concurrently.
-
-    ``train_masks``/``alpha0s``/``f0s`` carry a leading fold axis; ``Cs`` is
-    a scalar or (b,) vector (per-cell C for hyper-parameter grids). Returns
-    a fold-batched ``SMOResult``. Converged folds freeze while stragglers
-    keep iterating — see ``engine.solve_batched``. ``n_iter0s`` pre-loads
-    per-lane iteration counters when resuming a checkpointed batched run
-    (mirrors the single-lane ``n_iter0``).
-    """
-    return solve_batched(DenseKernel(K), y, train_masks, Cs, alpha0s, f0s,
-                         tol=tol, max_iter=max_iter, wss=wss,
-                         chunk_iters=chunk_iters, n_iter0s=n_iter0s)

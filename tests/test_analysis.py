@@ -3,10 +3,10 @@
 The headline test is the calibration contract: the plan analyzer's
 predicted distinct-program count must match the MEASURED jit cache misses
 of an actual grid run. Width-capped schedules realize every predicted
-width deterministically, so caps 1 and 2 assert exact equality (both
-pools); the unbounded schedule is a can-produce upper bound — lanes that
-converge in lockstep may never visit intermediate widths — so it asserts
-measured <= predicted (DESIGN.md §Static analysis).
+width deterministically, so caps 1 and 2 assert exact equality (one
+gamma or three); the unbounded schedule is a can-produce upper bound —
+lanes that converge in lockstep may never visit intermediate widths — so
+it asserts measured <= predicted (DESIGN.md §Static analysis).
 """
 import json
 import pathlib
@@ -62,32 +62,30 @@ def _small_plan(cache_bytes=0, evaluate=True):
 
 # ------------------------------------------------- predicted vs measured
 
-def _predicted(pool, max_width):
-    plans = grid_plans(_heart(), *_cs_gammas(), pool=pool,
-                       max_width=max_width, **_grid_kwargs())
-    progs = set()
-    for p in plans:
-        pa = analyze_plan(p)
-        assert pa.ok, pa.report.render()
-        progs |= set(map(tuple, pa.programs))
-    return len(progs)
+def _predicted(gammas, max_width):
+    (plan,) = grid_plans(_heart(), _cs_gammas()[0], gammas,
+                         max_width=max_width, **_grid_kwargs())
+    pa = analyze_plan(plan)
+    assert pa.ok, pa.report.render()
+    return len(set(map(tuple, pa.programs)))
 
 
-def _measured(pool, max_width):
+def _measured(gammas, max_width):
     chunk_jit.clear_cache()
     chunk_batched_jit.clear_cache()
-    run_grid(_heart(), *_cs_gammas(), pool=pool, max_width=max_width,
+    run_grid(_heart(), _cs_gammas()[0], gammas, max_width=max_width,
              **_grid_kwargs())
     return chunk_jit._cache_size() + chunk_batched_jit._cache_size()
 
 
-@pytest.mark.parametrize("pool", ["cross_gamma", "per_gamma"])
+@pytest.mark.parametrize("gammas", [_cs_gammas()[1][:1], _cs_gammas()[1]],
+                         ids=["one_gamma", "all_gammas"])
 @pytest.mark.parametrize("max_width", [1, 2])
-def test_predicted_programs_match_measured_compiles(pool, max_width):
+def test_predicted_programs_match_measured_compiles(gammas, max_width):
     """Width-capped schedules: analyzer prediction == jit cache misses,
-    exactly. The jit cache is global, so per_gamma's three pools share
-    compiles — same count as the single cross-gamma pool."""
-    assert _predicted(pool, max_width) == _measured(pool, max_width) \
+    exactly. The jit cache is global, so the sources of a multi-gamma
+    pool share compiles — same count as a one-gamma pool."""
+    assert _predicted(gammas, max_width) == _measured(gammas, max_width) \
         == max_width
 
 
@@ -95,16 +93,16 @@ def test_unbounded_width_is_an_upper_bound():
     """max_width=0 (uncapped): every predicted width CAN occur, but a
     lockstep schedule may skip intermediate ones — measured never exceeds
     predicted."""
-    predicted = _predicted("cross_gamma", 0)
+    gammas = _cs_gammas()[1]
+    predicted = _predicted(gammas, 0)
     assert predicted == len(possible_widths(3, 4, 0)) == 3
-    assert _measured("cross_gamma", 0) <= predicted
+    assert _measured(gammas, 0) <= predicted
 
 
 def test_analyzer_enumerates_exact_grid_plans():
     """grid_plans IS run_grid's builder: per-source peaks reflect the
     fold-chain DAG (3 independent cells per gamma, folds chained)."""
-    (plan,) = grid_plans(_heart(), *_cs_gammas(), pool="cross_gamma",
-                         **_grid_kwargs())
+    (plan,) = grid_plans(_heart(), *_cs_gammas(), **_grid_kwargs())
     pa = analyze_plan(plan)
     assert pa.ok
     assert set(pa.per_source) == {0, 1, 2}

@@ -6,10 +6,10 @@ import numpy as np
 import pytest
 
 from repro.data.svm_suite import make_dataset, kfold_chunks
-from repro.svm import (DenseKernel, FusedRBF, OnDemandRBF, PallasRBF, init_f,
-                       kernel_matrix, smo_solve, smo_solve_batched)
+from repro.svm import (DenseKernel, FusedRBF, LanePool, OnDemandRBF,
+                       PallasRBF, init_f, kernel_matrix, smo_solve)
 from repro.svm.distributed import smo_iterations
-from repro.svm.engine import EngineState, smo_chunk, solve, solve_batched
+from repro.svm.engine import EngineState, smo_chunk, solve
 
 
 def _setup(name="heart", n=150):
@@ -18,6 +18,25 @@ def _setup(name="heart", n=150):
     y = jnp.asarray(ds.y, jnp.float64)
     K = kernel_matrix(X, X, gamma=ds.gamma)
     return ds, X, K, y
+
+
+def _pool_batch(source, y, masks, Cs, alpha0s, f0s, *, wss="2",
+                max_iter=10_000_000, n_iter0s=None):
+    """Solve one lane per row of ``masks`` on a LanePool at the batch's
+    full width: ``max_width=0`` and ``lane_quantum`` = the lane count, so
+    the packed width is the batch's own until lanes retire. Returns the
+    lanes' SMOResults stacked along a leading lane axis."""
+    b = masks.shape[0]
+    Cs = np.broadcast_to(np.asarray(Cs, np.float64), (b,))
+    n_iter0s = np.broadcast_to(
+        np.asarray(0 if n_iter0s is None else n_iter0s), (b,))
+    pool = LanePool({"src": source}, y, wss=wss, max_width=0,
+                    lane_quantum=b, chunk_iters=4096)
+    for h in range(b):
+        pool.add(h, masks[h], float(Cs[h]), alpha0s[h], f0s[h], source="src",
+                 n_iter0=int(n_iter0s[h]), max_iter=max_iter)
+    res = pool.run()
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *(res[h] for h in range(b)))
 
 
 # ------------------------------------------------- kernel-source parity ---
@@ -172,8 +191,8 @@ def test_batched_folds_match_sequential_bitwise():
     for h in range(k):
         masks[h, chunks[h]] = False
     masks = jnp.asarray(masks)
-    bat = smo_solve_batched(K2, y2, masks, ds.C, jnp.zeros((k, n)),
-                            jnp.tile(-y2, (k, 1)))
+    bat = _pool_batch(DenseKernel(K2), y2, masks, ds.C, jnp.zeros((k, n)),
+                      jnp.tile(-y2, (k, 1)))
     for h in range(k):
         seq = smo_solve(K2, y2, masks[h], ds.C, jnp.zeros(n), -y2)
         np.testing.assert_array_equal(np.asarray(seq.alpha),
@@ -190,8 +209,8 @@ def test_batched_per_lane_C():
     n = y.shape[0]
     mask = jnp.ones(n, bool).at[:20].set(False)
     Cs = jnp.asarray([0.5, 4.0, 32.0])
-    bat = smo_solve_batched(K, y, jnp.tile(mask[None], (3, 1)), Cs,
-                            jnp.zeros((3, n)), jnp.tile(-y, (3, 1)))
+    bat = _pool_batch(DenseKernel(K), y, jnp.tile(mask[None], (3, 1)), Cs,
+                      jnp.zeros((3, n)), jnp.tile(-y, (3, 1)))
     for lane, C in enumerate([0.5, 4.0, 32.0]):
         seq = smo_solve(K, y, mask, C, jnp.zeros(n), -y)
         np.testing.assert_array_equal(np.asarray(seq.alpha),
@@ -218,7 +237,7 @@ def test_batched_warm_seeds():
     masks = jnp.stack([m1, m1])
     alpha0s = jnp.stack([jnp.zeros(n), a1])
     f0s = jnp.stack([-y2, f1])
-    bat = smo_solve_batched(K2, y2, masks, ds.C, alpha0s, f0s)
+    bat = _pool_batch(DenseKernel(K2), y2, masks, ds.C, alpha0s, f0s)
     assert int(bat.n_iter[1]) < int(bat.n_iter[0])
     cold = smo_solve(K2, y2, m1, ds.C, jnp.zeros(n), -y2)
     warm = smo_solve(K2, y2, m1, ds.C, a1, f1)
@@ -256,7 +275,8 @@ def test_pallas_source_matches_fused_bitwise(name, n, max_iter):
 
 
 def test_pallas_source_batched_bitwise():
-    """The parity holds under vmap (the pool's batched dispatch path)."""
+    """The parity holds under vmap (the pool's batched dispatch path) at
+    width 3, the widest at which the two programs stay bitwise."""
     ds = make_dataset("heart", n_override=120)
     X = jnp.asarray(ds.X)
     y = jnp.asarray(ds.y, jnp.float64)
@@ -267,8 +287,8 @@ def test_pallas_source_batched_bitwise():
     Cs = jnp.asarray([ds.C, 4.0 * ds.C, ds.C])
     a0 = jnp.zeros((3, n))
     f0 = jnp.tile(-y, (3, 1))
-    fb = solve_batched(FusedRBF(X, ds.gamma), y, masks, Cs, a0, f0, wss="1")
-    pb = solve_batched(PallasRBF(X, ds.gamma), y, masks, Cs, a0, f0, wss="1")
+    fb = _pool_batch(FusedRBF(X, ds.gamma), y, masks, Cs, a0, f0, wss="1")
+    pb = _pool_batch(PallasRBF(X, ds.gamma), y, masks, Cs, a0, f0, wss="1")
     for a, b in zip(fb, pb):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -321,10 +341,8 @@ def test_pallas_wide_batch_tolerance():
                        for h in range(5)])
     a0 = jnp.zeros((5, n))
     f0 = jnp.tile(-y, (5, 1))
-    fb = solve_batched(FusedRBF(X, ds.gamma), y, masks, ds.C, a0, f0,
-                       wss="1")
-    pb = solve_batched(PallasRBF(X, ds.gamma), y, masks, ds.C, a0, f0,
-                       wss="1")
+    fb = _pool_batch(FusedRBF(X, ds.gamma), y, masks, ds.C, a0, f0, wss="1")
+    pb = _pool_batch(PallasRBF(X, ds.gamma), y, masks, ds.C, a0, f0, wss="1")
     assert bool(jnp.all(fb.converged)) and bool(jnp.all(pb.converged))
     np.testing.assert_allclose(np.asarray(fb.alpha), np.asarray(pb.alpha),
                                atol=1e-10)
@@ -403,9 +421,8 @@ def test_run_cv_batched_pallas_backend():
     assert rep.accuracy == pytest.approx(cold.accuracy, abs=1e-12)
     for fp, fd in zip(rep.folds, cold.folds):
         assert fp.objective == pytest.approx(fd.objective, rel=1e-5)
-    with pytest.raises(ValueError, match="repacked"):
-        run_cv_batched(ds, k=4, source_backend="pallas_rbf",
-                       schedule="batched")
+    with pytest.raises(ValueError, match="source_backend"):
+        run_cv_batched(ds, k=4, source_backend="pallas")
 
 
 def test_grid_pallas_resident_is_n2_independent():
@@ -464,26 +481,27 @@ def test_solver_halts_on_nan_state():
     assert int(res.n_iter) == 0   # halted before any update was applied
 
 
-@pytest.mark.parametrize("schedule,label", [
-    ("batched", "cold_batched"), ("repacked", "cold_batched_repacked")])
-def test_run_cv_batched_matches_cold_cv(schedule, label):
+@pytest.mark.parametrize("max_width", [1, 2, 4])
+def test_run_cv_batched_matches_cold_cv(max_width):
+    """The k-lane plan replays ``run_cv(method="cold")`` fold for fold at
+    every dispatch width, up to all k folds in one batched program."""
     from repro.core.cv import run_cv, run_cv_batched
     ds = make_dataset("heart", n_override=120)
     cold = run_cv(ds, k=4, method="cold")
-    bat = run_cv_batched(ds, k=4, schedule=schedule)
-    assert bat.method == label
+    bat = run_cv_batched(ds, k=4, max_width=max_width)
+    assert bat.method == "cold_batched_repacked"
     assert bat.accuracy == pytest.approx(cold.accuracy, abs=1e-12)
     assert [f.n_iter for f in bat.folds] == [f.n_iter for f in cold.folds]
     assert all(f.converged for f in bat.folds)
-    if schedule == "repacked":
-        assert bat.occupancy["chunks"] >= 1
-        assert bat.occupancy["peak_width"] >= 1
+    assert bat.occupancy["chunks"] >= 1
+    assert bat.occupancy["peak_width"] == max_width
 
 
 def test_solve_batched_n_iter0s_resume_bitwise():
-    """A capped batched run resumed with per-lane ``n_iter0s`` replays the
-    uninterrupted iterate sequence — alpha, f AND the n_iter account —
-    mirroring the single-lane ``solve(..., n_iter0=...)`` path."""
+    """A capped batch of pool lanes resumed with per-lane ``n_iter0``
+    replays the uninterrupted iterate sequence — alpha, f AND the n_iter
+    account — mirroring the single-lane ``solve(..., n_iter0=...)``
+    path."""
     ds, X, K, y = _setup(n=120)
     n = y.shape[0]
     masks = jnp.stack([jnp.ones(n, bool).at[:20].set(False),
@@ -491,17 +509,18 @@ def test_solve_batched_n_iter0s_resume_bitwise():
     Cs = jnp.asarray([ds.C, 4.0 * ds.C])
     a0 = jnp.zeros((2, n))
     f0 = jnp.tile(-y, (2, 1))
-    full = smo_solve_batched(K, y, masks, Cs, a0, f0)
-    part = smo_solve_batched(K, y, masks, Cs, a0, f0, max_iter=150)
+    src = DenseKernel(K)
+    full = _pool_batch(src, y, masks, Cs, a0, f0)
+    part = _pool_batch(src, y, masks, Cs, a0, f0, max_iter=150)
     np.testing.assert_array_equal(np.asarray(part.n_iter), [150, 150])
-    resumed = smo_solve_batched(K, y, masks, Cs, part.alpha, part.f,
-                                n_iter0s=part.n_iter)
+    resumed = _pool_batch(src, y, masks, Cs, part.alpha, part.f,
+                          n_iter0s=part.n_iter)
     for a, b in zip(full, resumed):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # the cap counts TOTAL updates incl. the preload: resuming a 150-iter
     # state under max_iter=150 must apply zero further updates
-    recapped = smo_solve_batched(K, y, masks, Cs, part.alpha, part.f,
-                                 n_iter0s=part.n_iter, max_iter=150)
+    recapped = _pool_batch(src, y, masks, Cs, part.alpha, part.f,
+                           n_iter0s=part.n_iter, max_iter=150)
     np.testing.assert_array_equal(np.asarray(recapped.alpha),
                                   np.asarray(part.alpha))
     np.testing.assert_array_equal(np.asarray(recapped.n_iter), [150, 150])

@@ -27,7 +27,7 @@ def test_grid_covers_all_cells(ds):
     assert best.accuracy == max(c.accuracy for c in rep.cells)
 
 
-@pytest.mark.parametrize("method", ["sir", "cold"])
+@pytest.mark.parametrize("method", ["sir", "cold", "mir", "ato"])
 def test_grid_cell_matches_run_cv(ds, method):
     """Each grid cell must reproduce the standalone CV run on that cell's
     hyper-parameters exactly (same engine, same seeds, same schedule)."""

@@ -5,9 +5,9 @@ import numpy as np
 import pytest
 
 from repro.kernels.ops import (flash_attention, fused_smo_step,
-                               rbf_kernel_matrix, smo_f_update)
+                               rbf_kernel_matrix)
 from repro.kernels.ref import (flash_attention_ref, fused_smo_step_ref,
-                               rbf_kernel_matrix_ref, smo_f_update_ref)
+                               rbf_kernel_matrix_ref)
 
 RNG = np.random.default_rng(7)
 
@@ -59,21 +59,14 @@ def test_flash_attention_bf16():
                                np.asarray(r, np.float32), atol=0.06)
 
 
-@pytest.mark.parametrize("n", [100, 1000, 8192, 10_000])
-def test_smo_f_update(n):
-    f = jnp.asarray(RNG.normal(size=(n,)))
-    Ki = jnp.asarray(RNG.normal(size=(n,)))
-    Kj = jnp.asarray(RNG.normal(size=(n,)))
-    out = smo_f_update(f, Ki, Kj, 0.37, block=1024)
-    ref = smo_f_update_ref(f, Ki, Kj, 0.37)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-12)
-
-
 def _step_problem(n, d, dtype):
-    X = jnp.asarray(RNG.normal(size=(n, d)), dtype)
+    # a generator of the problem's own, so a case's data does not hang on
+    # which tests ran before it in the process
+    rng = np.random.default_rng([n, d])
+    X = jnp.asarray(rng.normal(size=(n, d)), dtype)
     xij = X[jnp.asarray([3, n - 1])]       # a real WSS pair's feature rows
     sq = jnp.sum(X * X, axis=1)
-    f = jnp.asarray(RNG.normal(size=(n,)), dtype)
+    f = jnp.asarray(rng.normal(size=(n,)), dtype)
     return f, X, xij, sq, jnp.asarray(0.37, dtype)
 
 
@@ -83,6 +76,8 @@ def _step_problem(n, d, dtype):
     (120, 40, 32, 16),    # multi-block on both axes
     (160, 13, 40, 13),    # bm divides n over 4 lane-dense blocks, bk = d
     (150, 13, 40, 13),    # bm does not divide n: padded rows, bk = d
+    (1000, 13, 96, 13),   # the f update at n = 1000, 11 blocks, last ragged
+    (10_000, 9, 1024, 9),  # the f update at n = 10,000, last block ragged
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
 def test_fused_smo_step_ragged(n, d, bm, bk, dtype):

@@ -1,4 +1,4 @@
-"""LaneScheduler: per-lane bit-parity with sequential solves under forced
+"""LanePool: per-lane bit-parity with sequential solves under forced
 repack boundaries, mixed convergence orders, dependency admission, and
 resume-from-mid-batch checkpoints (by original lane id)."""
 import jax
@@ -8,7 +8,7 @@ import pytest
 
 from repro.core.cv import _fold_masks, _transition_idx
 from repro.data.svm_suite import kfold_chunks, make_dataset
-from repro.svm import (DenseKernel, LaneScheduler, init_f, kernel_matrix,
+from repro.svm import (DenseKernel, LanePool, init_f, kernel_matrix,
                        smo_solve)
 from repro.svm.precision import kernel_input
 from repro.svm.scheduler import bucket_width
@@ -46,11 +46,11 @@ def test_scheduler_parity_bitwise_all_suite(name, max_width):
     width that parks/rotates lanes (max_width=3 over 4 lanes)."""
     ds, K, y, chunks, masks = _setup(name)
     n = y.shape[0]
-    sched = LaneScheduler(DenseKernel(K), y, chunk_iters=64, lane_quantum=2,
-                          max_width=max_width)
+    pool = LanePool({"K": DenseKernel(K)}, y, chunk_iters=64,
+                    lane_quantum=2, max_width=max_width)
     for h in range(4):
-        sched.add(h, masks[h], ds.C, jnp.zeros(n, K.dtype), -y)
-    results = sched.run()
+        pool.add(h, masks[h], ds.C, jnp.zeros(n, K.dtype), -y)
+    results = pool.run()
     for h in range(4):
         seq = smo_solve(K, y, masks[h], ds.C, jnp.zeros(n), -y)
         np.testing.assert_array_equal(np.asarray(seq.alpha),
@@ -59,7 +59,7 @@ def test_scheduler_parity_bitwise_all_suite(name, max_width):
                                       np.asarray(results[h].f))
         assert int(seq.n_iter) == int(results[h].n_iter)
         assert bool(results[h].converged) == bool(seq.converged)
-    occ = sched.occupancy
+    occ = pool.occupancy
     assert occ["chunks"] > 1
     if max_width == 0:
         assert occ["peak_width"] >= 4
@@ -80,11 +80,11 @@ def test_scheduler_mixed_convergence_orders():
     warm = smo_solve(K, y, masks[0], ds.C, jnp.zeros(n), -y)
     inits = [(jnp.zeros(n, K.dtype), -y)] * 4 + [(warm.alpha, warm.f)]
     lane_masks = [masks[h % 4] for h in range(4)] + [masks[0]]
-    sched = LaneScheduler(DenseKernel(K), y, chunk_iters=32, lane_quantum=2,
-                          max_width=0)
+    pool = LanePool({"K": DenseKernel(K)}, y, chunk_iters=32,
+                    lane_quantum=2, max_width=0)
     for i, (C, (a0, f0), mask) in enumerate(zip(Cs, inits, lane_masks)):
-        sched.add(i, mask, C, a0, f0)
-    results = sched.run()
+        pool.add(i, mask, C, a0, f0)
+    results = pool.run()
     orders = set()
     for i, (C, (a0, f0), mask) in enumerate(zip(Cs, inits, lane_masks)):
         seq = smo_solve(K, y, mask, C, a0, f0)
@@ -106,49 +106,48 @@ def test_scheduler_admission_matches_cv_chain():
     rep = run_cv(ds, k=4, method="sir")
     _, K, y, chunks, masks = _setup("heart")
     n = y.shape[0]
-    sched = LaneScheduler(DenseKernel(K), y, chunk_iters=64, lane_quantum=2)
-    sched.add(0, masks[0], ds.C, jnp.zeros(n, K.dtype), -y)
+    pool = LanePool({"K": DenseKernel(K)}, y, chunk_iters=64, lane_quantum=2)
+    pool.add(0, masks[0], ds.C, jnp.zeros(n, K.dtype), -y)
     for h in range(1, 4):
         S, R, T = _transition_idx(chunks, h - 1, h)
 
         def seed_fn(prev, C=ds.C, S=S, R=R, T=T):
             a0 = seeding.sir_seed(K, y, C, prev, S, R, T)
             return a0, init_f(K, y, a0)
-        sched.add(h, masks[h], ds.C, dep=h - 1, seed_fn=seed_fn)
-    results = sched.run()
+        pool.add(h, masks[h], ds.C, dep=h - 1, seed_fn=seed_fn)
+    results = pool.run()
     assert [int(results[h].n_iter) for h in range(4)] == \
         [f.n_iter for f in rep.folds]
-    assert sched.seed_time > 0.0
+    assert pool.seed_time > 0.0
 
 
 def test_scheduler_snapshot_resume_bitwise():
-    """Rebuild a scheduler from any mid-batch snapshot — retired lanes via
+    """Rebuild a pool from any mid-batch snapshot — retired lanes via
     add_result, live lanes via their (alpha, f, n_iter) keyed by original
     lane id — and finish with bit-identical results."""
-    from repro.svm.engine import EngineState, _finalize
+    from repro.svm.engine import EngineState, finalize
     ds, K, y, chunks, masks = _setup("heart")
     n = y.shape[0]
     snaps = []
-    sched = LaneScheduler(DenseKernel(K), y, chunk_iters=64, lane_quantum=2,
-                          max_width=0,
-                          on_snapshot=lambda s: snaps.append(
-                              s.snapshot_lanes()))
+    pool = LanePool({"K": DenseKernel(K)}, y, chunk_iters=64,
+                    lane_quantum=2, max_width=0,
+                    on_snapshot=lambda s: snaps.append(s.snapshot_lanes()))
     for h in range(4):
-        sched.add(h, masks[h], ds.C, jnp.zeros(n, K.dtype), -y)
-    full = sched.run()
+        pool.add(h, masks[h], ds.C, jnp.zeros(n, K.dtype), -y)
+    full = pool.run()
     assert len(snaps) >= 3, "solve should span several chunks"
     mid = len(snaps) // 2
     ids, tree = snaps[mid]
     assert ids == [0, 1, 2, 3]
     # resume under a DIFFERENT schedule shape (width-1 round-robin): the
     # snapshot is keyed by lane id, so packing at crash time is irrelevant
-    resumed = LaneScheduler(DenseKernel(K), y, chunk_iters=64,
-                            lane_quantum=2, max_width=1)
+    resumed = LanePool({"K": DenseKernel(K)}, y, chunk_iters=64,
+                       lane_quantum=2, max_width=1)
     for i, h in enumerate(ids):
         if bool(tree["done"][i]):
             state = EngineState(tree["alpha"][i], tree["f"][i],
                                 tree["n_iter"][i], jnp.ones((), bool))
-            resumed.add_result(h, _finalize(state, y, masks[h], ds.C, 1e-3))
+            resumed.add_result(h, finalize(state, y, masks[h], ds.C, 1e-3))
         else:
             resumed.add(h, masks[h], ds.C, tree["alpha"][i], tree["f"][i],
                         n_iter0=int(tree["n_iter"][i]))
@@ -208,11 +207,11 @@ def test_scheduler_single_lane_degrades_to_sequential():
     single-lane (width 1) path, bit-identical to engine.solve."""
     ds, K, y, chunks, masks = _setup("heart")
     n = y.shape[0]
-    sched = LaneScheduler(DenseKernel(K), y, chunk_iters=64)
-    sched.add("only", masks[0], ds.C, jnp.zeros(n, K.dtype), -y)
-    results = sched.run()
-    assert sched.occupancy["peak_width"] == 1
-    assert sched.occupancy["programs"] == 1
+    pool = LanePool({"K": DenseKernel(K)}, y, chunk_iters=64)
+    pool.add("only", masks[0], ds.C, jnp.zeros(n, K.dtype), -y)
+    results = pool.run()
+    assert pool.occupancy["peak_width"] == 1
+    assert pool.occupancy["programs"] == 1
     seq = smo_solve(K, y, masks[0], ds.C, jnp.zeros(n), -y)
     np.testing.assert_array_equal(np.asarray(seq.alpha),
                                   np.asarray(results["only"].alpha))
@@ -222,27 +221,27 @@ def test_scheduler_single_lane_degrades_to_sequential():
 def test_scheduler_deadlock_detection():
     ds, K, y, chunks, masks = _setup("heart")
     n = y.shape[0]
-    sched = LaneScheduler(DenseKernel(K), y, chunk_iters=64)
-    sched.add(0, masks[0], ds.C, jnp.zeros(n, K.dtype), -y)
-    sched.add(1, masks[1], ds.C, dep="missing",
+    pool = LanePool({"K": DenseKernel(K)}, y, chunk_iters=64)
+    pool.add(0, masks[0], ds.C, jnp.zeros(n, K.dtype), -y)
+    pool.add(1, masks[1], ds.C, dep="missing",
               seed_fn=lambda prev: (prev.alpha, prev.f))
     with pytest.raises(RuntimeError, match="never retire"):
-        sched.run()
+        pool.run()
 
 
 def test_scheduler_rejects_bad_lane_specs():
     ds, K, y, chunks, masks = _setup("heart")
     n = y.shape[0]
-    sched = LaneScheduler(DenseKernel(K), y)
-    sched.add(0, masks[0], ds.C, jnp.zeros(n, K.dtype), -y)
+    pool = LanePool({"K": DenseKernel(K)}, y)
+    pool.add(0, masks[0], ds.C, jnp.zeros(n, K.dtype), -y)
     with pytest.raises(ValueError, match="duplicate"):
-        sched.add(0, masks[0], ds.C, jnp.zeros(n, K.dtype), -y)
+        pool.add(0, masks[0], ds.C, jnp.zeros(n, K.dtype), -y)
     with pytest.raises(ValueError, match="exactly one"):
-        sched.add(1, masks[1], ds.C)
+        pool.add(1, masks[1], ds.C)
     with pytest.raises(ValueError, match="together"):
-        sched.add(1, masks[1], ds.C, jnp.zeros(n, K.dtype))
+        pool.add(1, masks[1], ds.C, jnp.zeros(n, K.dtype))
     with pytest.raises(ValueError, match="seed_fn"):
-        sched.add(2, masks[2], ds.C, dep=0)
+        pool.add(2, masks[2], ds.C, dep=0)
 
 
 def test_engine_state_lane_helpers():

@@ -360,20 +360,6 @@ def test_ato_coupled_working_set(monkeypatch):
     assert bool(jnp.all(~differs | near_zero))
 
 
-def test_ato_seed_batch_matches_solo():
-    """The vmapped batch entry (the grid's C-row path) reproduces the solo
-    seeder lane for lane."""
-    import jax
-    ds, K, y, chunks, res0, (S, R, T) = _fold_setup("heart", n=150, k=5)
-    prev2 = jax.tree.map(lambda a: jnp.stack([a, a]), res0)
-    a2 = seeding.ato_seed_batch(K, y, jnp.asarray([ds.C, ds.C]), prev2,
-                                S, R, T)
-    a1 = seeding.ato_seed(K, y, ds.C, res0, S, R, T)
-    assert a2.shape == (2,) + a1.shape
-    np.testing.assert_array_equal(np.asarray(a2[0]), np.asarray(a2[1]))
-    np.testing.assert_allclose(np.asarray(a2[0]), np.asarray(a1), atol=1e-9)
-
-
 # ------------------------------------------------------ property tests -----
 
 @settings(max_examples=20, deadline=None)

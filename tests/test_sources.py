@@ -9,7 +9,7 @@ import pytest
 
 from repro.checkpoint import CheckpointManager
 from repro.core.cv import _fold_masks
-from repro.core.grid import _merge_occupancy, run_grid
+from repro.core.grid import run_grid
 from repro.core.study import Plan, run_plan
 from repro.data.svm_suite import kfold_chunks, make_dataset
 from repro.svm import (DenseKernel, FusedRBF, KernelSpec, LanePool,
@@ -171,7 +171,7 @@ def test_pool_capped_selection_prefers_resident_sources():
 
 @pytest.mark.parametrize("name", SUITE)
 def test_run_grid_lru_budgets_bit_parity(name):
-    """run_grid(pool="cross_gamma") under max_resident=1 / 2 / unbounded
+    """run_grid under max_resident=1 / 2 / unbounded
     must produce bit-identical cells (iterations AND correct-counts) on
     every suite dataset — eviction/re-materialization schedules are
     unobservable in the results — while peak residency obeys the budget."""
@@ -329,31 +329,3 @@ def test_run_plan_rejects_non_dense_pinned_source_at_entry():
     assert int(r_od.evals[0][0]) == int(r_dense.evals[0][0])
 
 
-# --------------------------------------------------- occupancy merge fix
-
-def test_merge_occupancy_sums_programs_and_merges_per_source():
-    """programs is a distinct-compiled-programs bound: summing across
-    pools, not max (the old max undercounted); per_source blocks merge by
-    key instead of being dropped."""
-    rows = [
-        {"chunks": 10, "mean_live_width": 2.0, "mean_packed_width": 1.5,
-         "peak_width": 4, "programs": 3,
-         "per_source": {"0": {"chunks": 10, "mean_live_width": 2.0,
-                              "peak_live_width": 4}}},
-        {"chunks": 30, "mean_live_width": 1.0, "mean_packed_width": 1.0,
-         "peak_width": 2, "programs": 2,
-         "per_source": {"0": {"chunks": 10, "mean_live_width": 1.0,
-                              "peak_live_width": 2},
-                        "1": {"chunks": 20, "mean_live_width": 3.0,
-                              "peak_live_width": 5}}},
-    ]
-    merged = _merge_occupancy(rows)
-    assert merged["programs"] == 5                      # 3 + 2, not max
-    assert merged["chunks"] == 40
-    assert merged["mean_live_width"] == 1.25            # chunk-weighted
-    assert merged["per_source"]["0"] == {
-        "chunks": 20, "mean_live_width": 1.5, "peak_live_width": 4}
-    assert merged["per_source"]["1"] == {
-        "chunks": 20, "mean_live_width": 3.0, "peak_live_width": 5}
-    assert _merge_occupancy([]) is None
-    assert _merge_occupancy([{"chunks": 0}])["chunks"] == 0

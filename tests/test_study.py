@@ -429,18 +429,18 @@ def test_run_loo_kill_resume(tmp_path):
 @pytest.mark.parametrize("name", SUITE)
 def test_run_grid_pooled_matches_per_row(name):
     """The cross-gamma pooled grid must be bit-identical (per-cell
-    iteration counts AND accuracies) to the per-row scheduler baseline on
+    iteration counts AND accuracies) to one single-gamma grid per row on
     every suite dataset."""
     from repro.core.grid import run_grid
     ds = make_dataset(name, n_override=100)
-    kw = dict(Cs=[ds.C, 4 * ds.C], gammas=[0.5 * ds.gamma, 2 * ds.gamma],
-              k=3, method="sir", chunk_iters=256)
-    pooled = run_grid(ds, pool="cross_gamma", **kw)
-    rows = run_grid(ds, pool="per_gamma", **kw)
+    gammas = [0.5 * ds.gamma, 2 * ds.gamma]
+    kw = dict(Cs=[ds.C, 4 * ds.C], k=3, method="sir", chunk_iters=256)
+    pooled = run_grid(ds, gammas=gammas, **kw)
+    rows = [c for g in gammas for c in run_grid(ds, gammas=[g], **kw).cells]
     assert [(c.C, c.gamma, c.iterations, c.acc_correct, c.converged)
             for c in pooled.cells] == \
         [(c.C, c.gamma, c.iterations, c.acc_correct, c.converged)
-         for c in rows.cells]
+         for c in rows]
     assert set(pooled.occupancy["per_source"]) == {"0", "1"}
 
 
