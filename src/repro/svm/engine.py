@@ -29,7 +29,8 @@ has not been materialized yet:
   rows in one pass over X (halves the dominant HBM stream).
 * ``PallasRBF``    — FusedRBF's math as ONE fused Pallas launch per
   iteration (``kernels/smo_step.py``): kernel-row pair + rank-2 f-update
-  in a single blocked pass over X, never materializing rows in HBM.
+  in a single blocked pass over a feature-major copy of X, never
+  materializing rows in HBM.
   ``streams_rows = True`` — the engine routes the f-update through
   ``update_f(f, i, j, delta)`` instead of asking for rows.
 * ``ShardedRBF``   — OnDemandRBF/FusedRBF plus logical-axis sharding
@@ -77,7 +78,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.rbf import auto_interpret
-from repro.kernels.smo_step import fused_smo_step
+from repro.kernels.smo_step import feature_major, fused_smo_step
 from repro.sharding import constrain
 from repro.svm.precision import STATE_DTYPE, kdot
 
@@ -401,42 +402,49 @@ class FusedRBF(OnDemandRBF):
 class PallasRBF(OnDemandRBF):
     """Row-streaming RBF source over the fused Pallas step kernel.
 
-    Holds only X (``nbytes`` = X bytes, not n² kernel bytes): each SMO
-    iteration is one blocked pass over X that computes the WSS-1 pair's
-    kernel rows on the MXU and applies ``f += delta * (K_i - K_j)`` on the
-    VPU in the same launch (``kernels/smo_step.py``) — the rows never hit
-    HBM. The pair's two feature rows are read by index (``_pair``), not
-    by the one-hot product the sharded sources use: X lives on one
-    device, and the product would be a second pass over all of it.
+    Holds X twice, row-major and feature-major (``nbytes`` counts both:
+    O(n*d), not n² kernel bytes): each SMO iteration is one blocked pass
+    over the feature-major ``Xt`` that computes the WSS-1 pair's kernel
+    rows on the VPU and applies ``f += delta * (K_i - K_j)`` in the same
+    launch (``kernels/smo_step.py``) — the rows never hit HBM. ``Xt`` is
+    built once, here, padded to the launch's blocks, and travels with the
+    source as a pytree child, so no chunk program transposes X. The
+    row-major X serves the pair's two feature rows, read by index
+    (``_pair``), not by the one-hot product the sharded sources use (X
+    lives on one device, and the product would be a second pass over all
+    of it), and ``rows_at``/``matvec``.
     ``streams_rows = True`` tells the engine to route the update
     through ``update_f(f, i, j, delta)`` / ``kij(i, j)`` instead of
     materializing rows; selection must therefore be WSS-1 (``fused``).
 
-    Interpret-mode contract: on CPU (``interpret=None`` auto) the kernel
-    runs with full-array blocks — no padding, one contraction step — so
-    every op matches ``FusedRBF``'s jnp expression and alpha/f are
-    bit-identical to ``FusedRBF``, solo and vmapped under the lane pool
-    (tests/test_engine.py). Compiled launches take blocks derived from
-    (n, d) and VMEM (``kernels/smo_step.compiled_blocks``) unless
-    ``bm``/``bk`` are given, need a float32 X (the precision policy), and
-    carry the usual allclose guarantee only.
+    Interpret-mode contract: on CPU (``interpret=None`` auto) with no
+    ``bm``/``bk`` the kernel runs the parity configuration over the
+    row-major X, so every op matches ``FusedRBF``'s jnp expression and
+    alpha/f are bit-identical to ``FusedRBF``, solo and vmapped under the
+    lane pool (tests/test_engine.py). Blocked launches — compiled ones,
+    with blocks derived from (n, d) and VMEM
+    (``kernels/smo_step.compiled_blocks``) unless ``bm``/``bk`` are given
+    — read ``Xt``, need a float32 X (the precision policy), and carry the
+    usual allclose guarantee only.
     """
 
     streams_rows = True
 
     def __init__(self, X, gamma: float, sq_norms=None,
                  impl: str = "onehot_fused", *, bm: int | None = None,
-                 bk: int | None = None, interpret: bool | None = None):
+                 bk: int | None = None, interpret: bool | None = None,
+                 Xt=None):
         super().__init__(X, gamma, sq_norms, impl="onehot_fused")
         self.bm = bm
         self.bk = bk
         self.interpret = auto_interpret(interpret)
+        self.Xt = feature_major(X, bm, bk) if Xt is None else Xt
 
     @property
     def nbytes(self) -> int:
-        """Resident bytes are X's — the whole point: the cache budget
-        bounds rows-from-X sources by O(n*d), not O(n^2)."""
-        return int(self.X.nbytes)
+        """Resident bytes are X's two layouts — the whole point: the cache
+        budget bounds rows-from-X sources by O(n*d), not O(n^2)."""
+        return int(self.X.nbytes) + int(self.Xt.nbytes)
 
     def _pair(self, i, j):
         """The WSS pair's feature rows (2, d), read by index: 2·d floats,
@@ -467,7 +475,16 @@ class PallasRBF(OnDemandRBF):
         xij = self._pair(i, j)
         return fused_smo_step(f, self.X, xij, self.sq_norms, delta,
                               gamma=self.gamma, bm=self.bm, bk=self.bk,
-                              interpret=self.interpret)
+                              interpret=self.interpret, Xt=self.Xt)
+
+    def compact(self, idx):
+        """The active-set source over ``X[idx]`` (pads, index n, clamp to
+        the last row), with its feature-major copy built anew: ``Xt``'s
+        instances lie on its second axis, and its padding follows the
+        compacted n."""
+        idx = jnp.asarray(idx)
+        return PallasRBF(self.X[idx], self.gamma, self.sq_norms[idx],
+                         bm=self.bm, bk=self.bk, interpret=self.interpret)
 
     # rows_at / matvec (the eval-slab and streaming-matvec paths) are
     # inherited from OnDemandRBF — the expressions are row-streaming
@@ -475,15 +492,15 @@ class PallasRBF(OnDemandRBF):
     # bit-identical across the RBF source family.
 
     def tree_flatten(self):
-        return (self.X, self.sq_norms), \
+        return (self.X, self.sq_norms, self.Xt), \
             (self.gamma, self.impl, self.bm, self.bk, self.interpret)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        X, sq_norms = children
+        X, sq_norms, Xt = children
         gamma, impl, bm, bk, interpret = aux
         return cls(X, gamma, sq_norms, impl, bm=bm, bk=bk,
-                   interpret=interpret)
+                   interpret=interpret, Xt=Xt)
 
 
 @jax.tree_util.register_pytree_node_class

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.kernels.smo_step import feature_major_shape
 from repro.svm.engine import DenseKernel, PallasRBF
 from repro.svm.kernels import kernel_matrix
 
@@ -66,10 +67,10 @@ class KernelSpec:
 
     ``kind="pallas_rbf"`` declares a *row-streaming* source: materialize
     returns a :class:`~repro.svm.engine.PallasRBF` holding only ``X[:n]``
-    — ``nbytes`` is X's bytes, not n² kernel bytes, so the cache budget
-    bounds such sources by data size, and ``fused`` is answered True
-    without compute (WSS-1 is checked at pool construction, not deferred
-    to first dispatch).
+    in two layouts — ``nbytes`` is their bytes, not n² kernel bytes, so
+    the cache budget bounds such sources by data size, and ``fused`` is
+    answered True without compute (WSS-1 is checked at pool
+    construction, not deferred to first dispatch).
     """
     X: Any
     gamma: float = 1.0
@@ -100,11 +101,14 @@ class KernelSpec:
     def nbytes(self) -> int:
         """Resident bytes of the materialized source — what the cache
         budget accounts, known without computing anything: n² kernel
-        bytes for dense kinds, X's bytes for row-streaming kinds."""
+        bytes for dense kinds; for row-streaming kinds X's bytes in its
+        two layouts, row-major and the kernel's padded feature-major."""
+        itemsize = self.X.dtype.itemsize
         if self.kind == "pallas_rbf":
             d = int(self.X.shape[1])
-            return self.n_rows * d * self.X.dtype.itemsize
-        return self.n_rows * self.n_rows * self.X.dtype.itemsize
+            D, N = feature_major_shape(self.n_rows, d, itemsize)
+            return (self.n_rows * d + D * N) * itemsize
+        return self.n_rows * self.n_rows * itemsize
 
     def materialize(self):
         X = self.X if self.n is None else self.X[: self.n]

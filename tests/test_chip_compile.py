@@ -18,11 +18,14 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import seeding
 from repro.kernels.rbf import rbf_kernel_matrix
-from repro.kernels.smo_step import compiled_blocks, fused_smo_step
+from repro.kernels.smo_step import (compiled_blocks, feature_major_shape,
+                                     fused_smo_step)
 from repro.svm.engine import (DenseKernel, EngineState, PallasRBF,
                               SMOResult, chunk_batched_jit, chunk_jit)
 
 N, D = 32560, 123
+#: the feature-major X that PallasRBF holds at adult's shape
+DT, NT = feature_major_shape(N, D)
 HBM_BYTES = 16 * 10**9
 
 
@@ -69,14 +72,33 @@ def _state(spec):
                        spec((), jnp.int64), spec((), bool))
 
 
-def test_fused_smo_step_compiles_for_v5e(spec):
-    assert compiled_blocks(N, D) == (3256, D)   # divides n: no padded X copy
+def _compile_step(spec, n, d):
+    """The launch over the feature-major X the source holds, at (n, d)."""
     compiled = _compile(
-        lambda f, X, xij, sq: fused_smo_step(f, X, xij, sq, 0.37, gamma=0.5,
-                                             interpret=False),
-        spec((N,), jnp.float64), spec((N, D), jnp.float32),
-        spec((2, D), jnp.float32), spec((N,), jnp.float32))
+        lambda f, X, Xt, xij, sq: fused_smo_step(
+            f, X, xij, sq, 0.37, gamma=0.5, interpret=False, Xt=Xt),
+        spec((n,), jnp.float64), spec((n, d), jnp.float32),
+        spec(feature_major_shape(n, d), jnp.float32),
+        spec((2, d), jnp.float32), spec((n,), jnp.float32))
     assert "tpu_custom_call" in compiled.as_text()
+    _assert_x_not_rebuilt(compiled.as_text(), n, d)
+
+
+def test_fused_smo_step_compiles_for_v5e(spec):
+    """adult: the whole feature axis, 123 rounded up to 128, in one step,
+    over 8 lane blocks of 4,096 (32,768 lanes, 0.6% over n), with no
+    per-call copy of X."""
+    assert compiled_blocks(N, D) == (4096, 128)
+    assert (DT, NT) == (128, 32768)
+    _compile_step(spec, N, D)
+
+
+def test_fused_smo_step_compiles_for_v5e_at_mnist(spec):
+    """mnist, the widest X: 780 features, 784 in one step, over 59 lane
+    blocks of 1,024 (60,416 lanes), with no per-call copy of X."""
+    assert compiled_blocks(60000, 780) == (1024, 784)
+    assert feature_major_shape(60000, 780) == (784, 60416)
+    _compile_step(spec, 60000, 780)
 
 
 def test_rbf_kernel_matrix_compiles_for_v5e(spec):
@@ -112,14 +134,30 @@ def _assert_one_pass_over_x(text):
     assert "convolution" not in text
 
 
+def _assert_x_not_rebuilt(text, n=N, d=D):
+    """The kernel reads the feature-major X it is given as it is: no
+    array of X's size, in either layout, is transposed, padded, copied,
+    broadcast or built by a fusion in the program (a transpose and pad of
+    X would be a 16.8 MB copy per SMO iteration at adult's n)."""
+    dt, nt = feature_major_shape(n, d)
+    dims = "|".join(f"{a},{b}" for a, b in ((n, d), (d, n), (dt, nt),
+                                            (nt, dt)))
+    built = re.search(rf"= f32\[(\d+,)?({dims})\]\{{[^}}]*\}} "
+                      r"(transpose|pad|copy|copy-start|broadcast|fusion)\(",
+                      text)
+    assert built is None, built.group(0)
+
+
 def test_pallas_chunk_compiles_for_v5e(spec):
-    compiled = _compile(lambda X, sq, y, mask, st: chunk_jit(
-        PallasRBF(X, 0.5, sq, interpret=False), y, mask, 100.0, 1e-3,
-        jnp.asarray(10**6, jnp.int64), st, n_iters=4096, wss="1"),
-        spec((N, D), jnp.float32), spec((N,), jnp.float32),
-        spec((N,), jnp.float64), spec((N,), bool), _state(spec))
+    compiled = _compile(lambda X, Xt, sq, y, mask, st: chunk_jit(
+        PallasRBF(X, 0.5, sq, interpret=False, Xt=Xt), y, mask, 100.0,
+        1e-3, jnp.asarray(10**6, jnp.int64), st, n_iters=4096, wss="1"),
+        spec((N, D), jnp.float32), spec((DT, NT), jnp.float32),
+        spec((N,), jnp.float32), spec((N,), jnp.float64), spec((N,), bool),
+        _state(spec))
     _assert_lane_dense(compiled.as_text())
     _assert_one_pass_over_x(compiled.as_text())
+    _assert_x_not_rebuilt(compiled.as_text())
 
 
 def test_batched_pallas_chunk_compiles_for_v5e(spec):
@@ -129,14 +167,15 @@ def test_batched_pallas_chunk_compiles_for_v5e(spec):
     states = EngineState(spec((lanes, N), jnp.float64),
                          spec((lanes, N), jnp.float64),
                          spec((lanes,), jnp.int64), spec((lanes,), bool))
-    compiled = _compile(lambda X, sq, y, masks, Cs, st: chunk_batched_jit(
-        PallasRBF(X, 0.5, sq, interpret=False), y, masks, Cs, 1e-3,
+    compiled = _compile(lambda X, Xt, sq, y, masks, Cs, st: chunk_batched_jit(
+        PallasRBF(X, 0.5, sq, interpret=False, Xt=Xt), y, masks, Cs, 1e-3,
         jnp.asarray(10**6, jnp.int64), st, n_iters=4096, wss="1"),
-        spec((N, D), jnp.float32), spec((N,), jnp.float32),
-        spec((N,), jnp.float64), spec((lanes, N), bool),
-        spec((lanes,), jnp.float64), states)
+        spec((N, D), jnp.float32), spec((DT, NT), jnp.float32),
+        spec((N,), jnp.float32), spec((N,), jnp.float64),
+        spec((lanes, N), bool), spec((lanes,), jnp.float64), states)
     _assert_lane_dense(compiled.as_text())
     _assert_one_pass_over_x(compiled.as_text())
+    _assert_x_not_rebuilt(compiled.as_text())
 
 
 def test_compiled_launch_refuses_f64_operands(spec):

@@ -377,15 +377,42 @@ def test_pallas_pair_reads_rows_by_index(case):
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
+def test_pallas_compact_solves_as_fresh_source():
+    """A compacted source (the shrinking scheduler's active set, pads
+    clamped to the last row) holds the feature-major X of its own rows,
+    so a blocked launch over it solves exactly as a source built over
+    ``X[idx]`` does."""
+    ds = make_dataset("heart", n_override=150)
+    X = jnp.asarray(ds.X, jnp.float32)
+    y = jnp.asarray(ds.y, jnp.float64)
+    idx = jnp.asarray(np.r_[0:150:2, 150, 150])      # 75 rows and 2 pads
+    blocks = dict(bm=128, bk=8)                      # blocked: reads Xt
+    comp = PallasRBF(X, ds.gamma, **blocks).compact(idx)
+    fresh = PallasRBF(X[idx], ds.gamma, **blocks)
+    np.testing.assert_array_equal(np.asarray(comp.Xt), np.asarray(fresh.Xt))
+    yc = y[idx]
+    mask = jnp.arange(idx.shape[0]) < 75
+    args = (yc, mask, ds.C, jnp.zeros(idx.shape[0]), -yc)
+    rc = solve(comp, *args, wss="1", max_iter=300)
+    rf = solve(fresh, *args, wss="1", max_iter=300)
+    np.testing.assert_array_equal(np.asarray(rc.alpha), np.asarray(rf.alpha))
+    np.testing.assert_array_equal(np.asarray(rc.f), np.asarray(rf.f))
+    assert int(rc.n_iter) == int(rf.n_iter) > 0
+
+
 def test_pallas_nbytes_is_data_not_matrix():
-    """The cache budget must account X's bytes, not n² kernel bytes."""
+    """The cache budget must account X's bytes, in the two layouts the
+    source holds, not n² kernel bytes."""
+    from repro.kernels.smo_step import feature_major_shape
     from repro.svm.sources import KernelSpec
     ds = make_dataset("heart", n_override=150)
     X = jnp.asarray(ds.X)
     src = PallasRBF(X, ds.gamma)
-    assert src.nbytes == X.nbytes
+    assert src.Xt.shape == feature_major_shape(*X.shape, X.dtype.itemsize)
+    assert src.nbytes == X.nbytes + src.Xt.nbytes
     spec = KernelSpec(X, gamma=ds.gamma, kind="pallas_rbf", n=100)
-    assert spec.nbytes == 100 * X.shape[1] * X.dtype.itemsize
+    D, N = feature_major_shape(100, X.shape[1], X.dtype.itemsize)
+    assert spec.nbytes == (100 * X.shape[1] + D * N) * X.dtype.itemsize
     assert spec.fused and spec.streams_rows
     mat = spec.materialize()
     assert isinstance(mat, PallasRBF) and mat.nbytes == spec.nbytes
@@ -427,9 +454,10 @@ def test_run_cv_batched_pallas_backend():
 
 def test_grid_pallas_resident_is_n2_independent():
     """A budgeted grid over pallas sources: peak resident kernel bytes are
-    X bytes per gamma — independent of n² — and accuracy matches the dense
-    cold grid."""
+    X's bytes in the source's two layouts per gamma — independent of n² —
+    and accuracy matches the dense cold grid."""
     from repro.core.grid import run_grid
+    from repro.kernels.smo_step import feature_major_shape
     ds = make_dataset("heart", n_override=120)
     kw = dict(Cs=(0.5, 2.0), gammas=(0.5, 1.0), k=3, method="cold",
               max_resident=1)
@@ -437,7 +465,9 @@ def test_grid_pallas_resident_is_n2_independent():
     dense = run_grid(ds, **kw)
     n = pal.n
     itemsize = 4   # kernel operands are float32 (svm/precision.py)
-    x_bytes = n * ds.X.shape[1] * itemsize
+    # X row-major and the kernel's padded feature-major copy of it
+    D, N = feature_major_shape(n, ds.X.shape[1], itemsize)
+    x_bytes = (n * ds.X.shape[1] + D * N) * itemsize
     assert pal.resident["peak_resident_bytes"] <= x_bytes
     assert pal.resident["peak_resident_bytes"] < n * n * itemsize
     assert dense.resident["peak_resident_bytes"] >= n * n * itemsize

@@ -78,6 +78,9 @@ def _step_problem(n, d, dtype):
     (150, 13, 40, 13),    # bm does not divide n: padded rows, bk = d
     (1000, 13, 96, 13),   # the f update at n = 1000, 11 blocks, last ragged
     (10_000, 9, 1024, 9),  # the f update at n = 10,000, last block ragged
+    (300, 16, 128, 16),   # lane blocks of 128, n not a multiple: 84 pad lanes
+    (600, 13, 256, 16),   # d not a multiple of 8: 3 zero feature rows
+    (700, 21, 256, 8),    # d wider than bk: three feature steps, padded to 24
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
 def test_fused_smo_step_ragged(n, d, bm, bk, dtype):
