@@ -22,6 +22,13 @@ place:
   next fold's rows: a fault in the evaluation, which the compared count
   ``pred_mismatch`` has to catch.
 
+Each solve runs over the dense n x n kernel (``reference.kernel``,
+``reference.smo``) where that fits the chip beside the solve
+(``fits_dense``), and otherwise with the pair's kernel rows computed from
+X in every iteration (``reference.smo_rows``), the held-out decision
+values from blocks of rows: the same solve, for a configuration whose K
+one chip cannot hold.
+
 Each answer is judged by ``reference.check`` as a run judges the
 program's, and its numbers go through ``run.judge`` with the cell's limits
 (``bench/limits``), the comparison that decides a run's ``correct``: one
@@ -33,6 +40,7 @@ fault) gives.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import pathlib
 import sys
@@ -44,34 +52,53 @@ if str(BENCH) not in sys.path:
 
 #: folds per seed, drawn from the seed
 FOLDS = 2
+#: device bytes the dense solve needs beside its n x n float32 kernel:
+#: the block of rows ``reference.kernel`` writes (at most 2,048 rows of
+#: n) with its intermediates, X, and the solve's vectors. On a v5e (XLA
+#: may use 16,909,336,064 bytes) up to 62,919 rows take the dense solve,
+#: mnist's 60,000 (K 14.4 GB) among them
+HEADROOM = 1 << 30
 #: kernel of each solve -> the readings taken from it: (name, how many
 #: folds on from the solved one lie the rows it predicts)
 READINGS = {"rbf": (("sound", 0), ("wrong_fold", 1)),
             "rbf_high": (("control", 0),)}
 
 
-def answers(cfg, y, chunks, folds, kern, max_iter, shifts=(0,)):
-    """What the reference solver answers for ``folds`` over kernel
-    ``kern``, in the form ``reference.check`` takes: one list of answers
-    for each shift in ``shifts``; fold h's held-out predictions are taken
-    for the rows of fold h + shift."""
+def memory_limit():
+    """The bytes the device says XLA may use, or None where it states no
+    limit (the CPU, in tests)."""
+    import jax
+    return (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+def fits_dense(n: int, limit) -> bool:
+    """Whether the dense n x n float32 kernel fits a device that lets XLA
+    use ``limit`` bytes, beside its solve: 4 n^2 bytes and ``HEADROOM``.
+    With no limit stated, the dense solve is taken."""
+    return limit is None or 4 * n * n + HEADROOM <= limit
+
+
+def answers(cfg, y, chunks, folds, solve, evaluate, max_iter, shifts=(0,)):
+    """What the reference solver answers for ``folds``, in the form
+    ``reference.check`` takes: one list of answers for each shift in
+    ``shifts``; fold h's held-out predictions are taken for the rows of
+    fold h + shift. ``solve`` and ``evaluate`` are ``reference.smo`` and
+    ``reference.evaluate`` (or ``smo_rows`` and ``evaluate_rows``) with
+    their kernel bound."""
     import jax.numpy as jnp
     import numpy as np
 
     import data
-    import reference
 
     masks = data.train_masks(chunks)
     k = chunks.shape[0]
     out = [[] for _ in shifts]
     for h in folds:
         train = jnp.asarray(masks[h])
-        alpha, f, it = reference.smo(kern, y, train, cfg["C"], cfg["tol"],
-                                     max_iter)
+        alpha, f, it = solve(y, train, cfg["C"], cfg["tol"], max_iter)
         for got, shift in zip(out, shifts):
             rows = jnp.asarray(chunks[(h + shift) % k])
-            pred, obj = reference.evaluate(kern, rows, y, alpha, f, train,
-                                           cfg["C"])
+            pred, obj = evaluate(rows, y, alpha, f, train, cfg["C"])
             got.append(dict(alpha=np.asarray(alpha), f=np.asarray(f),
                             pred=np.asarray(pred), objective=float(obj),
                             train=masks[h], test=chunks[h], n_iter=int(it)))
@@ -91,15 +118,28 @@ def readings(cfg, seed: int, max_iter: int) -> dict:
     X32, y = X[:n].astype(np.float32), y[:n]
     folds = [int(h) for h in np.random.default_rng(seed).choice(
         cfg["k"], FOLDS, replace=False)]
-    out = {"seed": seed, "folds": folds}
+    dense = fits_dense(n, memory_limit())
+    out = {"seed": seed, "folds": folds, "solve": "dense" if dense else "rows"}
     for fn, names in READINGS.items():
         t0 = time.monotonic()
-        kern = reference.kernel(X32, cfg["gamma"], getattr(reference, fn))
-        got = answers(cfg, y, chunks, folds, kern, max_iter,
-                      [shift for _, shift in names])
-        # free the n x n kernel now: the next one does not fit beside it
-        kern.delete()
-        del kern
+        shifts = [shift for _, shift in names]
+        kern = getattr(reference, fn)
+        if dense:
+            K = reference.kernel(X32, cfg["gamma"], kern)
+            got = answers(cfg, y, chunks, folds,
+                          functools.partial(reference.smo, K),
+                          functools.partial(reference.evaluate, K),
+                          max_iter, shifts)
+            # free the n x n kernel now: the next one does not fit beside it
+            K.delete()
+            del K
+        else:
+            got = answers(cfg, y, chunks, folds,
+                          functools.partial(reference.smo_rows, X32,
+                                            cfg["gamma"], kern),
+                          functools.partial(reference.evaluate_rows, X32,
+                                            cfg["gamma"], kern),
+                          max_iter, shifts)
         for (name, _), ans in zip(names, got):
             out[name] = reference.check(X32, y, cfg["C"], cfg["gamma"],
                                         cfg["tol"], ans)

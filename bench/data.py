@@ -2,13 +2,18 @@
 
 A copy of the stand-in generator and ``kfold_chunks`` that the program
 ships (``repro.data.svm_suite``), kept here so that no change to the
-program can change the data a cell runs on. ``tests/bench`` holds the two
-equal while they are.
+program can change the data a cell runs on. Its parameters are the
+configuration's own: ``dataset`` (the name its random stream is drawn
+under), ``features`` and the ``generator`` block, so a new dataset is a
+new configuration file and nothing else. ``tests/bench`` holds each
+configuration's block equal to the program's entry for its dataset,
+where the program has one.
 
-Generator: two anisotropic Gaussian clusters over ``n_informative`` dims,
-the other dims pure noise, then label noise ``flip``; features scaled to
-[-1, 1]. Deterministic per (name, seed): the seed goes through crc32, not
-``hash()``, which Python salts per process.
+Generator: two anisotropic Gaussian clusters over ``n_informative`` dims
+at centres drawn at scale ``separation``, the other dims pure noise, then
+label noise ``flip``; ``balanced`` gives an exact 50/50 label split;
+features scaled to [-1, 1]. Deterministic per (dataset, seed): the seed
+goes through crc32, not ``hash()``, which Python salts per process.
 """
 from __future__ import annotations
 
@@ -16,25 +21,15 @@ import zlib
 
 import numpy as np
 
-# name -> (cardinality, dim, C, gamma, n_informative, separation, flip,
-#          balanced); C and gamma are the paper's Table 2 values, the
-# cardinality is the program's CPU-sized default (a cell passes its own)
-SPECS = {
-    "adult":   (2000, 123, 100.0, 0.5, 40, 1.3, 0.10, False),
-    "heart":   (270, 13, 2182.0, 0.2, 10, 0.35, 0.30, False),
-    "madelon": (2000, 500, 1.0, 0.7071, 0, 0.0, 0.0, True),
-    "mnist":   (2000, 780, 10.0, 0.125, 60, 0.15, 0.40, True),
-    "webdata": (2000, 300, 64.0, 7.8125, 30, 2.2, 0.015, False),
-}
 
-
-def make_dataset(name: str, *, seed: int = 0, n: int | None = None):
-    """``(X, y)`` of the stand-in for ``name``: X (n, d) float64 in
-    [-1, 1], y (n,) int64 in {-1, +1}."""
-    n0, d, _, _, n_inf, sep, flip, balanced = SPECS[name]
-    n = n0 if n is None else n
-    rng = np.random.default_rng(zlib.crc32(f"{name}:{seed}".encode()))
-    if balanced:
+def make_dataset(cfg: dict, *, seed: int, n: int):
+    """``(X, y)`` of the stand-in that configuration ``cfg`` describes, at
+    ``n`` rows: X (n, cfg["features"]) float64 in [-1, 1], y (n,) int64 in
+    {-1, +1}."""
+    gen, d = cfg["generator"], cfg["features"]
+    n_inf, sep = gen["n_informative"], gen["separation"]
+    rng = np.random.default_rng(zlib.crc32(f"{cfg['dataset']}:{seed}".encode()))
+    if gen["balanced"]:
         y = np.repeat([1, -1], [n - n // 2, n // 2])
         y = y[rng.permutation(n)]
     else:
@@ -45,7 +40,7 @@ def make_dataset(name: str, *, seed: int = 0, n: int | None = None):
         scales = 0.5 + rng.random(n_inf)
         X[:, :n_inf] = X[:, :n_inf] * scales + np.where(y[:, None] > 0,
                                                         centers[0], centers[1])
-    flip_mask = rng.random(n) < flip
+    flip_mask = rng.random(n) < gen["flip"]
     y = np.where(flip_mask, -y, y)
     X = X / (np.abs(X).max(axis=0, keepdims=True) + 1e-12)
     return X.astype(np.float64), y.astype(np.int64)
@@ -66,7 +61,7 @@ def cell_inputs(cfg: dict, seed: int):
     order drawn from ``seed``: seeds differ in their inputs and not in the
     work they need."""
     n, k = cfg["published_rows"], cfg["k"]
-    X, y = make_dataset(cfg["dataset"], seed=cfg["data_seed"], n=n)
+    X, y = make_dataset(cfg, seed=cfg["data_seed"], n=n)
     chunks = kfold_chunks(n, k, seed=cfg["data_seed"])
     # the rows past k * (n // k), in no fold, stay where they are
     solved = chunks.size

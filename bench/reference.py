@@ -10,8 +10,10 @@ in float64) and two plain pieces of code:
   objective and the held-out predictions. The numbers it returns compare
   the program's answer with those;
 * ``smo`` is a textbook SMO solve (maximal violating pair) over a dense K
-  that ``kernel`` builds. Over the kernel ``rbf_high`` (the matmul one
-  step below the configuration's precision) it is the control: what an
+  that ``kernel`` builds; ``smo_rows`` is the same solve with the pair's
+  two kernel rows computed from X in every iteration, for a K that does
+  not fit the chip. Over the kernel ``rbf_high`` (the matmul one step
+  below the configuration's precision) either is the control: what an
   answer in a lower precision looks like to ``check``.
 
 Kernel rows are computed in blocks of rows, so the check fits beside
@@ -120,24 +122,28 @@ INFEASIBLE = 1e9
 SPAN = 256
 
 
-@functools.partial(jax.jit, static_argnames=("block",))
-def _kv_block(Xp, X, V_hl, gamma, r0, block):
+@functools.partial(jax.jit, static_argnames=("block", "fn"))
+def _kv_block(Xp, X, V_hl, gamma, r0, block, fn):
     n, F2 = V_hl.shape
-    Kb = rbf(jax.lax.dynamic_slice_in_dim(Xp, r0, block), X, gamma)
+    Kb = fn(jax.lax.dynamic_slice_in_dim(Xp, r0, block), X, gamma)
     Kb = Kb.reshape(block, n // SPAN, SPAN)
     part = jnp.einsum("bcj,cjf->bcf", Kb, V_hl.reshape(n // SPAN, SPAN, F2),
                       precision=HIGHEST, preferred_element_type=jnp.float32)
     return jnp.sum(part.astype(jnp.float64), axis=1)
 
 
-def kv_product(X, V, gamma, block: int = BLOCK) -> np.ndarray:
-    """K @ V for the float32 RBF kernel of X (n, d) and float64 V (n, F),
-    as float64 (n, F) on the host. V is split into float32 high and low
-    parts, so the product keeps V's digits; float32 sums run over SPAN
-    columns at a time and float64 sums over the rest, so what is left is
-    float32 accumulation over SPAN terms."""
+def kv_product(X, V, gamma, block: int = BLOCK, fn=rbf,
+               rows=None) -> np.ndarray:
+    """K @ V for the float32 kernel ``fn`` (``rbf`` by default) of X (n, d)
+    and float64 V (n, F), as float64 (n, F) on the host; with ``rows``,
+    only those rows of it, (len(rows), F). V is split into float32 high
+    and low parts, so the product keeps V's digits; float32 sums run over
+    SPAN columns at a time and float64 sums over the rest, so what is left
+    is float32 accumulation over SPAN terms."""
     X = jnp.asarray(X, jnp.float32)
     n = X.shape[0]
+    Xr = X if rows is None else X[jnp.asarray(rows)]
+    m = Xr.shape[0]
     V = np.asarray(V, np.float64)
     hi = V.astype(np.float32)
     lo = (V - hi.astype(np.float64)).astype(np.float32)
@@ -146,14 +152,14 @@ def kv_product(X, V, gamma, block: int = BLOCK) -> np.ndarray:
     V_hl = jnp.asarray(np.pad(np.concatenate([hi, lo], axis=1),
                               ((0, pad), (0, 0))))
     Xc = jnp.pad(X, ((0, pad), (0, 0)))
-    block = min(block, n)
-    Xp = jnp.pad(X, ((0, (-n) % block), (0, 0)))
+    block = min(block, m)
+    Xp = jnp.pad(Xr, ((0, (-m) % block), (0, 0)))
     F = V.shape[1]
-    out = np.empty((n, F))
-    for r0 in range(0, n, block):
-        r1 = min(r0 + block, n)
-        o = np.asarray(_kv_block(Xp, Xc, V_hl, gamma, r0, block))[: r1 - r0]
-        out[r0:r1] = o[:, :F] + o[:, F:]
+    out = np.empty((m, F))
+    for r0 in range(0, m, block):
+        r1 = min(r0 + block, m)
+        o = np.asarray(_kv_block(Xp, Xc, V_hl, gamma, r0, block, fn))
+        out[r0:r1] = o[: r1 - r0, :F] + o[: r1 - r0, F:]
     return out
 
 
@@ -212,11 +218,12 @@ def check(X, y, C, gamma, tol, folds) -> dict:
     return {k: v if np.isfinite(v) else INFEASIBLE for k, v in out.items()}
 
 
-@functools.partial(jax.jit, static_argnames=("max_iter",))
-def _smo(K, y, train, C, tol, max_iter):
+def _mvp_loop(pair, y, train, C, tol, max_iter):
+    """SMO from zero with the maximal violating pair, state in y's dtype;
+    ``pair(i, j)`` gives the pair's kernel rows K_i, K_j and K_ii, K_jj in
+    that dtype."""
     dtype = y.dtype
     n = y.shape[0]
-    diag = jnp.diagonal(K).astype(dtype)
     tau = jnp.asarray(1e-12, dtype)
 
     def sets(alpha):
@@ -239,8 +246,8 @@ def _smo(K, y, train, C, tol, max_iter):
         up, low = sets(alpha)
         i = jnp.argmin(jnp.where(up, f, jnp.inf))
         j = jnp.argmax(jnp.where(low, f, -jnp.inf))
-        K_i, K_j = K[i].astype(dtype), K[j].astype(dtype)
-        eta = jnp.maximum(diag[i] + diag[j] - 2.0 * K_i[j], tau)
+        K_i, K_j, K_ii, K_jj = pair(i, j)
+        eta = jnp.maximum(K_ii + K_jj - 2.0 * K_i[j], tau)
         delta = (f[j] - f[i]) / eta
         hi_i = jnp.where(y[i] > 0, C - alpha[i], alpha[i])
         hi_j = jnp.where(y[j] > 0, alpha[j], C - alpha[j])
@@ -253,12 +260,50 @@ def _smo(K, y, train, C, tol, max_iter):
     return jax.lax.while_loop(cond, body, (alpha0, -y, jnp.zeros((), jnp.int32)))
 
 
+@functools.partial(jax.jit, static_argnames=("max_iter",))
+def _smo(K, y, train, C, tol, max_iter):
+    diag = jnp.diagonal(K).astype(y.dtype)
+
+    def pair(i, j):
+        return K[i].astype(y.dtype), K[j].astype(y.dtype), diag[i], diag[j]
+
+    return _mvp_loop(pair, y, train, C, tol, max_iter)
+
+
+@functools.partial(jax.jit, static_argnames=("fn", "max_iter"))
+def _smo_rows(X, gamma, y, train, C, tol, fn, max_iter):
+    def pair(i, j):
+        R = fn(X[jnp.stack([i, j])], X, gamma).astype(y.dtype)
+        return R[0], R[1], R[0, i], R[1, j]
+
+    return _mvp_loop(pair, y, train, C, tol, max_iter)
+
+
 def smo(K, y, train, C, tol, max_iter: int, dtype=jnp.float64):
     """Solve one fold from zero by SMO with the maximal violating pair, in
     state precision ``dtype``: returns (alpha, f, n_iter) in that dtype."""
     y = jnp.asarray(y, dtype)
     return _smo(K, y, jnp.asarray(train, bool), jnp.asarray(C, dtype),
                 jnp.asarray(tol, dtype), max_iter=int(max_iter))
+
+
+def smo_rows(X, gamma, fn, y, train, C, tol, max_iter: int):
+    """``smo`` in float64 state over the float32 kernel ``fn`` of X (n, d)
+    with no dense K: each iteration computes the pair's two kernel rows
+    from X, as ``kernel`` computes every row, so the solve holds X and its
+    state alone."""
+    f64 = jnp.float64
+    return _smo_rows(jnp.asarray(X, jnp.float32), gamma, jnp.asarray(y, f64),
+                     jnp.asarray(train, bool), jnp.asarray(C, f64),
+                     jnp.asarray(tol, f64), fn=fn, max_iter=int(max_iter))
+
+
+def _bias_objective(y, alpha, f, train, C):
+    """A solve's bias (LIBSVM's rule: the mean of -f over free vectors)
+    and dual objective, in the solve's own precision."""
+    free = train & (alpha > 0) & (alpha < C)
+    b = -jnp.sum(jnp.where(free, f, 0.0)) / jnp.maximum(jnp.sum(free), 1)
+    return b, jnp.sum(alpha) - 0.5 * jnp.dot(alpha * y, f + y)
 
 
 def evaluate(K, test, y, alpha, f, train, C):
@@ -269,13 +314,23 @@ def evaluate(K, test, y, alpha, f, train, C):
     webdata's size), and the held-out rows ``test`` kept."""
     dtype = alpha.dtype
     y = jnp.asarray(y, dtype)
-    free = train & (alpha > 0) & (alpha < C)
-    b = -jnp.sum(jnp.where(free, f, 0.0)) / jnp.maximum(jnp.sum(free), 1)
+    b, obj = _bias_objective(y, alpha, f, train, C)
     v = alpha * y
     hi = v.astype(K.dtype)
     lo = (v - hi.astype(dtype)).astype(K.dtype)
     Kv = jnp.dot(K, jnp.stack([hi, lo], axis=1), precision=HIGHEST)
     dec = Kv[test, 0].astype(dtype) + Kv[test, 1].astype(dtype) + b
     pred = jnp.where(dec >= 0, 1, -1)
-    obj = jnp.sum(alpha) - 0.5 * jnp.dot(v, f + y)
     return pred, obj
+
+
+def evaluate_rows(X, gamma, fn, test, y, alpha, f, train, C):
+    """``evaluate`` for a solve with no dense K (``smo_rows``): the
+    held-out rows' decision values are their rows of K (alpha * y), taken
+    from X in blocks of rows by ``kv_product`` over the solve's kernel
+    ``fn``."""
+    y = jnp.asarray(y, alpha.dtype)
+    b, obj = _bias_objective(y, alpha, f, train, C)
+    v = np.asarray(alpha * y, np.float64)
+    dec = kv_product(X, v[:, None], gamma, fn=fn, rows=test)[:, 0] + float(b)
+    return np.where(dec >= 0, 1, -1), obj

@@ -133,9 +133,10 @@ class FoldChain:
         """Held-out predictions and dual objective, with the functions
         ``run_cv`` (dense K) and ``run_cv_batched`` (row-streaming
         source) take them with. Over a dense K every row is predicted and
-        the held-out ones kept: ``run_cv``'s gather of K's test rows needs
-        some 6 GB of transient memory at webdata's size, which one chip
-        does not have beside K."""
+        the held-out ones kept, as ``run_cv`` predicts through one product
+        with K: a gather of K's test rows would be a second (n / k, n)
+        buffer, which one chip does not hold beside webdata's or mnist's
+        K."""
         test = self.test[h]
         b = bias_from_solution(res, self.y, self.masks[h], self.cfg["C"])
         if self.K is not None:

@@ -32,7 +32,7 @@ def main(out_dir: str, seconds: float = 0.05) -> None:
     run.enable_compile_cache()
     c = run.resolve("adult.cold_pallas")
     cfg = dict(c.cfg, published_rows=ROWS, rows=ROWS, max_iter=MAX_ITER)
-    X, y = data.make_dataset(cfg["dataset"], seed=7, n=ROWS)
+    X, y = data.make_dataset(cfg, seed=7, n=ROWS)
     chunks = data.kfold_chunks(ROWS, cfg["k"], seed=7)
     step = c.step.STEP(cfg, c.traffic, X, y, chunks)
     step.setup()

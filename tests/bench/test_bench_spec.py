@@ -7,6 +7,7 @@ import re
 import shutil
 import sys
 
+import numpy as np
 import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -137,33 +138,64 @@ def test_layers_named_alike():
         assert f"| {m['layer']} |" in perf, m["layer"]
 
 
+def _tree(root: pathlib.Path) -> dict:
+    return {p.relative_to(root): p.read_bytes()
+            for p in sorted(root.rglob("*")) if p.is_file()}
+
+
 def test_new_cell_and_metric_need_no_edit(tmp_path):
-    """A later cell, traffic mix and per-layer metric are new files and new
-    entries: the harness finds them with no file that exists edited."""
+    """A later configuration (of a dataset that no file of the benchmark
+    names), cell, traffic mix and per-layer metric are new files and new
+    entries: the harness finds them, and makes the new configuration's
+    data, with no file that exists edited."""
+    import data
     root = tmp_path / "checkout"
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "testdata"))
+    before = _tree(root / "bench")
     spec = json.loads(json.dumps(SPEC))
-    (root / "bench" / "traffic" / "mir.json").write_text(json.dumps(
-        {"step": "fold_chain", "method": "mir", "source": "dense",
-         "wss": "2", "chunk_iters": None}))
-    (root / "bench" / "limits" / "adult.mir.json").write_text(
-        json.dumps({"obj_rel": 1e-5}))
+    new = {
+        "configs/covtype_mini.json": {
+            "name": "covtype_mini", "dataset": "covtype_mini",
+            "published_rows": 300, "rows": 300, "features": 54,
+            "generator": {"n_informative": 10, "separation": 1.3,
+                          "flip": 0.1, "balanced": False},
+            "C": 10.0, "gamma": 0.125, "k": 10, "tol": 1e-3,
+            "max_iter": 100_000, "reduced": {}, "data_seed": 0},
+        "traffic/ato_probe.json": {
+            "step": "fold_chain", "method": "ato", "source": "dense",
+            "wss": "2", "chunk_iters": None},
+        "limits/covtype_mini.ato_probe.json": {"obj_rel": 1e-5},
+    }
+    for rel, body in new.items():
+        assert not (root / "bench" / rel).exists(), rel
+        (root / "bench" / rel).write_text(json.dumps(body))
     (root / "bench" / "metrics" / "fold_count.py").write_text(
         "def read(run):\n    return float(len(run.folds))\n")
-    spec["workloads"].append({"name": "adult.mir", "config": "adult",
-                              "traffic": "mir", "chips": 1, "why": "w"})
+    spec["configs"].append({"name": "covtype_mini", "source": "s",
+                            "file": "bench/configs/covtype_mini.json",
+                            "reduced": [], "why": "w"})
+    spec["workloads"].append({"name": "covtype_mini.ato_probe",
+                              "config": "covtype_mini",
+                              "traffic": "ato_probe", "chips": 1, "why": "w"})
     spec["per_layer"].append({"name": "fold_count", "unit": "folds",
                               "better": "higher", "source": "host_clock",
                               "layer": "plan and pool", "moves": "fold_s"})
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
-    c = run.resolve("adult.mir", bench=root / "bench")
-    assert c.traffic["method"] == "mir"
+    c = run.resolve("covtype_mini.ato_probe", bench=root / "bench")
+    assert c.traffic["method"] == "ato" and c.cfg["features"] == 54
     assert "fold_count" in c.readers
     assert c.readers["fold_count"].read(type("R", (), {"folds": [1, 2]})) == 2.0
+    X, y, chunks = data.cell_inputs(c.cfg, 2**31 + 5)
+    assert X.shape == (300, 54) and y.shape == (300,)
+    assert chunks.shape == (10, 30) and np.abs(X).max() <= 1.0
+    again = data.cell_inputs(c.cfg, 2**31 + 5)
+    assert all(np.array_equal(a, b) for a, b in zip(again, (X, y, chunks)))
     # an old cell now reports the new metric too, since it lists no cells
     old = run.resolve("adult.cold", bench=root / "bench")
     assert "fold_count" in {m["name"] for m in old.per_layer}
+    after = _tree(root / "bench")
+    assert {k: after[k] for k in before} == before
 
 
 def test_setup_bound_and_metric_bounds_are_finite():

@@ -32,7 +32,7 @@ def test_every_traffic_file_is_used_and_names_a_step():
 def test_fold_ring_steps(traffic):
     c = run.resolve(BY_TRAFFIC[traffic])
     cfg = dict(c.cfg, published_rows=ROWS, rows=ROWS)
-    X, y = data.make_dataset(cfg["dataset"], seed=3, n=ROWS)
+    X, y = data.make_dataset(cfg, seed=3, n=ROWS)
     chunks = data.kfold_chunks(ROWS, cfg["k"], seed=3)
     step = c.step.STEP(cfg, c.traffic, X, y, chunks)
     folds = step.setup()
