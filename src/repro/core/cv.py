@@ -35,6 +35,7 @@ dispatch and mid-study checkpoint/resume from the shared entry point.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 
 import jax.numpy as jnp
@@ -59,6 +60,9 @@ _FOLD_STRIDE = 1_000_000
 # Study records (retain_class "study") start at study.STUDY_BASE, above
 # both — see DESIGN.md §Study API for the full key scheme.
 _BATCH_BASE = _FOLD_STRIDE ** 2
+#: SMO iterations per chunk of a matrix-free ``run_cv`` given no
+#: ``chunk_iters``, as ``run_cv_batched``'s default
+PALLAS_CHUNK_ITERS = 4096
 
 
 @dataclasses.dataclass
@@ -169,6 +173,19 @@ def _eval_fold_rows(source, y, chunks, h, res, C) -> tuple[int, int, float]:
             float(obj))
 
 
+def _cv_source(X, gamma, source_backend: str, kernel_backend: str):
+    """``(source, K)`` of a CV over ``X``: the dense float32 K and its
+    ``DenseKernel``, or for ``"pallas_rbf"`` the row-streaming
+    ``PallasRBF`` and no K (only the O(n·d) row norms are computed)."""
+    if source_backend == "pallas_rbf":
+        source = PallasRBF(X, gamma)
+        source.sq_norms.block_until_ready()
+        return source, None
+    K = kernel_matrix(X, X, kind="rbf", gamma=gamma, backend=kernel_backend)
+    K.block_until_ready()
+    return DenseKernel(K), K
+
+
 def _fold_masks(chunks: np.ndarray) -> np.ndarray:
     """(k, n) boolean train masks; row h is True off fold h's test chunk."""
     k, n = chunks.shape[0], chunks.size
@@ -185,7 +202,8 @@ def run_cv(ds: SVMDataset, k: int = 10, method: str = "sir",
            kernel_backend: str = "jnp", chunk_iters: int | None = None,
            checkpoint_every: int = 1, shrink_every: int | str = 0,
            shrink_quantum: int = 128, shrink_caps=None,
-           shrink_on_seed: bool = True) -> CVReport:
+           shrink_on_seed: bool = True,
+           source_backend: str = "dense") -> CVReport:
     """Run alpha-seeded k-fold CV. ``unavailable_folds`` simulates stragglers/
     failures: those folds' results are not used as seeds (best_available
     policy then falls back to the nearest earlier completed fold).
@@ -209,8 +227,19 @@ def run_cv(ds: SVMDataset, k: int = 10, method: str = "sir",
     legacy mid records carry only (alpha, f, n_iter), not the shrink
     ledger, so a resume could not re-enter the compact subproblem —
     study-keyed drivers (``run_cv_batched``, ``run_grid``) checkpoint the
-    ledger and support both together."""
+    ledger and support both together.
+
+    ``source_backend="pallas_rbf"`` solves over the row-streaming
+    ``PallasRBF`` source instead of a dense K, as ``run_cv_batched`` does:
+    no (n, n) kernel is built, the folds solve under WSS-1 in chunks of
+    ``chunk_iters`` (``PALLAS_CHUNK_ITERS`` where it is None), SIR seeds
+    from K[R, T] blocks computed from X and f0 from the streaming
+    ``matvec``, and folds are evaluated off row slabs
+    (``_eval_fold_rows``). Methods that read whole slabs of K (MIR, ATO)
+    are refused at ``run_plan`` entry."""
     seeding.SEEDERS[method]   # validate the method name up front
+    if source_backend not in ("dense", "pallas_rbf"):
+        raise ValueError(f"unknown source_backend {source_backend!r}")
     if shrink_every and checkpoint_manager is not None \
             and chunk_iters is not None:
         raise ValueError(
@@ -228,13 +257,13 @@ def run_cv(ds: SVMDataset, k: int = 10, method: str = "sir",
     # two slice orders differ in final bits at some shapes, and grid cells
     # must stay bit-identical to run_cv)
     t0 = time.perf_counter()
-    K = kernel_matrix(X[:n], X[:n], kind="rbf", gamma=ds.gamma,
-                      backend=kernel_backend)
-    K.block_until_ready()
+    source, K = _cv_source(X[:n], ds.gamma, source_backend, kernel_backend)
     kernel_time = time.perf_counter() - t0
-    del X   # only K is read from here on
+    del X   # only the source is read from here on
     y = y[:n]
     masks = jnp.asarray(_fold_masks(chunks))
+    evaluate = (functools.partial(_eval_fold, K) if K is not None
+                else functools.partial(_eval_fold_rows, source))
 
     results: dict[int, object] = {}
     restored_meta: dict[int, dict] = {}
@@ -300,7 +329,7 @@ def run_cv(ds: SVMDataset, k: int = 10, method: str = "sir",
         if restored_meta[h].get("method") != method:
             continue
         res = results[h]
-        correct, total, obj = _eval_fold(K, y, chunks, h, res, ds.C)
+        correct, total, obj = evaluate(y, chunks, h, res, ds.C)
         folds.append(FoldStat(
             fold=h, seed_from=restored_meta[h].get("seed_from", -1),
             n_iter=int(res.n_iter), init_time=0.0, solve_time=0.0,
@@ -308,9 +337,13 @@ def run_cv(ds: SVMDataset, k: int = 10, method: str = "sir",
             converged=bool(res.converged), restored=True))
 
     # ---- declare the fold chain as a plan ----
-    plan = Plan(sources={"cv": DenseKernel(K)}, y=y, tol=tol,
-                chunk_iters=chunk_iters if chunk_iters is not None
-                else max_iter,
+    pallas = source_backend == "pallas_rbf"
+    if chunk_iters is not None:
+        plan_chunk = chunk_iters
+    else:
+        plan_chunk = PALLAS_CHUNK_ITERS if pallas else max_iter
+    plan = Plan(sources={"cv": source}, y=y, tol=tol,
+                wss="1" if pallas else "2", chunk_iters=plan_chunk,
                 shrink_every=shrink_every, shrink_quantum=shrink_quantum,
                 shrink_caps=shrink_caps, shrink_on_seed=shrink_on_seed)
     for g in sorted(results):
@@ -401,7 +434,7 @@ def run_cv(ds: SVMDataset, k: int = 10, method: str = "sir",
     for h in range(start_fold, k):
         res = sres.results[h]
         stat = sres.stats[h]
-        correct, total, obj = _eval_fold(K, y, chunks, h, res, ds.C)
+        correct, total, obj = evaluate(y, chunks, h, res, ds.C)
         folds.append(FoldStat(
             fold=h, seed_from=seed_froms[h], n_iter=stat.n_iter,
             init_time=stat.seed_s, solve_time=stat.solve_s,
@@ -465,15 +498,7 @@ def run_cv_batched(ds: SVMDataset, k: int = 10, tol: float = 1e-3,
     # slice before the kernel call (see run_cv): no wasted (N, N) compute,
     # bit-aligned with run_grid's KernelSpec sources
     t0 = time.perf_counter()
-    if source_backend == "pallas_rbf":
-        K = None
-        source = PallasRBF(X[:n], ds.gamma)
-        source.sq_norms.block_until_ready()
-    else:
-        K = kernel_matrix(X[:n], X[:n], kind="rbf", gamma=ds.gamma,
-                          backend=kernel_backend)
-        K.block_until_ready()
-        source = DenseKernel(K)
+    source, K = _cv_source(X[:n], ds.gamma, source_backend, kernel_backend)
     kernel_time = time.perf_counter() - t0
     y = y[:n]
     masks = jnp.asarray(_fold_masks(chunks))

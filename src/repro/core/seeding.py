@@ -286,26 +286,13 @@ def mir_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx):
 # SIR — Single Instance Replacement (paper Eq. 19-21, Algorithm 3)
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("fallback",))
-def sir_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx,
-             rng_key: jax.Array | None = None, fallback: str = "random"):
-    """Greedy replacement: each removed x_r inherits its alpha to the most
-    similar (max kernel value) unused same-label x_t, followed by constraint
-    repair.
-
-    ``fallback`` controls the label-less case (no unused same-label x_t):
-
-    * ``"random"`` — the paper's rule: a random unused pick. A sign-flipped
-      beta lands on one coordinate; the repair then shifts every T beta.
-    * ``"skip"`` — beyond-paper: drop that alpha and let the (uniform,
-      diffuse) repair absorb the mass. Avoids poisoning single coordinates
-      with large wrong-sign alphas, which SMO then diffuses over the whole
-      free set.
-    """
+def _sir_replace(K_RT, y, C, prev: SMOResult, S_idx, R_idx, T_idx,
+                 rng_key, fallback: str):
+    """SIR's greedy replacement and repair from its kernel block
+    ``K_RT = K[R_idx][:, T_idx]`` (``sir_seed``)."""
     if rng_key is None:
         rng_key = jax.random.PRNGKey(0)
     m = R_idx.shape[0]
-    K_RT = K[R_idx][:, T_idx]
     same = (y[R_idx][:, None] == y[T_idx][None, :])
     alpha_R = prev.alpha[R_idx]
     priority = jax.random.uniform(rng_key, (T_idx.shape[0],), STATE_DTYPE)
@@ -337,6 +324,52 @@ def sir_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx,
     alpha0 = jnp.zeros_like(prev.alpha).at[S_idx].set(prev.alpha[S_idx])
     alpha0 = alpha0.at[T_idx].set(y[T_idx] * beta_T)
     return repair_equality(alpha0, y, C, S_idx, T_idx)
+
+
+@functools.partial(jax.jit, static_argnames=("fallback",))
+def _sir_dense(K, y, C, prev, S_idx, R_idx, T_idx, rng_key, fallback):
+    if rng_key is None:       # first, so the program's ops keep their order
+        rng_key = jax.random.PRNGKey(0)
+    return _sir_replace(K[R_idx][:, T_idx], y, C, prev, S_idx, R_idx, T_idx,
+                        rng_key, fallback)
+
+
+_sir_from_block = jax.jit(_sir_replace, static_argnames=("fallback",))
+
+
+@jax.jit
+def _kernel_block(source, rows, cols):
+    return source.block(rows, cols)
+
+
+def sir_seed(K, y, C, prev: SMOResult, S_idx, R_idx, T_idx,
+             rng_key: jax.Array | None = None, fallback: str = "random"):
+    """Greedy replacement: each removed x_r inherits its alpha to the most
+    similar (max kernel value) unused same-label x_t, followed by constraint
+    repair.
+
+    ``K`` is the dense kernel matrix, sliced as ``K[R_idx][:, T_idx]``, or
+    a row-streaming source with no K: its ``block(R_idx, T_idx)`` computes
+    that block from X (|R| x |T|, timed by the ``repro.seed.block`` span,
+    synced). The replacement is the same program on either block.
+
+    ``fallback`` controls the label-less case (no unused same-label x_t):
+
+    * ``"random"`` — the paper's rule: a random unused pick. A sign-flipped
+      beta lands on one coordinate; the repair then shifts every T beta.
+    * ``"skip"`` — beyond-paper: drop that alpha and let the (uniform,
+      diffuse) repair absorb the mass. Avoids poisoning single coordinates
+      with large wrong-sign alphas, which SMO then diffuses over the whole
+      free set.
+    """
+    if not callable(getattr(K, "block", None)):
+        return _sir_dense(K, y, C, prev, S_idx, R_idx, T_idx, rng_key,
+                          fallback)
+    with obs.span("repro.seed.block", rows=int(R_idx.shape[0]),
+                  cols=int(T_idx.shape[0])):
+        K_RT = jax.block_until_ready(_kernel_block(K, R_idx, T_idx))
+    return _sir_from_block(K_RT, y, C, prev, S_idx, R_idx, T_idx, rng_key,
+                           fallback)
 
 
 # --------------------------------------------------------------------------
@@ -707,6 +740,10 @@ from repro.svm.shrink import seed_active_mask  # noqa: E402,F401
 # description works for fold chains, C-adjacent grid warm starts and LOO
 # rounds. ``repro.core.study`` finishes the admission by computing
 # ``f0 = init_f(K, y, alpha0)``.
+#
+# On a row-streaming source (no dense K) ``K`` is the source itself, and
+# f0 is ``source.matvec(alpha0 * y) - y``; ``row_source_needs`` says which
+# transforms run there and what the source must offer.
 
 TRANSFORMS: dict[str, callable] = {}
 
@@ -737,6 +774,23 @@ def scale_C_transform(K, y, C, prev, *, C_old, train_mask):
 #: scale_C never touches K, so the Study API admits it on K-less
 #: (row-streaming) sources, deriving f0 from the source's streaming matvec
 scale_C_transform.kernel_free = True
+
+#: the fold seeders that read K only through a source's ``block``: SIR
+#: its K[R, T]
+ROW_SEEDERS = ("sir",)
+
+
+def row_source_needs(transform: str, params: dict) -> tuple[str, ...] | None:
+    """The methods a source with no dense K must offer for ``transform``
+    with ``params`` to run on it: ``matvec`` (f0) for every transform that
+    runs there, and ``block`` besides for the ``"fold"`` transform of a
+    ``ROW_SEEDERS`` method. None where the transform needs a dense K
+    (MIR's least squares and ATO's ramp read whole slabs of it)."""
+    if getattr(TRANSFORMS[transform], "kernel_free", False):
+        return ("matvec",)
+    if transform == "fold" and params.get("method") in ROW_SEEDERS:
+        return ("block", "matvec")
+    return None
 
 
 @register_transform("loo_avg")

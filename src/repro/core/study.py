@@ -61,8 +61,8 @@ import numpy as np
 from repro import obs
 from repro.core import seeding
 from repro.svm import shrink as shrink_mod
-from repro.svm.engine import (DenseKernel, EngineState, SMOResult,
-                              finalize)
+from repro.svm.engine import (MATVEC_BLOCK, DenseKernel, EngineState,
+                              SMOResult, finalize)
 from repro.svm.precision import kdot
 from repro.svm.scheduler import LanePool
 from repro.svm.sources import KernelSpec, is_factory
@@ -516,11 +516,44 @@ def plan_from_dict(d: dict) -> Plan:
                 sv_eval=bool(d.get("sv_eval", False)))
 
 
+def _runs_on_rows(source, transform: str, params: dict) -> bool:
+    """Whether a source with no dense K offers what ``transform`` with
+    ``params`` reads (``seeding.row_source_needs``)."""
+    needs = seeding.row_source_needs(transform, params)
+    return needs is not None and all(callable(getattr(source, m, None))
+                                     for m in needs)
+
+
+def _needs_dense(transform: str, params: dict) -> str:
+    """The start of the message that refuses ``transform`` on a source
+    with no dense K: the transform, and the fold seeder where one is
+    named."""
+    method = params.get("method")
+    return f"transform {transform!r}" + (
+        f" with method {method!r}" if method is not None else "") + " needs"
+
+
+@jax.jit
+def _streamed_init_f(source, y, alpha0):
+    """``init_f`` through a row-streaming source's ``matvec``, under one
+    jit: eagerly, ``matvec``'s ``lax.map`` would trace and compile anew at
+    every admission."""
+    return source.matvec(alpha0 * y) - y
+
+
 def _make_seed_fn(plan: Plan, spec: LaneSpec, resolve):
     """Build the pool-facing seed closure for a dependent lane. ``resolve``
     maps a source key to a USABLE source at call time (the pool's residency
     cache) — K is looked up lazily, at admission, so factory sources only
-    materialize when a lane of theirs actually seeds."""
+    materialize when a lane of theirs actually seeds.
+
+    Over a dense K the transform reads K and f0 is ``init_f``. A source
+    with no K takes the transforms ``seeding.row_source_needs`` admits
+    (the kernel-free ones, and the ``"fold"`` transform's SIR),
+    is handed to the transform in K's place (SIR takes its K[R, T] block
+    from ``source.block``), and gives f0 as ``matvec(alpha0 * y) - y``,
+    timed by the ``repro.seed.matvec`` span (synced); any other transform
+    is refused there, naming it."""
     fn = seeding.TRANSFORMS[spec.transform]
     key = plan.source_key_of(spec)
     y, C, params = plan.y_of(key), spec.C, dict(spec.params)
@@ -528,30 +561,32 @@ def _make_seed_fn(plan: Plan, spec: LaneSpec, resolve):
     def seed(prev):
         source = resolve(key)
         K = getattr(source, "K", None)
-        if K is None:
-            # kernel-free transforms (seeding.py marks them) never touch
-            # K; f0 comes from the source's streaming matvec instead of
-            # the dense init_f
-            if getattr(fn, "kernel_free", False) and \
-                    callable(getattr(source, "matvec", None)):
-                alpha0 = fn(None, y, C, prev, **params)
-                return alpha0, source.matvec(alpha0 * y) - y
-            raise ValueError(f"lane {spec.id!r}: transform "
-                             f"{spec.transform!r} needs a dense kernel "
-                             f"source (source {key!r} has no K)")
-        alpha0 = fn(K, y, C, prev, **params)
-        return alpha0, init_f(K, y, alpha0)
+        if K is not None:
+            alpha0 = fn(K, y, C, prev, **params)
+            return alpha0, init_f(K, y, alpha0)
+        if not _runs_on_rows(source, spec.transform, params):
+            raise ValueError(f"lane {spec.id!r}: "
+                             f"{_needs_dense(spec.transform, params)} a "
+                             f"dense kernel source (source {key!r} has no "
+                             "K)")
+        alpha0 = fn(source, y, C, prev, **params)
+        n = int(y.shape[0])
+        with obs.span("repro.seed.matvec", rows=n,
+                      blocks=-(-n // MATVEC_BLOCK)):
+            f0 = jax.block_until_ready(_streamed_init_f(source, y, alpha0))
+        return alpha0, f0
 
     seed.transform = spec.transform       # named by the repro.pool.seed span
     return seed
 
 
 def _check_dense(plan: Plan, lane_id, key, what: str,
-                 transform: str | None = None) -> None:
+                 transform: str | None = None, params=None) -> None:
     """Seed transforms and evaluations need a dense K — unless the
-    source supports the K-less alternative: kernel-free transforms run
-    off a streaming ``matvec``, evaluations off a ``rows_at`` row slab.
-    For an already-materialized (pinned) source that is checkable AT
+    source supports the K-less alternative: the transforms
+    ``seeding.row_source_needs`` admits run off the source's ``block``
+    and ``matvec`` (``_make_seed_fn``), evaluations off a ``rows_at`` row
+    slab. For an already-materialized (pinned) source that is checkable AT
     ENTRY — an incompatible source must not fail only after its
     dependency solved for hours. Factory entries stay deferred for the
     capabilities a spec cannot declare; the lazy resolution re-checks."""
@@ -559,9 +594,7 @@ def _check_dense(plan: Plan, lane_id, key, what: str,
     if is_factory(entry) or getattr(entry, "K", None) is not None:
         return
     if transform is not None:
-        fn = seeding.TRANSFORMS[transform]
-        if getattr(fn, "kernel_free", False) and \
-                callable(getattr(entry, "matvec", None)):
+        if _runs_on_rows(entry, transform, params or {}):
             return
     elif callable(getattr(entry, "rows_at", None)):
         return
@@ -592,8 +625,8 @@ def _validate_plan(plan: Plan, specs: dict) -> None:
                                  f"{spec.transform!r} (have "
                                  f"{sorted(seeding.TRANSFORMS)})")
             _check_dense(plan, spec.id, plan.source_key_of(spec),
-                         f"transform {spec.transform!r} needs",
-                         transform=spec.transform)
+                         _needs_dense(spec.transform, spec.params),
+                         transform=spec.transform, params=spec.params)
     for ev in plan.evals:
         if ev.lane not in specs:
             raise ValueError(f"EvalSpec targets undeclared lane {ev.lane!r}")

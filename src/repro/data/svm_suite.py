@@ -1,9 +1,10 @@
-"""Synthetic stand-ins for the paper's five LibSVM datasets.
+"""Synthetic stand-ins for the paper's five LibSVM datasets, and Forest.
 
-The container is offline, so Adult/Heart/Madelon/MNIST/Webdata cannot be
-downloaded. We generate binary tasks with the paper's dimensionalities and
-hyper-parameters (Table 2); the three large sets are cardinality-scaled to a
-CPU budget (paper claims are about iteration counts / identical fixed points,
+Nothing can be downloaded, so Adult/Heart/Madelon/MNIST/Webdata and
+Forest (UCI covertype, binary) are generated: binary tasks with the
+published dimensionalities and hyper-parameters (the paper's Table 2;
+Forest's from Catanzaro et al., ICML 2008); the large sets are
+cardinality-scaled to a CPU budget (paper claims are about iteration counts / identical fixed points,
 which are scale-invariant — see DESIGN.md §Synthetic datasets).
 
 Generator: two anisotropic Gaussian clusters over ``n_informative`` dims,
@@ -34,8 +35,10 @@ class SVMDataset:
 
 # name -> (cardinality, dim, C, gamma, n_informative, separation, flip,
 #          balanced)
-# C/gamma are the paper's Table 2 values. ``cardinality`` for the three large
-# sets is scaled (paper sizes in comments). separation/flip are tuned so each
+# C/gamma are the paper's Table 2 values; forest's are Catanzaro, Sundaram &
+# Keutzer's (ICML 2008, Table 1: UCI covertype, binary), whose Adult, Web and
+# MNIST rows carry Table 2's. ``cardinality`` for the large sets is scaled
+# (paper sizes in comments). separation/flip are tuned so each
 # synthetic task lands in the same SVM *regime* as its namesake — the paper's
 # reported CV accuracies expose those regimes: Madelon 50.0% and MNIST 50.85%
 # are chance level (K ~= I, alphas ~ all bounded at C), Heart 55.6% is near
@@ -49,6 +52,7 @@ SPECS = {
     "madelon": (2000, 500, 1.0, 0.7071, 0, 0.0, 0.0, True),     # paper size
     "mnist":   (2000, 780, 10.0, 0.125, 60, 0.15, 0.40, True),  # paper: 60,000
     "webdata": (2000, 300, 64.0, 7.8125, 30, 2.2, 0.015, False),  # paper: 49,749
+    "forest":  (2000, 54, 10.0, 0.125, 10, 1.3, 0.10, False),   # paper: 561,012
 }
 DATASETS = tuple(SPECS)
 

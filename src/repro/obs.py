@@ -28,8 +28,10 @@ time spent on programs with no second counted twice.
 Span names (DESIGN.md §Spans): ``repro.plan`` and its children
 ``repro.plan.prepare``, ``.analyze``, ``.evals``, ``.release``;
 ``repro.pool.build``, ``.run``, ``.seed``, ``.dispatch``, ``.wait``,
-``.retire``; ``repro.seed.assemble`` (MIR's assembly, inside
-``repro.pool.seed``); ``repro.cache.materialize``.
+``.retire``; ``repro.seed.assemble`` (MIR's assembly), ``repro.seed.block``
+(SIR's K[R, T] from a row-streaming source) and ``repro.seed.matvec`` (f0
+through its streaming ``matvec``), each inside ``repro.pool.seed``;
+``repro.cache.materialize``.
 """
 from __future__ import annotations
 

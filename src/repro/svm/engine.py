@@ -84,6 +84,10 @@ from repro.svm.precision import STATE_DTYPE, kdot
 
 _INF = jnp.inf
 _TAU = 1e-12
+#: kernel rows per block of the row-streaming sources' ``matvec``
+MATVEC_BLOCK = 2048
+#: kernel columns ``matvec`` sums in float32 before float64 takes over
+MATVEC_SPAN = 256
 
 #: logical-axis rules for the sharded sources (instances over pod x data,
 #: features over model) — re-exported by ``repro.svm.distributed``.
@@ -270,6 +274,20 @@ class DenseKernel:
         return cls(children[0])
 
 
+@jax.jit
+def _rbf_block(Xr, Xc, sq_c, gamma):
+    """RBF kernel values of the rows ``Xr`` against the columns ``Xc``
+    (``sq_c`` their squared norms) -> (r, c), for ``rows_at`` and
+    ``block``. One program, so the result is the only (r, c) buffer: run
+    op by op, each of the expression's (r, c) temporaries stays allocated
+    until the device has run its consumer, and the slab's evaluation held
+    seven at once (11.5 GB for a Forest fold's 6,400 held-out rows of
+    64,000 on a TPU v5e)."""
+    d2 = jnp.maximum(jnp.sum(Xr * Xr, -1)[:, None] + sq_c[None]
+                     - 2.0 * (Xr @ Xc.T), 0.0)
+    return jnp.exp(-gamma * d2)
+
+
 @jax.tree_util.register_pytree_node_class
 class OnDemandRBF:
     """RBF kernel rows recomputed from X each iteration (K_ii = 1).
@@ -343,25 +361,40 @@ class OnDemandRBF:
     def rows_at(self, idx):
         """Kernel row slab K[idx, :] -> (t, n) — the evaluation path for
         K-less sources: O(t*n) transient, never n^2 resident."""
-        Xi = self.X[jnp.asarray(idx)]
-        d2 = jnp.maximum(jnp.sum(Xi * Xi, -1)[:, None] + self.sq_norms[None]
-                         - 2.0 * (Xi @ self.X.T), 0.0)
-        return jnp.exp(-self.gamma * d2)
+        return _rbf_block(self.X[jnp.asarray(idx)], self.X, self.sq_norms,
+                          self.gamma)
 
-    def matvec(self, v, *, block: int = 2048):
-        """Streaming ``K @ v`` (``init_f`` on seeded lanes, unshrink
+    def block(self, rows, cols):
+        """Kernel block K[rows][:, cols] -> (r, c): ``rows_at``'s expression
+        over the columns ``cols`` alone, what a seed transform reads of K
+        (SIR's K[R, T]) without the (r, n) slab."""
+        cols = jnp.asarray(cols)
+        return _rbf_block(self.X[jnp.asarray(rows)], self.X[cols],
+                          self.sq_norms[cols], self.gamma)
+
+    def matvec(self, v, *, block: int = MATVEC_BLOCK):
+        """Streaming ``K @ v`` (f0 of seeded lanes, objectives, unshrink
         reconstruction): kernel row blocks are formed and reduced
-        immediately, O(block*n) transient memory."""
+        immediately, O(block*n) transient memory. Each row's float32 sums
+        run over ``MATVEC_SPAN`` columns and float64 sums over the rest
+        (``kdot``'s ``span``): a seeded f0 sums some n/2 products of up to
+        C, and float32 over all n of them would put its error at ~1e-3 by
+        64,000 rows."""
         n, d = self.X.shape
         pad = (-n) % block
         Xb = jnp.pad(self.X, ((0, pad), (0, 0))).reshape(-1, block, d)
         sqb = jnp.pad(self.sq_norms, (0, pad)).reshape(-1, block)
+        # columns padded to whole spans: finite kernel values times v's 0
+        cpad = (-n) % MATVEC_SPAN
+        Xc = jnp.pad(self.X, ((0, cpad), (0, 0)))
+        sqc = jnp.pad(self.sq_norms, (0, cpad))
+        vc = jnp.pad(v, (0, cpad))
 
         def one(args):
             xb, sb = args
-            d2 = jnp.maximum(sb[:, None] + self.sq_norms[None]
-                             - 2.0 * (xb @ self.X.T), 0.0)
-            return kdot(jnp.exp(-self.gamma * d2), v)
+            d2 = jnp.maximum(sb[:, None] + sqc[None] - 2.0 * (xb @ Xc.T),
+                             0.0)
+            return kdot(jnp.exp(-self.gamma * d2), vc, span=MATVEC_SPAN)
 
         return jax.lax.map(one, (Xb, sqb)).reshape(-1)[:n]
 
@@ -412,7 +445,7 @@ class PallasRBF(OnDemandRBF):
     row-major X serves the pair's two feature rows, read by index
     (``_pair``), not by the one-hot product the sharded sources use (X
     lives on one device, and the product would be a second pass over all
-    of it), and ``rows_at``/``matvec``.
+    of it), and ``rows_at``/``block``/``matvec``.
     ``streams_rows = True`` tells the engine to route the update
     through ``update_f(f, i, j, delta)`` / ``kij(i, j)`` instead of
     materializing rows; selection must therefore be WSS-1 (``fused``).
@@ -486,10 +519,11 @@ class PallasRBF(OnDemandRBF):
         return PallasRBF(self.X[idx], self.gamma, self.sq_norms[idx],
                          bm=self.bm, bk=self.bk, interpret=self.interpret)
 
-    # rows_at / matvec (the eval-slab and streaming-matvec paths) are
-    # inherited from OnDemandRBF — the expressions are row-streaming
-    # already, and sharing one definition keeps the reconstruction path
-    # bit-identical across the RBF source family.
+    # rows_at / block / matvec (the eval-slab, seed-block and
+    # streaming-matvec paths) are inherited from OnDemandRBF — the
+    # expressions are row-streaming already, and sharing one definition
+    # keeps the reconstruction path bit-identical across the RBF source
+    # family.
 
     def tree_flatten(self):
         return (self.X, self.sq_norms, self.Xt), \

@@ -33,7 +33,7 @@ def kernel_input(X) -> jnp.ndarray:
     return jnp.asarray(X, KERNEL_DTYPE)
 
 
-def kdot(K, v) -> jnp.ndarray:
+def kdot(K, v, *, span: int | None = None) -> jnp.ndarray:
     """``K @ v`` for kernel values ``K`` (t, n) and a state vector ``v``
     (n,), returned in ``v``'s dtype.
 
@@ -41,13 +41,24 @@ def kdot(K, v) -> jnp.ndarray:
     both go through ONE f32 matmul (no f64 copy of K, which at n = 32,561
     would not fit a 16 GB chip); the two halves are summed in f64. What
     remains is f32 accumulation error — about 1e-7 of ``sum |K_ij v_j|`` —
-    which the tolerances in DESIGN.md §Precision policy account for. Same
-    dtypes take the plain product (the float64 reference path)."""
+    which the tolerances in DESIGN.md §Precision policy account for. With
+    ``span`` (which must divide n) the f32 sums run over ``span`` columns
+    at a time and f64 sums over the partial sums, so the f32 accumulation
+    covers ``span`` terms, not n. Same dtypes take the plain product (the
+    float64 reference path)."""
     if K.dtype == v.dtype:
         return K @ v
     hi = v.astype(K.dtype)
     lo = (v - hi.astype(v.dtype)).astype(K.dtype)
-    out = jnp.dot(K, jnp.stack([hi, lo], axis=-1),
-                  precision=jax.lax.Precision.HIGHEST,
+    V = jnp.stack([hi, lo], axis=-1)
+    if span is not None:
+        t, n = K.shape
+        part = jnp.einsum("tcj,cjf->tcf", K.reshape(t, n // span, span),
+                          V.reshape(n // span, span, 2),
+                          precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=K.dtype)
+        out = jnp.sum(part.astype(v.dtype), axis=1)
+        return out[..., 0] + out[..., 1]
+    out = jnp.dot(K, V, precision=jax.lax.Precision.HIGHEST,
                   preferred_element_type=K.dtype)
     return out[..., 0].astype(v.dtype) + out[..., 1].astype(v.dtype)

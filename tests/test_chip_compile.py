@@ -205,3 +205,42 @@ def test_mir_programs_compile_for_v5e_at_mnist(spec):
                      spec((n,), f64), spec((), f64), spec((n,), f64), *sets)
     for compiled in (assemble, solve):
         assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_row_sir_programs_compile_for_v5e_at_forest(spec):
+    """A SIR-seeded fold with no dense K at Forest's 64,000 x 54, whose K
+    (16.4 GB) no chip holds: the K[R, T] block computed from X (6,400
+    squared, 164 MB), the replacement over it, f0 through the streaming
+    matvec, the matrix-free chunk, and the held-out rows' kernel slab
+    (6,400 x 64,000, 1.64 GB) with no second slab beside it, each well
+    inside HBM."""
+    from repro.core import study
+    n, d, t = 64000, 54, 6400
+    f32, f64, i64 = jnp.float32, jnp.float64, jnp.int64
+    dt, nt = feature_major_shape(n, d)
+    source = lambda X, Xt, sq: PallasRBF(X, 0.125, sq,  # noqa: E731
+                                         interpret=False, Xt=Xt)
+    src = (spec((n, d), f32), spec((dt, nt), f32), spec((n,), f32))
+    prev = SMOResult(spec((n,), f64), spec((n,), f64), spec((), i64),
+                     spec((), bool), spec((), f64), spec((), f64))
+    sets = (spec((n - 2 * t,), i64), spec((t,), i64), spec((t,), i64))
+    block = _compile(lambda X, Xt, sq, R, T: seeding._kernel_block(
+        source(X, Xt, sq), R, T), *src, sets[1], sets[2])
+    assert block.memory_analysis().output_size_in_bytes == 4 * t * t
+    _compile(lambda K_RT, y, C, p, S, R, T: seeding._sir_from_block(
+        K_RT, y, C, p, S, R, T, None, "random"),
+        spec((t, t), f32), spec((n,), f64), spec((), f64), prev, *sets)
+    f0 = _compile(lambda X, Xt, sq, y, a: study._streamed_init_f(
+        source(X, Xt, sq), y, a), *src, spec((n,), f64), spec((n,), f64))
+    assert f0.memory_analysis().temp_size_in_bytes < 2e9
+    slab = _compile(lambda X, Xt, sq, test: source(X, Xt, sq).rows_at(test),
+                    *src, sets[1]).memory_analysis()
+    assert slab.output_size_in_bytes == 4 * t * n
+    assert slab.temp_size_in_bytes < 0.1 * slab.output_size_in_bytes
+    state = EngineState(spec((n,), f64), spec((n,), f64), spec((), i64),
+                        spec((), bool))
+    chunk = _compile(lambda X, Xt, sq, y, mask, st: chunk_jit(
+        source(X, Xt, sq), y, mask, 10.0, 1e-3, jnp.asarray(10**6, i64), st,
+        n_iters=4096, wss="1"), *src, spec((n,), f64), spec((n,), bool),
+        state)
+    _assert_one_pass_over_x(chunk.as_text())

@@ -303,8 +303,9 @@ def test_validate_plan_cycle_names_the_cycle():
 
 
 def test_validate_plan_dense_k_names_lane_and_source():
-    """A seed transform on a K-less source fails at entry, naming both
-    the lane and the source key it resolved to."""
+    """A seed transform that reads slabs of K (MIR's least squares), on a
+    K-less source, fails at entry, naming the lane, the method and the
+    source key it resolved to."""
     from repro.svm import PallasRBF
     ds, _, y, chunks, masks = _setup("heart")
     n = y.shape[0]
@@ -312,10 +313,11 @@ def test_validate_plan_dense_k_names_lane_and_source():
     plan = Plan(sources={"rbf": PallasRBF(X, ds.gamma)}, y=y, wss="1")
     plan.lane("w0", train_mask=masks[0], C=ds.C, alpha0=jnp.zeros(n), f0=-y)
     plan.lane("w1", train_mask=masks[1], C=ds.C, dep="w0", transform="fold",
-              params=dict(method="sir",
+              params=dict(method="mir",
                           S_idx=jnp.arange(4), R_idx=jnp.arange(4),
                           T_idx=jnp.arange(4)))
-    with pytest.raises(ValueError, match=r"'w1'.*'fold'.*'rbf' has no K"):
+    with pytest.raises(ValueError,
+                       match=r"'w1'.*'fold' with method 'mir'.*'rbf' has no K"):
         run_plan(plan)
 
 
